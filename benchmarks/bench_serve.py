@@ -418,6 +418,8 @@ def run_sustained(report_rows: List[str], n_queries: int = 100_000,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--sustained", action="store_true",

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 from typing import List
 
@@ -123,6 +124,46 @@ def run(report_rows: List[str]) -> None:
                 "paper claim: SMMS balances better than Terasort")
 
 
+def _radix_end_to_end(entries, report_rows, best_of) -> None:
+    """Forced-bitonic vs forced-radix ``cluster.sort`` at m = 2^14."""
+    t_w, m_w = 4, 1 << 14
+    xw = jnp.asarray(uniform_keys(t_w * m_w, seed=7).reshape(t_w, m_w))
+    e2e = {}
+    for family in ("bitonic", "radix"):
+        with ops.force_sort_kernel(family):
+            reset_default_pool()
+            ops.reset_dispatch_counts()
+            (keys_f, _), rep_f = cluster.sort(xw, algorithm="smms",
+                                              kernel_backend="pallas")
+            calls = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
+                        if path in KERNEL_PATHS)
+            us = best_of(xw, algorithm="smms", kernel_backend="pallas")
+        e2e[family] = {"us": us, "keys": np.asarray(keys_f),
+                       "dispatches": int(calls),
+                       "k_workload": rep_f.k_workload}
+    assert np.array_equal(e2e["bitonic"]["keys"], e2e["radix"]["keys"]), (
+        "forced-radix cluster.sort diverged from forced-bitonic")
+    radix_faster = bool(e2e["radix"]["us"] <= e2e["bitonic"]["us"])
+    entries.append({"op": "cluster.sort[smms,radix-vs-bitonic]",
+                    "shape": f"{t_w}x{m_w}",
+                    "interpret_mode": True,
+                    "bitonic_us": round(e2e["bitonic"]["us"]),
+                    "radix_us": round(e2e["radix"]["us"]),
+                    "radix_dispatches": e2e["radix"]["dispatches"],
+                    "dispatch_budget": DISPATCH_BUDGET["smms_radix"],
+                    "radix_faster": radix_faster,
+                    "bitwise_equal": True,
+                    "k_workload": e2e["radix"]["k_workload"]})
+    report_rows.append(
+        f"kernel_compare,cluster.sort,radix_vs_bitonic,t={t_w},m={m_w},"
+        f"bitonic_us={e2e['bitonic']['us']:.0f},"
+        f"radix_us={e2e['radix']['us']:.0f},equal=1,"
+        f"radix_faster={int(radix_faster)}")
+    assert e2e["radix"]["dispatches"] <= DISPATCH_BUDGET["smms_radix"], (
+        f"forced-radix smms: {e2e['radix']['dispatches']} dispatches "
+        f"exceed the budget {DISPATCH_BUDGET['smms_radix']}")
+
+
 def run_kernel_compare(report_rows: List[str]) -> None:
     """Kernel-dispatch layer on vs off through the REAL front door.
 
@@ -130,20 +171,21 @@ def run_kernel_compare(report_rows: List[str]) -> None:
     kernel_backend="pallas" vs "reference" and asserts the outputs are
     bitwise identical — the differential contract, measured at benchmark
     scale.  Results land under ``kernel_compare[<mode>]`` of
-    BENCH_sort.json, keyed "interpret" or "compiled" by the live
-    ``ops.INTERPRET`` flag so the two backend modes keep separate
-    records (``main(--backend=compiled)`` flips the flag on an
-    accelerator).  In interpret mode the Pallas latency is a correctness
-    datapoint, NOT hardware performance; every entry carries its own
+    BENCH_sort.json, keyed "interpret" or "compiled" by the platform's
+    kernel mode (``ops.interpret_mode()``: interpreted on CPU, compiled
+    on TPU) so the two modes keep separate records.  In interpret mode
+    the Pallas latency is a correctness datapoint, NOT hardware
+    performance; every entry carries its own
     ``interpret_mode`` so a reader never has to guess.  Each kernel row
     is joined against the roofline memory model
     (:class:`repro.launch.roofline.KernelCost`) into expected-vs-
-    achieved bandwidth rows — the calibration feed for the
-    ``sort_kernel_choice`` crossover constants.
+    achieved bandwidth rows — calibration data for the radix-vs-bitonic
+    verdict.
     """
     from repro.launch.roofline import KernelCost
 
-    mode = "interpret" if ops.INTERPRET else "compiled"
+    interpret = ops.interpret_mode()
+    mode = "interpret" if interpret else "compiled"
     entries = []
     roofline_rows = []
 
@@ -161,7 +203,7 @@ def run_kernel_compare(report_rows: List[str]) -> None:
         equal = bool(np.array_equal(np.asarray(ref), np.asarray(ker)))
         assert equal, "kernel sort diverged from reference"
         entries.append({"op": "ops.sort", "shape": f"{rows}x{n}",
-                        "interpret_mode": ops.INTERPRET,
+                        "interpret_mode": interpret,
                         "reference_us": round(ref_us),
                         "pallas_us": round(ker_us), "bitwise_equal": equal})
         roofline_rows.append(KernelCost.bitonic(rows, n).row(
@@ -179,7 +221,7 @@ def run_kernel_compare(report_rows: List[str]) -> None:
     equal = bool(np.array_equal(np.asarray(ref), np.asarray(ker)))
     assert equal, "kernel merge diverged from reference"
     entries.append({"op": "ops.merge_sorted_rows", "shape": "8x512",
-                    "interpret_mode": ops.INTERPRET,
+                    "interpret_mode": interpret,
                     "reference_us": round(ref_us),
                     "pallas_us": round(ker_us), "bitwise_equal": equal})
     roofline_rows.append(KernelCost.merge(8, 512).row(
@@ -189,18 +231,15 @@ def run_kernel_compare(report_rows: List[str]) -> None:
         f"pallas_us={ker_us:.0f},equal=1")
 
     # ---- radix vs bitonic: the wide-row crossover point ------------------
-    # n = 2^14 is past the cost model's float32 crossover
-    # (sort_kernel_choice picks radix there on compiled backends); both
-    # kernel families are forced in turn over the SAME input, checked
+    # n = 2^14 is past the roofline model's float32 crossover;
+    # both kernel families are forced in turn over the SAME input, checked
     # bitwise against each other and against jnp.sort, and joined
-    # against the roofline model.  The radix <= bitonic timing gate only
-    # arms in compiled mode — the interpret-mode emulator prices
-    # radix's scatter at ~30x its hardware cost (that measurement is
-    # exactly why sort_kernel_choice pins bitonic under interpret), so
-    # there the rows are recorded as calibration data only.
+    # against the roofline model.  Interpret mode only: the radix kernel
+    # has no Mosaic lowering, and the interpreter prices radix's scatter
+    # at ~30x its hardware cost, so these rows are calibration data.
     rows_w, n_w = 4, 1 << 14
-    for dtype, key_bits in ((jnp.float32, 32), (jnp.int32, 32),
-                            (jnp.bfloat16, 16)):
+    radix_dtypes = ((jnp.float32, 32), (jnp.int32, 32), (jnp.bfloat16, 16))
+    for dtype, key_bits in radix_dtypes if interpret else ():
         if dtype == jnp.int32:
             xw = jax.random.randint(jax.random.key(n_w), (rows_w, n_w),
                                     -(2 ** 31), 2 ** 31 - 1, dtype=jnp.int32)
@@ -220,11 +259,11 @@ def run_kernel_compare(report_rows: List[str]) -> None:
         entries.append({"op": "ops.sort[radix-vs-bitonic]",
                         "shape": f"{rows_w}x{n_w}",
                         "dtype": np.dtype(dtype).name,
-                        "interpret_mode": ops.INTERPRET,
+                        "interpret_mode": interpret,
                         "bitonic_us": round(bit_us),
                         "radix_us": round(rad_us),
                         "radix_faster": faster,
-                        "chosen": ops.sort_kernel_choice(xw),
+                        "chosen": ops.sort_kernel_choice(),
                         "bitwise_equal": equal})
         roofline_rows.append(KernelCost.bitonic(rows_w, n_w).row(
             bit_us * 1e-6, op="ops.sort[bitonic]",
@@ -237,11 +276,6 @@ def run_kernel_compare(report_rows: List[str]) -> None:
             f"kernel_compare,radix_vs_bitonic,{np.dtype(dtype).name},"
             f"{rows_w}x{n_w},bitonic_us={bit_us:.0f},radix_us={rad_us:.0f},"
             f"equal=1,radix_faster={int(faster)}")
-        if not ops.INTERPRET:
-            assert faster, (
-                f"compiled radix must beat bitonic at {rows_w}x{n_w} "
-                f"({np.dtype(dtype).name}): {rad_us:.0f}us vs "
-                f"{bit_us:.0f}us — recalibrate RADIX_PASS_SUBSTAGES")
 
     # ---- end-to-end: the cluster front door ------------------------------
     # The front door's default substrate is the shared jit pool, so a
@@ -278,7 +312,7 @@ def run_kernel_compare(report_rows: List[str]) -> None:
         regression |= slower
         entries.append({"op": f"cluster.sort[{algorithm}]",
                         "shape": f"{t}x{m}",
-                        "interpret_mode": ops.INTERPRET,
+                        "interpret_mode": interpret,
                         "reference_us": round(ref_us),
                         "pallas_us": round(ker_us),
                         "pallas_dispatches": int(kernel_calls),
@@ -296,57 +330,17 @@ def run_kernel_compare(report_rows: List[str]) -> None:
 
     # ---- end-to-end radix at the wide-row point --------------------------
     # Same front door, rows wide enough that the cost model would pick
-    # radix on a compiled backend (m = 2^14 per shard).  Radix is forced
-    # per family (fresh pool inside the context — the choice is a
-    # trace-time decision) so both families' end-to-end wall clock and
-    # dispatch counts land in the record; the timing gate again only
-    # arms in compiled mode.
-    t_w, m_w = 4, 1 << 14
-    xw = jnp.asarray(uniform_keys(t_w * m_w, seed=7).reshape(t_w, m_w))
-    e2e = {}
-    for family in ("bitonic", "radix"):
-        with ops.force_sort_kernel(family):
-            reset_default_pool()
-            ops.reset_dispatch_counts()
-            (keys_f, _), rep_f = cluster.sort(xw, algorithm="smms",
-                                              kernel_backend="pallas")
-            calls = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
-                        if path in KERNEL_PATHS)
-            us = best_of(xw, algorithm="smms", kernel_backend="pallas")
-        e2e[family] = {"us": us, "keys": np.asarray(keys_f),
-                       "dispatches": int(calls),
-                       "k_workload": rep_f.k_workload}
-    assert np.array_equal(e2e["bitonic"]["keys"], e2e["radix"]["keys"]), (
-        "forced-radix cluster.sort diverged from forced-bitonic")
-    radix_faster = bool(e2e["radix"]["us"] <= e2e["bitonic"]["us"])
-    entries.append({"op": "cluster.sort[smms,radix-vs-bitonic]",
-                    "shape": f"{t_w}x{m_w}",
-                    "interpret_mode": ops.INTERPRET,
-                    "bitonic_us": round(e2e["bitonic"]["us"]),
-                    "radix_us": round(e2e["radix"]["us"]),
-                    "radix_dispatches": e2e["radix"]["dispatches"],
-                    "dispatch_budget": DISPATCH_BUDGET["smms_radix"],
-                    "radix_faster": radix_faster,
-                    "bitwise_equal": True,
-                    "k_workload": e2e["radix"]["k_workload"]})
-    report_rows.append(
-        f"kernel_compare,cluster.sort,radix_vs_bitonic,t={t_w},m={m_w},"
-        f"bitonic_us={e2e['bitonic']['us']:.0f},"
-        f"radix_us={e2e['radix']['us']:.0f},equal=1,"
-        f"radix_faster={int(radix_faster)}")
-    assert e2e["radix"]["dispatches"] <= DISPATCH_BUDGET["smms_radix"], (
-        f"forced-radix smms: {e2e['radix']['dispatches']} dispatches "
-        f"exceed the budget {DISPATCH_BUDGET['smms_radix']}")
-    if not ops.INTERPRET:
-        assert radix_faster, (
-            f"compiled radix must beat bitonic end-to-end at "
-            f"{t_w}x{m_w}: {e2e['radix']['us']:.0f}us vs "
-            f"{e2e['bitonic']['us']:.0f}us")
+    # radix (m = 2^14 per shard).  Radix is forced per family (fresh
+    # pool inside the context — the choice is a trace-time decision) so
+    # both families' end-to-end wall clock and dispatch counts land in
+    # the record; interpret mode only, as above.
+    if interpret:
+        _radix_end_to_end(entries, report_rows, best_of)
     reset_default_pool()
 
     _merge_bench_json({"kernel_compare": {mode: {
         "suite": "bench_sort.run_kernel_compare",
-        "interpret_mode": ops.INTERPRET,
+        "interpret_mode": interpret,
         "note": ("interpret-mode Pallas latencies are a correctness "
                  "datapoint, not TPU performance; end-to-end rows time "
                  "the warm fused front door, best of {} runs; roofline "
@@ -507,44 +501,30 @@ def run_dispatch_budget(report_rows: List[str]) -> None:
 def main(argv: List[str] = None) -> int:
     """CLI: ``python -m benchmarks.bench_sort [--backend=interpret|compiled]``.
 
-    ``--backend=compiled`` reruns the kernel-compare gate with the
-    Pallas interpreter OFF (``ops.INTERPRET = False``, the runtime
-    equivalent of ``REPRO_PALLAS_INTERPRET=0``) so the kernels lower
-    through the real backend compiler; its record lands under
+    The kernel mode follows the platform (``ops.interpret_mode()``):
+    ``--backend`` states which one the caller expects.
+    ``--backend=compiled`` measures Mosaic-compiled kernels and needs a
+    TPU; anywhere else it exits 1 rather than record interpreter
+    latencies under a device's name.  Its record lands under
     ``kernel_compare["compiled"]`` in BENCH_sort.json next to the
-    interpret record, and the radix-beats-bitonic timing gates arm.
-    Compiled Pallas needs an accelerator: on a CPU-only host the run
-    SKIPS gracefully (exit 0, one explanatory line) instead of crashing
-    in the Mosaic/Triton lowering.
+    interpret record.
     """
     import argparse
 
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--backend", choices=("interpret", "compiled"),
                    default="interpret",
-                   help="Pallas execution mode for the kernel-compare "
-                        "suite (compiled needs a GPU/TPU)")
+                   help="expected Pallas execution mode of the "
+                        "kernel-compare suite (compiled needs a TPU)")
     args = p.parse_args(argv)
 
     rows: List[str] = []
-    if args.backend == "compiled":
-        platform = jax.default_backend()
-        if platform not in ("gpu", "tpu"):
-            print(f"bench_sort: SKIP --backend=compiled — needs an "
-                  f"accelerator, jax.default_backend() is {platform!r} "
-                  f"(interpret-mode records in BENCH_sort.json are "
-                  f"unaffected)")
-            return 0
-        prev = ops.INTERPRET
-        ops.INTERPRET = False
-        reset_default_pool()
-        try:
-            run_kernel_compare(rows)
-        finally:
-            ops.INTERPRET = prev
-            reset_default_pool()
-    else:
-        run_kernel_compare(rows)
+    if args.backend == "compiled" and ops.interpret_mode():
+        print(f"bench_sort: --backend=compiled needs a TPU; no accelerator "
+              f"here (jax.default_backend() is {ops.platform()!r})",
+              file=sys.stderr)
+        return 1
+    run_kernel_compare(rows)
     for row in rows:
         print(row)
     return 0
@@ -570,5 +550,6 @@ def run_scaling(report_rows: List[str]) -> None:
 
 
 if __name__ == "__main__":
-    import sys
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -2,6 +2,9 @@
 HLO with op metadata (this is the 'profile' the §Perf hints describe —
 lowered IR, not wall clock).
 
+A CPU-only model-scaffold tool: it forces 512 host devices at import, so
+it is never sent to the chip and nothing on the chip path imports it.
+
     PYTHONPATH=src python -m benchmarks.inspect_collectives --arch X --shape Y
 """
 import os

@@ -188,6 +188,8 @@ def run_overhead_gate(report_rows: List[str]) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows: List[str] = []
     run(rows)
     run_overhead_gate(rows)

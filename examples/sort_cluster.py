@@ -44,9 +44,9 @@ def main():
     # --- the same sort through the Pallas kernel layer -------------------
     # kernel_backend="pallas" routes the Round-1 bitonic sort, the
     # branch-free searchsorted partition, and the Round-3 merge kernel; the
-    # output is bitwise identical to the jnp reference path.  (Here the
-    # kernels run in interpret mode — on a real TPU export
-    # REPRO_PALLAS_INTERPRET=0 and the identical calls compile w/ Mosaic.)
+    # output is bitwise identical to the jnp reference path.  (The kernel
+    # mode follows the platform: the Pallas interpreter on CPU, Mosaic-
+    # compiled kernels on a TPU, for the identical calls.)
     mk = 1 << 10
     xk = jnp.asarray(lidar_like(t * mk, seed=3).reshape(t, mk))
     (keys_ref, _), _ = cluster.sort(xk, algorithm="smms", r=r,
@@ -61,4 +61,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -15,7 +15,6 @@ One runtime, two executors, uniform accounting:
   ``algorithm="auto"``, which hands the choice to the sketch-driven
   planner in :mod:`repro.planner`.
 """
-from . import compat
 from .api import (AUTO, JOIN_ALGORITHMS, MOE_DISPATCH_MODES,
                   SORT_ALGORITHMS, join, moe_dispatch, sort)
 from .capacity import CapacityOverflowError, CapacityPolicy, run_with_capacity
@@ -25,7 +24,6 @@ from .substrate import (ShardMapSubstrate, Substrate, SubstratePool,
                         recommend_pool_size, reset_default_pool)
 
 __all__ = [
-    "compat",
     "sort", "join", "moe_dispatch",
     "SORT_ALGORITHMS", "JOIN_ALGORITHMS", "MOE_DISPATCH_MODES", "AUTO",
     "CapacityPolicy", "CapacityOverflowError", "run_with_capacity",

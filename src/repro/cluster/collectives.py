@@ -35,11 +35,11 @@ from __future__ import annotations
 import contextlib
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from . import compat
 
 # NOTE: repro.core.alpha_k is imported lazily in phases()/report() — the
 # algorithm modules under repro.core import this module at load time, and
@@ -164,7 +164,8 @@ class CollectiveTape:
         ``restage``, a pure relay runs: ``keys_buf`` must then be
         (t1, t2, C) with block [g, d2] addressed to machine (g, d2), and
         the stage-2 landing, reassembled source-major, is bitwise equal
-        to the flat t-way all_to_all of the same buffer.
+        to the flat t-way all_to_all of the same buffer.  ``values_buf``
+        (one array or a tuple of arrays) rides beside the keys, untracked.
 
         Stage 2 is issued in ``chunks`` column slices; ``chunk_fn(keys,
         values)`` runs on each landed chunk *between* the chunked
@@ -192,7 +193,7 @@ class CollectiveTape:
             swap = lambda y: jnp.reshape(jnp.swapaxes(y, 0, 1),
                                          (y.shape[1], -1))
             buf2 = swap(rk)
-            vals2 = swap(rv) if rv is not None else None
+            vals2 = jax.tree.map(swap, rv) if rv is not None else None
             if pad is not None:
                 vrow = jnp.sum(
                     (buf2 < jnp.asarray(pad, buf2.dtype)).reshape(
@@ -211,7 +212,8 @@ class CollectiveTape:
         with self.phase(f"{phase_prefix} s2"):
             for j in range(chunks):
                 ck = lax.slice_in_dim(buf2, j * cc, (j + 1) * cc, axis=1)
-                cv = (lax.slice_in_dim(vals2, j * cc, (j + 1) * cc, axis=1)
+                cv = (jax.tree.map(lambda v: lax.slice_in_dim(
+                          v, j * cc, (j + 1) * cc, axis=1), vals2)
                       if vals2 is not None else None)
                 s = sent2 if j == 0 else jnp.zeros((), jnp.int32)
                 ok = self.all_to_all(ck, a2, sent=s, pad=pad)
@@ -224,7 +226,7 @@ class CollectiveTape:
     def ragged_all_to_all(self, operand, output, input_offsets, send_sizes,
                           output_offsets, recv_sizes, *, axis_name: str,
                           sent=None, track: bool = True):
-        out = compat.ragged_all_to_all(
+        out = lax.ragged_all_to_all(
             operand, output, input_offsets, send_sizes, output_offsets,
             recv_sizes, axis_name=axis_name)
         if track:

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .collectives import CollectiveTape
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY
@@ -274,7 +273,8 @@ class ShardMapSubstrate(Substrate):
     def __init__(self, *axes: AxisSpec, mesh=None, jit: bool = True):
         super().__init__(*axes)
         if mesh is None:
-            mesh = compat.make_mesh(self.shape, self.axis_names)
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh(self.shape, self.axis_names)
         self.mesh = mesh
         self._jit = jit
         # (shard_fn, arg signature) -> (jitted fn, tape).  jax.jit's own
@@ -311,9 +311,9 @@ class ShardMapSubstrate(Substrate):
                     return jax.tree.map(restore, (out, tape.traced()))
 
                 spec = P(*self.axis_names)
-                fn = compat.shard_map(wrapper, mesh=self.mesh,
-                                      in_specs=tuple(spec for _ in args),
-                                      out_specs=spec)
+                fn = jax.shard_map(wrapper, mesh=self.mesh,
+                                   in_specs=tuple(spec for _ in args),
+                                   out_specs=spec, check_vma=False)
                 if self._jit:
                     fn = jax.jit(fn, donate_argnums=donate)
                 cached = (fn, tape)

@@ -14,8 +14,7 @@ Two backends:
 * ``static``  — dense ``lax.all_to_all`` of (t, C/t) tiles padded with a
   sentinel.  Works under ``shard_map`` *and* ``vmap`` (used by unit tests).
 * ``ragged``  — ``lax.ragged_all_to_all`` with exact send sizes into a
-  C-sized output buffer.  shard_map only (and only on jax builds that ship
-  the op — see repro.cluster.compat); saves the padding bandwidth.
+  C-sized output buffer.  shard_map only; saves the padding bandwidth.
 
 Both report dropped-object counts so callers can detect capacity overflow —
 a fault, recovered by the CapacityPolicy retry loop in repro.cluster.
@@ -97,8 +96,9 @@ def build_send_buffer(x_sorted: jnp.ndarray, starts: jnp.ndarray,
 
     Returns (keys_buf, values_buf_or_None, dropped) where dropped counts
     objects beyond per-pair capacity (0 when capacity is adequate).
-    ``valid_len`` bounds the gather when ``x_sorted`` carries a padded
-    tail (segment indices never reach it — lens sum to valid_len).
+    ``values`` may be one array or a tuple of arrays, each with leading
+    dim m.  ``valid_len`` bounds the gather when ``x_sorted`` carries a
+    padded tail (segment indices never reach it — lens sum to valid_len).
     """
     t = starts.shape[0]
     m = valid_len if valid_len is not None else x_sorted.shape[0]
@@ -107,11 +107,13 @@ def build_send_buffer(x_sorted: jnp.ndarray, starts: jnp.ndarray,
     valid = cols[None, :] < lens[:, None]
     safe = jnp.clip(idx, 0, m - 1)
     keys = jnp.where(valid, x_sorted[safe], jnp.asarray(pad_key, x_sorted.dtype))
-    vals = None
-    if values is not None:
-        vals_g = values[safe]                                  # (t, C, ...)
-        mask = valid.reshape(t, cap_per_pair, *([1] * (values.ndim - 1)))
-        vals = jnp.where(mask, vals_g, jnp.zeros_like(vals_g))
+
+    def pack(v):
+        vals_g = v[safe]                                       # (t, C, ...)
+        mask = valid.reshape(t, cap_per_pair, *([1] * (v.ndim - 1)))
+        return jnp.where(mask, vals_g, jnp.zeros_like(vals_g))
+
+    vals = jax.tree.map(pack, values) if values is not None else None
     dropped = jnp.sum(jnp.maximum(lens - cap_per_pair, 0))
     return keys, vals, dropped
 
@@ -297,12 +299,25 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
     capacities are ceil(cap_factor*m / t1) and / t2 (m/sqrt(t)-scale
     pair loads), which is where the peak-receive win over the flat
     t * ceil(cap_factor*m / t) buffer comes from.
+
+    Values of tied keys leave in the flat path's order too: by source
+    machine, then by position in its sorted run.  The stable merges keep
+    ties in buffer order, which after two hops and chunked merges is
+    hop-major, so each object carries its flat origin id beside its
+    value and the landing is re-ordered by (key, origin) at the end.
     """
     a1, a2 = axis_names
     chunks = max(1, int(overlap_chunks))
     c1 = -(-int(cap_factor * m) // t1)
     c2 = -(-int(cap_factor * m) // t2)
     c2 = -(-c2 // chunks) * chunks
+    if values is not None:
+        rows = x_sorted.shape[0]
+        if t1 * t2 * rows > jnp.iinfo(jnp.int32).max:
+            raise ValueError(f"{t1 * t2} machines x {rows} rows overflow "
+                             "the int32 origin ids of the staged exchange")
+        src = lax.axis_index(a1) * t2 + lax.axis_index(a2)
+        values = (values, src * rows + jnp.arange(rows, dtype=jnp.int32))
     # group segmentation: group g's segment = flat segments [g*t2, (g+1)*t2)
     g_starts = starts[::t2]                                      # (t1,)
     g_ends = jnp.concatenate([starts[t2::t2],
@@ -353,11 +368,20 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
         stacked = jnp.stack([ck for ck, _ in outs])
         if values is not None:
             final_k, final_v = ops.merge_sorted_rows_kv(
-                stacked, jnp.stack([cv for _, cv in outs]),
+                stacked, jax.tree.map(lambda *cv: jnp.stack(cv),
+                                      *[cv for _, cv in outs]),
                 backend=kernel_backend)
         else:
             final_k = ops.merge_sorted_rows(stacked, backend=kernel_backend)
             final_v = None
+    if values is not None:
+        # (key, origin) order: stable sort by origin, then by key
+        final_v, origin = final_v
+        iota = jnp.arange(origin.shape[0], dtype=jnp.int32)
+        _, by_origin = ops.sort_kv(origin, iota, backend=kernel_backend)
+        final_k, perm = ops.sort_kv(final_k[by_origin], by_origin,
+                                    backend=kernel_backend)
+        final_v = final_v[perm]
     count = jnp.sum(final_k < jnp.asarray(PAD, final_k.dtype)
                     ).astype(jnp.int32)
     dropped = tape.psum(drop1 + aux["drop2"], (a1, a2)).astype(jnp.int32)

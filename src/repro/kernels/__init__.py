@@ -1,5 +1,7 @@
-"""Pallas TPU kernels for the compute hot spots (validated in interpret
-mode on CPU; Mosaic-compiled on real TPUs via ops.INTERPRET = False)."""
+"""Pallas TPU kernels for the compute hot spots.
+
+``ops`` dispatches to them with the kernel mode derived from the
+platform: Mosaic-compiled on a TPU, the Pallas interpreter on CPU."""
 from . import ops, ref
 from .bitonic import (bitonic_sort, bitonic_sort_kv, merge_sorted_rows,
                       sort_sentinel)

@@ -4,14 +4,20 @@ The paper's hot spot is the per-machine sort (O((n/t) log(n/t)) of the
 total cost).  On TPU the comparison network must be *vectorial*: a scalar
 heap/quicksort is hostile to the 8x128 VPU.  A bitonic network is branch-
 free, oblivious (fixed schedule — static shapes), and every compare-
-exchange substage is two full-width min/max over a relayout, which maps
-onto VREG shuffles.
+exchange substage is a few full-width selects over lane rotations.
 
 Layout choice: the network runs along the LAST (lane) dimension with the
-block resident in VMEM.  Distance-d partner exchange is expressed as a
-reshape (rows, n/(2d), 2, d) so no gathers are needed — Mosaic lowers the
-(2, d) split into sublane/lane rotations.  The direction bit of stage k
-depends only on the run index (position >> (k+1)), a broadcast compare.
+block resident in VMEM.  The partner of lane i at distance d is lane
+i ^ d, read through two lane rotations (``pltpu.roll`` by d and by n-d)
+and a select on bit d of the lane iota — no reshape, no gather, both of
+which Mosaic refuses at these shapes.  The direction bit of stage k is
+bit k+1 of the lane index, a broadcast compare.  Substages run in
+``fori_loop``s with a traced distance, so the kernel body stays small
+however long the row is.  Rows are padded to at least ``MIN_LANES``
+(one lane tile) so every rotation spans whole 128-lane vregs, and 16-bit
+keys are widened to 32 bits before the kernel and narrowed after
+(Mosaic rotates and dynamically slices 32-bit data only; the widening
+is exact and order-preserving).
 
 Three kernels:
 
@@ -29,9 +35,12 @@ Three kernels:
   whole-array block); inputs past one VMEM tile route to the
   rank-merge kernel in ``fused.py`` instead.
 
+Every block is a (1, rows, lanes) slice of a 3-D view whose last two
+dimensions are the whole array's, which satisfies Mosaic's (8, 128)
+block-tiling rule for any row count.
+
 Cost: for the m = n/t <= 64k row blocks SMMS uses, the whole row fits
-VMEM (64k f32 = 256 KiB << 16 MiB) and each kernel is memory-light (one
-HBM read + write per row).
+VMEM and each kernel is memory-light (one HBM read + write per row).
 
 Sentinel discipline: rows are padded to a power of two with the dtype's
 ``sort sentinel`` — +inf for floats, iinfo.max for ints — so padding
@@ -45,7 +54,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "bitonic_sort",
@@ -53,10 +64,23 @@ __all__ = [
     "merge_sorted_rows",
     "merge_sorted_rows_argsort",
     "sort_network_block",
-    "sort_network_block_kv",
     "merge_network_block",
     "sort_sentinel",
+    "MIN_LANES",
 ]
+
+# Narrowest kernel row: one full lane tile, so every lane rotation moves
+# whole vregs (rows are padded up to it with the sort sentinel).
+MIN_LANES = 128
+
+# Scoped-VMEM ceiling for the network kernels: a 64k-lane (key, value)
+# row pair keeps a handful of full-row temporaries live per substage,
+# past the compiler's 16 MiB default but well inside a v5e core's VMEM.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def sort_sentinel(dtype) -> jnp.ndarray:
@@ -67,143 +91,150 @@ def sort_sentinel(dtype) -> jnp.ndarray:
     return jnp.asarray(jnp.iinfo(dtype).max, dtype)
 
 
-def _compare_exchange(x, d: int, descending_runs: jnp.ndarray):
-    """One substage: exchange partners at distance d.
-
-    x: (rows, n). descending_runs: (n/(2d),) bool — per partner-group
-    direction (precomputed for this substage).  Swap-based rather than
-    min/max so a NaN never propagates to its partner: an unordered pair
-    simply doesn't swap, which preserves the input multiset (NaN keys
-    are outside the bitwise-parity contract but must not corrupt their
-    neighbours)."""
-    rows, n = x.shape
-    xr = x.reshape(rows, n // (2 * d), 2, d)
-    a = xr[:, :, 0, :]
-    b = xr[:, :, 1, :]
-    swap = (a > b) != descending_runs[None, :, None]
-    lo = jnp.where(swap, b, a)
-    hi = jnp.where(swap, a, b)
-    return jnp.stack([lo, hi], axis=2).reshape(rows, n)
+def widen(x: jnp.ndarray) -> jnp.ndarray:
+    """Exact, order-preserving 32-bit carrier for sub-32-bit keys."""
+    if jnp.dtype(x.dtype).itemsize >= 4:
+        return x
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return x.astype(jnp.float32)
+    return x.astype(jnp.int32)
 
 
-def _compare_exchange_kv(keys, vals, d: int, descending_runs):
-    """Lexicographic (key, value) compare-exchange at distance d."""
-    rows, n = keys.shape
-    kr = keys.reshape(rows, n // (2 * d), 2, d)
-    vr = vals.reshape(rows, n // (2 * d), 2, d)
-    ka, kb = kr[:, :, 0, :], kr[:, :, 1, :]
-    va, vb = vr[:, :, 0, :], vr[:, :, 1, :]
-    gt = (ka > kb) | ((ka == kb) & (va > vb))   # pair a sorts after pair b
-    swap = gt != descending_runs[None, :, None]
-    klo = jnp.where(swap, kb, ka)
-    khi = jnp.where(swap, ka, kb)
-    vlo = jnp.where(swap, vb, va)
-    vhi = jnp.where(swap, va, vb)
-    return (jnp.stack([klo, khi], axis=2).reshape(rows, n),
-            jnp.stack([vlo, vhi], axis=2).reshape(rows, n))
+def _lane_iota(shape) -> jnp.ndarray:
+    return lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
 
 
-def _directions(n: int, d: int, k: int) -> jnp.ndarray:
-    """Per partner-group descending bit for stage k, distance d."""
-    group = jnp.arange(n // (2 * d)) * (2 * d)      # first elt of each group
-    return ((group >> (k + 1)) & 1) == 1
+def _partner(x, d, upper):
+    """``x[..., i ^ d]`` for a power-of-two lane distance d (static or
+    traced): lanes with bit d clear read lane i + d, the others i - d.
+    ``pltpu.roll`` follows ``jnp.roll`` (lane i moves to i + shift)."""
+    n = x.shape[-1]
+    ax = x.ndim - 1
+    return jnp.where(upper, pltpu.roll(x, d, ax), pltpu.roll(x, n - d, ax))
 
 
-def sort_network_block(x: jnp.ndarray) -> jnp.ndarray:
-    """Full bitonic sort of each row of x: (rows, n), n a power of 2.
+def _select(chans, partners, upper, desc):
+    """One compare-exchange given each lane's partner values.
 
-    Pure jnp — usable inside a Pallas kernel body or standalone (this is
-    also what the kernel's interpret-mode path executes).
+    Lexicographic over the channels (keys first, then tie-breakers).
+    Swap-based rather than min/max so a NaN never propagates to its
+    partner: an unordered pair simply doesn't swap, which preserves the
+    input multiset (NaN keys are outside the bitwise-parity contract but
+    must not corrupt their neighbours)."""
+    gt = None
+    for c, p in zip(reversed(chans), reversed(partners)):
+        lo = jnp.where(upper, p, c)          # value at the pair's lower lane
+        hi = jnp.where(upper, c, p)
+        gt = lo > hi if gt is None else (lo > hi) | ((lo == hi) & gt)
+    swap = gt != desc
+    return tuple(jnp.where(swap, p, c) for c, p in zip(chans, partners))
+
+
+def _exchange(chans, d, lane, desc):
+    upper = (lane & d) != 0
+    return _select(chans, tuple(_partner(c, d, upper) for c in chans),
+                   upper, desc)
+
+
+def sort_network_block(*chans):
+    """Bitonic sort of each row of ``chans[0]``: (rows, n), n a power of 2.
+
+    Further channels (same shape) ride along and break key ties
+    lexicographically — ``(keys, arange)`` yields the stable argsort.
+    Kernel-body code: the lane rotations lower only inside a Pallas
+    kernel (compiled or interpreted).  Returns the tuple of channels.
     """
-    rows, n = x.shape
+    rows, n = chans[0].shape
     logn = int(math.log2(n))
     assert 1 << logn == n, "n must be a power of 2"
-    for k in range(logn):               # runs of length 2^(k+1) get sorted
-        for j in range(k, -1, -1):      # exchange distance 2^j
-            d = 1 << j
-            x = _compare_exchange(x, d, _directions(n, d, k))
-    return x
+    lane = _lane_iota(chans[0].shape)
+
+    def stage(k, chans):                 # runs of length 2^(k+1) get sorted
+        desc = (jnp.right_shift(lane, k + 1) & 1) == 1
+
+        def substage(s, chans):          # exchange distance 2^(k-s)
+            return _exchange(chans, jnp.left_shift(1, k - s), lane, desc)
+
+        return lax.fori_loop(0, k + 1, substage, chans)
+
+    return lax.fori_loop(0, logn, stage, tuple(chans))
 
 
-def sort_network_block_kv(keys: jnp.ndarray, vals: jnp.ndarray):
-    """Lexicographic (key, value) bitonic sort of each row.
+def merge_network_block(*chans, run: int):
+    """Merge rows of ``chans[0]`` whose length-``run`` chunks are each
+    sorted ascending (lexicographically with the tie channels).
 
-    keys/vals: (rows, n), n a power of 2.  Pure jnp — shared by the
-    ``bitonic_sort_kv`` kernel body and the fused sort+partition kernel
-    (``kernels/fused.py``), so the network cannot diverge between them.
+    (rows, n); n and run powers of 2, run divides n.  log2(n/run)
+    merge levels — n log n work instead of the full network's n log^2 n.
+    Each level first compares lane i with its mirror i ^ (2*lvl - 1)
+    inside every 2*lvl group (the "flip", built from log2(2*lvl) lane
+    butterflies), which leaves two bitonic halves; ascending half-cleaners
+    then finish the level.  Kernel-body code, like
+    :func:`sort_network_block`.
     """
-    rows, n = keys.shape
-    logn = int(math.log2(n))
-    assert 1 << logn == n, "n must be a power of 2"
-    for k in range(logn):
-        for j in range(k, -1, -1):
-            d = 1 << j
-            keys, vals = _compare_exchange_kv(keys, vals, d,
-                                              _directions(n, d, k))
-    return keys, vals
-
-
-def merge_network_block(x: jnp.ndarray, run: int) -> jnp.ndarray:
-    """Merge rows of x whose length-``run`` chunks are each sorted ascending.
-
-    x: (rows, n); n and run powers of 2, run divides n.  log2(n/run)
-    pairwise bitonic-merge levels — n log n work instead of the full
-    network's n log^2 n.  Pure jnp, usable inside a kernel body.
-    """
-    rows, n = x.shape
+    rows, n = chans[0].shape
+    lane = _lane_iota(chans[0].shape)
+    chans = tuple(chans)
     lvl = run
     while lvl < n:
-        xr = x.reshape(rows, n // (2 * lvl), 2, lvl)
-        a = xr[:, :, 0, :]
-        b = xr[:, :, 1, :][:, :, ::-1]          # reverse -> bitonic sequence
-        y = jnp.concatenate([a, b], axis=-1).reshape(rows, n)
-        d = lvl
-        while d >= 1:                            # all-ascending merge stages
-            y = _compare_exchange(y, d, jnp.zeros(n // (2 * d), bool))
-            d //= 2
-        x = y
+        def butterfly(b, ch):
+            d = jnp.left_shift(1, b)
+            upper = (lane & d) != 0
+            return tuple(_partner(c, d, upper) for c in ch)
+
+        mirrored = lax.fori_loop(0, int(math.log2(2 * lvl)), butterfly,
+                                 chans)
+        chans = _select(chans, mirrored, (lane & lvl) != 0, False)
+
+        def half_clean(s, ch, lvl=lvl):
+            return _exchange(ch, jnp.right_shift(lvl, s + 1), lane, False)
+
+        chans = lax.fori_loop(0, int(math.log2(lvl)), half_clean, chans)
         lvl *= 2
-    return x
+    return chans
 
 
-def _sort_kernel(x_ref, o_ref):
-    o_ref[...] = sort_network_block(x_ref[...])
+def _network_kernel(*refs, network):
+    """Run ``network`` over the block: channel refs in, as many out."""
+    k = len(refs) // 2
+    for o_ref, v in zip(refs[k:], network(*(r[...] for r in refs[:k]))):
+        o_ref[...] = v
 
 
-def _sort_kv_kernel(k_ref, v_ref, ok_ref, ov_ref):
-    keys, vals = sort_network_block_kv(k_ref[...], v_ref[...])
-    ok_ref[...] = keys
-    ov_ref[...] = vals
-
-
-def _merge_kernel(x_ref, o_ref, *, run: int):
-    o_ref[...] = merge_network_block(x_ref[...], run)
-
-
-def _merge_kv_kernel(k_ref, v_ref, ok_ref, ov_ref, *, run: int):
-    keys = k_ref[...]
-    vals = v_ref[...]
-    rows, n = keys.shape
-    lvl = run
-    while lvl < n:
-        kr = keys.reshape(rows, n // (2 * lvl), 2, lvl)
-        vr = vals.reshape(rows, n // (2 * lvl), 2, lvl)
-        keys = jnp.concatenate([kr[:, :, 0, :], kr[:, :, 1, :][:, :, ::-1]],
-                               axis=-1).reshape(rows, n)
-        vals = jnp.concatenate([vr[:, :, 0, :], vr[:, :, 1, :][:, :, ::-1]],
-                               axis=-1).reshape(rows, n)
-        d = lvl
-        while d >= 1:
-            keys, vals = _compare_exchange_kv(
-                keys, vals, d, jnp.zeros(n // (2 * d), bool))
-            d //= 2
-        lvl *= 2
-    ok_ref[...] = keys
-    ov_ref[...] = vals
+_sort_kernel = functools.partial(_network_kernel, network=sort_network_block)
 
 
 def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
+
+
+def _row_blocks(kernel, chans, block_rows: int, interpret: bool, name: str):
+    """Run ``kernel`` over (rows, n) channels in (block_rows, n) blocks.
+
+    The channels are viewed as (groups, block_rows, n) so every block
+    spans the trailing two dimensions whole, and carried as 32-bit
+    (:func:`widen`) through the kernel.
+    """
+    rows, n = chans[0].shape
+    views = [widen(c).reshape(rows // block_rows, block_rows, n)
+             for c in chans]
+    spec = pl.BlockSpec((None, block_rows, n), lambda i: (i, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid=(rows // block_rows,),
+        in_specs=[spec] * len(chans),
+        out_specs=tuple([spec] * len(chans)),
+        out_shape=tuple(jax.ShapeDtypeStruct(v.shape, v.dtype)
+                        for v in views),
+        compiler_params=compiler_params(),
+        interpret=interpret,
+        name=name,
+    )(*views)
+    return [o.reshape(rows, n).astype(c.dtype) for o, c in zip(out, chans)]
+
+
+def _padded_width(n: int) -> int:
+    return max(MIN_LANES, _next_pow2(n))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -211,24 +242,20 @@ def bitonic_sort(x: jnp.ndarray, block_rows: int = 8,
                  interpret: bool = True) -> jnp.ndarray:
     """Row-wise ascending sort via the Pallas bitonic kernel.
 
-    x: (rows, n).  n is padded to a power of 2 with the dtype's sort
-    sentinel (stripped after).  interpret=True validates on CPU; on TPU
-    pass interpret=False.
+    x: (rows, n).  n is padded to a power of 2 (at least ``MIN_LANES``)
+    with the dtype's sort sentinel, stripped after.  ``interpret=True``
+    runs the kernel body through the Pallas interpreter (the CPU path);
+    ``repro.kernels.ops`` derives the flag from the platform.
     """
     rows, n = x.shape
-    np2 = max(2, _next_pow2(n))
+    np2 = _padded_width(n)
+    block_rows = min(block_rows, rows)
     rpad = (-rows) % block_rows
-    big = sort_sentinel(x.dtype)
     xp = (x if rpad == 0 and np2 == n else
-          jnp.pad(x, ((0, rpad), (0, np2 - n)), constant_values=big))
-    out = pl.pallas_call(
-        _sort_kernel,
-        grid=((rows + rpad) // block_rows,),
-        in_specs=[pl.BlockSpec((block_rows, np2), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, np2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
-        interpret=interpret,
-    )(xp)
+          jnp.pad(x, ((0, rpad), (0, np2 - n)),
+                  constant_values=sort_sentinel(x.dtype)))
+    (out,) = _row_blocks(_sort_kernel, [xp], block_rows, interpret,
+                         "bitonic_sort")
     return out[:rows, :n]
 
 
@@ -241,7 +268,8 @@ def bitonic_sort_kv(keys: jnp.ndarray, values: jnp.ndarray,
     the stable argsort permutation in the value channel.
     """
     rows, n = keys.shape
-    np2 = max(2, _next_pow2(n))
+    np2 = _padded_width(n)
+    block_rows = min(block_rows, rows)
     rpad = (-rows) % block_rows
     if rpad == 0 and np2 == n:
         kp, vp = keys, values
@@ -250,24 +278,30 @@ def bitonic_sort_kv(keys: jnp.ndarray, values: jnp.ndarray,
                      constant_values=sort_sentinel(keys.dtype))
         vp = jnp.pad(values, ((0, rpad), (0, np2 - n)),
                      constant_values=sort_sentinel(values.dtype))
-    spec = pl.BlockSpec((block_rows, np2), lambda i: (i, 0))
-    ok, ov = pl.pallas_call(
-        _sort_kv_kernel,
-        grid=((rows + rpad) // block_rows,),
-        in_specs=[spec, spec],
-        out_specs=(spec, spec),
-        out_shape=(jax.ShapeDtypeStruct(kp.shape, keys.dtype),
-                   jax.ShapeDtypeStruct(vp.shape, values.dtype)),
-        interpret=interpret,
-    )(kp, vp)
+    ok, ov = _row_blocks(_sort_kernel, [kp, vp], block_rows, interpret,
+                         "bitonic_sort_kv")
     return ok[:rows, :n], ov[:rows, :n]
 
 
-def _pad_sorted_rows(x: jnp.ndarray, sentinel) -> jnp.ndarray:
-    """Pad (t, c) sorted rows to (pow2, pow2) — rows stay sorted."""
-    t, c = x.shape
+def merge_padded_shape(t: int, c: int):
+    """(rows, cols) the merge kernels pad t sorted rows of c to.
+
+    Both powers of two; cols widens past the row's own power of two
+    until the flat total spans at least one lane tile (``MIN_LANES``).
+    ``ops`` sizes its eligibility gate with the same numbers.
+    """
     tp2 = max(1, _next_pow2(t))
     cp2 = max(2, _next_pow2(c))
+    while tp2 * cp2 < MIN_LANES:
+        cp2 *= 2
+    return tp2, cp2
+
+
+def _pad_sorted_rows(x: jnp.ndarray, sentinel) -> jnp.ndarray:
+    """Pad (t, c) sorted rows to :func:`merge_padded_shape` — rows stay
+    sorted."""
+    t, c = x.shape
+    tp2, cp2 = merge_padded_shape(t, c)
     return jnp.pad(x, ((0, tp2 - t), (0, cp2 - c)), constant_values=sentinel)
 
 
@@ -304,31 +338,17 @@ def _merge_levels(kp: jnp.ndarray, ip, run: int, interpret: bool):
     optional tie-break/permutation channel merged lexicographically.
     """
     rows, c = kp.shape
+    chans = [kp] if ip is None else [kp, ip]
     while rows > 1:
         rpb = min(rows, max(2, MERGE_TILE_LANES // c))
         nb = rows // rpb
-        kflat = kp.reshape(nb, rpb * c)
-        spec = pl.BlockSpec((1, rpb * c), lambda i: (i, 0))
-        if ip is None:
-            kflat = pl.pallas_call(
-                functools.partial(_merge_kernel, run=c),
-                grid=(nb,), in_specs=[spec], out_specs=spec,
-                out_shape=jax.ShapeDtypeStruct(kflat.shape, kp.dtype),
-                interpret=interpret,
-            )(kflat)
-        else:
-            iflat = ip.reshape(nb, rpb * c)
-            kflat, iflat = pl.pallas_call(
-                functools.partial(_merge_kv_kernel, run=c),
-                grid=(nb,), in_specs=[spec, spec], out_specs=(spec, spec),
-                out_shape=(jax.ShapeDtypeStruct(kflat.shape, kp.dtype),
-                           jax.ShapeDtypeStruct(iflat.shape, jnp.int32)),
-                interpret=interpret,
-            )(kflat, iflat)
-            ip = iflat
-        kp = kflat
+        merge = functools.partial(merge_network_block, run=c)
+        chans = _row_blocks(functools.partial(_network_kernel, network=merge),
+                            [ch.reshape(nb, rpb * c) for ch in chans], 1,
+                            interpret, "bitonic_merge")
         rows, c = nb, rpb * c
-    return kp.reshape(-1), (None if ip is None else ip.reshape(-1))
+    flat = [ch.reshape(-1) for ch in chans]
+    return flat[0], (None if ip is None else flat[1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

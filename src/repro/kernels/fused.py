@@ -7,24 +7,29 @@ with its own pad-to-pow2 / unpad round trip through HBM.  This module
 holds the fused alternatives (the FlashAttention treatment applied to
 the shuffle pipeline):
 
-* ``sort_partition``    — bitonic-sort a block AND binary-search the t-1
-  destination boundaries over the freshly sorted block in the same
-  kernel pass.  One HBM read, one write, zero intermediate
-  materialization.  Used by Terasort's Round 3 (sort and partition are
-  adjacent there; SMMS sorts in Round 1, before the sample gather, so
-  only its partition half can fuse).
+* ``sort_partition``    — bitonic-sort a block AND count the sorted keys
+  below each of the t-1 destination boundaries over the freshly sorted
+  block in the same kernel pass.  One HBM read, one write, zero
+  intermediate materialization.  Used by Terasort's Round 3 (sort and
+  partition are adjacent there; SMMS sorts in Round 1, before the sample
+  gather, so only its partition half can fuse).
 * ``sort_partition_kv`` — the payload-carrying variant: lexicographic
   (key, iota) pair sort (= the *stable* argsort permutation, bitwise)
   plus the same in-kernel boundary search.  Used by RandJoin's
   tuple-to-interval routing.
 * ``merge_ranks``       — the scale-out path for merging sorted rows
   that do NOT fit one VMEM tile: every element's final position is its
-  rank in the global lexicographic (key, flat-index) order, computed as
-  a sum of per-row branch-free binary searches.  The grid is
-  (query rows × query blocks × bound rows) with the rank accumulated
-  across the (sequential) bound-row axis, so each block touches only
-  one row pair at a time — per-block VMEM is O(row), not O(t·row).
-  A host-side scatter places keys (and the stable permutation) by rank.
+  rank in the global lexicographic (key, flat-index) order, a sum over
+  bound rows of how many of the row's pairs sort below it.  The grid is
+  (query rows × query blocks × bound rows × bound blocks) with the rank
+  accumulated across the (sequential) bound axes, so each step touches
+  one query block and one bound block.  A host-side scatter places keys
+  (and the stable permutation) by rank.
+
+The searches are ``bucketize``'s compare-and-count (no in-kernel
+gather).  In the rank merge the query block is a sorted run, so the
+chunk-range pruning leaves about block/128 + 2 bound chunks per step:
+near-linear work rather than a full cross product.
 
 Sentinel discipline matches ``bitonic.py``: padding uses the dtype's
 sort sentinel for keys and *unique* continuation ids for the index
@@ -39,34 +44,60 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitonic import (_next_pow2, sort_network_block, sort_network_block_kv,
-                      sort_sentinel)
-from .bucketize import _bin_search_block
+from .bitonic import (MIN_LANES, _next_pow2, compiler_params,
+                      sort_network_block, sort_sentinel, widen)
+from .bucketize import (CHUNK, chunk_range, count_below, dense_chunk,
+                        key_less, pair_less)
 
 __all__ = ["sort_partition", "sort_partition_kv", "merge_ranks"]
 
 
-def _sort_partition_kernel(x_ref, q_ref, xs_ref, cuts_ref, *, m: int):
-    """Sort the row, then count sorted elements < each query (side='left')."""
-    xs = sort_network_block(x_ref[...])
-    xs_ref[...] = xs
-    cuts_ref[...] = _bin_search_block(q_ref[...], xs, m, "left")
+def _sort_partition_kernel(*refs, m: int, n_chans: int):
+    """Sort the row's channels, then count sorted keys < each query."""
+    ins, q_ref = refs[:n_chans], refs[n_chans]
+    outs, cuts_ref = refs[n_chans + 1:2 * n_chans + 1], refs[-1]
+    for o_ref, v in zip(outs, sort_network_block(*(r[...] for r in ins))):
+        o_ref[...] = v
+    ks = outs[0]
 
+    def chunk(c):
+        return (ks[:, pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)],)
 
-def _sort_partition_kv_kernel(k_ref, i_ref, q_ref, ks_ref, order_ref,
-                              cuts_ref, *, m: int):
-    """Lexicographic (key, iota) sort + in-kernel boundary search."""
-    keys, vals = sort_network_block_kv(k_ref[...], i_ref[...])
-    ks_ref[...] = keys
-    order_ref[...] = vals
-    cuts_ref[...] = _bin_search_block(q_ref[...], keys, m, "left")
+    cnt = count_below((q_ref[...],), chunk, key_less("left"), 0,
+                      ks.shape[-1] // CHUNK)
+    cuts_ref[...] = jnp.minimum(cnt, m)
 
 
 def _pad_row(x: jnp.ndarray, fill) -> jnp.ndarray:
-    """(n,) -> (1, pow2) padded with ``fill`` (min width 2)."""
+    """(n,) -> (1, pow2 >= MIN_LANES) padded with ``fill``."""
     n = x.shape[0]
-    p = max(2, _next_pow2(n))
+    p = max(MIN_LANES, _next_pow2(n))
     return jnp.pad(x, (0, p - n), constant_values=fill)[None, :]
+
+
+def _sort_partition_call(chans, queries, interpret: bool):
+    """One-block fused sort + left count; returns (sorted chans, cuts).
+
+    Keys travel as 32-bit (:func:`widen`) through the kernel."""
+    m = chans[0].shape[0]
+    nq = queries.shape[0]
+    rows = [_pad_row(w, sort_sentinel(w.dtype)) for w in map(widen, chans)]
+    qc = jnp.pad(widen(queries), (0, (-nq) % 8), mode="edge")[:, None]
+    full = lambda a: pl.BlockSpec(a.shape, lambda i: (0,) * a.ndim)
+    out = pl.pallas_call(
+        functools.partial(_sort_partition_kernel, m=m, n_chans=len(rows)),
+        grid=(1,),
+        in_specs=[full(r) for r in rows] + [full(qc)],
+        out_specs=tuple(full(r) for r in rows) + (full(qc),),
+        out_shape=tuple(jax.ShapeDtypeStruct(r.shape, r.dtype)
+                        for r in rows)
+        + (jax.ShapeDtypeStruct(qc.shape, jnp.int32),),
+        compiler_params=compiler_params(),
+        interpret=interpret,
+        name=f"sort_partition{'_kv' if len(rows) > 1 else ''}",
+    )(*rows, qc)
+    return ([o[0, :m].astype(c.dtype) for o, c in zip(out[:-1], chans)],
+            out[-1][:nq, 0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -80,22 +111,8 @@ def sort_partition(x: jnp.ndarray, queries: jnp.ndarray,
     bitwise equal to the unfused ``ops.sort`` → ``ops.searchsorted``
     pipeline, in ONE kernel dispatch.
     """
-    m = x.shape[0]
-    nq = queries.shape[0]
-    xp = _pad_row(x, sort_sentinel(x.dtype))
-    qp = _pad_row(queries, sort_sentinel(queries.dtype))
-    xs, cuts = pl.pallas_call(
-        functools.partial(_sort_partition_kernel, m=m),
-        grid=(1,),
-        in_specs=[pl.BlockSpec(xp.shape, lambda i: (0, 0)),
-                  pl.BlockSpec(qp.shape, lambda i: (0, 0))],
-        out_specs=(pl.BlockSpec(xp.shape, lambda i: (0, 0)),
-                   pl.BlockSpec(qp.shape, lambda i: (0, 0))),
-        out_shape=(jax.ShapeDtypeStruct(xp.shape, x.dtype),
-                   jax.ShapeDtypeStruct(qp.shape, jnp.int32)),
-        interpret=interpret,
-    )(xp, qp)
-    return xs[0, :m], cuts[0, :nq]
+    (xs,), cuts = _sort_partition_call([x], queries, interpret)
+    return xs, cuts
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -109,127 +126,45 @@ def sort_partition_kv(keys: jnp.ndarray, queries: jnp.ndarray,
     realized by the lexicographic (key, iota) network) and ``cuts`` is
     the left-searchsorted of the queries over the sorted keys.
     """
-    m = keys.shape[0]
-    nq = queries.shape[0]
-    kp = _pad_row(keys, sort_sentinel(keys.dtype))
-    iota = jnp.arange(m, dtype=jnp.int32)
-    ip = _pad_row(iota, sort_sentinel(jnp.int32))
-    qp = _pad_row(queries, sort_sentinel(queries.dtype))
-    ks, order, cuts = pl.pallas_call(
-        functools.partial(_sort_partition_kv_kernel, m=m),
-        grid=(1,),
-        in_specs=[pl.BlockSpec(kp.shape, lambda i: (0, 0)),
-                  pl.BlockSpec(ip.shape, lambda i: (0, 0)),
-                  pl.BlockSpec(qp.shape, lambda i: (0, 0))],
-        out_specs=(pl.BlockSpec(kp.shape, lambda i: (0, 0)),
-                   pl.BlockSpec(ip.shape, lambda i: (0, 0)),
-                   pl.BlockSpec(qp.shape, lambda i: (0, 0))),
-        out_shape=(jax.ShapeDtypeStruct(kp.shape, keys.dtype),
-                   jax.ShapeDtypeStruct(ip.shape, jnp.int32),
-                   jax.ShapeDtypeStruct(qp.shape, jnp.int32)),
-        interpret=interpret,
-    )(kp, ip, qp)
-    return ks[0, :m], order[0, :m], cuts[0, :nq]
+    iota = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    (ks, order), cuts = _sort_partition_call([keys, iota], queries,
+                                             interpret)
+    return ks, order, cuts
 
 
 # ---------------------------------------------------------------------------
 # rank-based merge: sorted rows too large for one VMEM tile
 # ---------------------------------------------------------------------------
 
-def _bin_search_pairs_block(qk, qi, bk, bi, n_bounds: int) -> jnp.ndarray:
-    """Count pairs (bk, bi) lexicographically < (qk, qi), per query.
+def _pair_bounds(keys, ids):
+    """Lexicographic (min, max) pairs of a (key, id) column: the extreme
+    key, then the extreme id among the rows holding it."""
+    kmin, kmax = jnp.min(keys), jnp.max(keys)
+    imin = jnp.min(jnp.where(keys == kmin, ids, jnp.iinfo(jnp.int32).max))
+    imax = jnp.max(jnp.where(keys == kmax, ids, jnp.iinfo(jnp.int32).min))
+    return (kmin, imin), (kmax, imax)
 
-    qk/qi: (1, block_n) query keys + tie-break ids; bk/bi: (1, P) one
-    bound row whose (key, id) pairs are strictly increasing (keys sorted
-    ascending, ids unique and ascending within equal keys).  Branch-free
-    binary search over the n_bounds+1 possible answers, mirroring
-    ``bucketize._bin_search_block``.
+
+def _rank_kernel(qk_ref, qi_ref, bk_ref, bi_ref, pos_ref):
+    """Accumulate one bound block's contribution to the query block's rank.
+
+    Grid axes 2 and 3 walk the bound rows and their column blocks
+    sequentially; the output block is revisited (same index map every
+    step) and accumulated, initialized on the first step.  A row's
+    contribution is the count of its pairs below each query, and counts
+    are additive over any column partition of the row.  Searching a row
+    against itself contributes the element's own in-row position (pairs
+    are strictly increasing), so no self-row special case is needed.
     """
-    lo = jnp.zeros(qk.shape, jnp.int32)
-    hi = jnp.full(qk.shape, n_bounds, jnp.int32)
-    steps = max(1, math.ceil(math.log2(n_bounds + 1)))
-    for _ in range(steps):
-        mid = jnp.minimum((lo + hi) // 2, n_bounds - 1)
-        k_mid = jnp.take_along_axis(bk, mid, axis=-1)
-        i_mid = jnp.take_along_axis(bi, mid, axis=-1)
-        pred = (k_mid < qk) | ((k_mid == qk) & (i_mid < qi))
-        go_right = pred & (lo < hi)
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-        hi = jnp.maximum(hi, lo)
-    return lo
-
-
-def _rank_kernel(qk_ref, qi_ref, bk_ref, bi_ref, pos_ref, *, c: int):
-    """Accumulate one bound-row's contribution to the query block's rank.
-
-    Grid axis 2 walks the bound rows sequentially; the output block is
-    revisited (same index map every step) and accumulated, initialized
-    on the first step.  Searching a row against itself contributes the
-    element's own in-row position (pairs are strictly increasing), so
-    no self-row special case is needed.
-    """
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
+    @pl.when((pl.program_id(2) == 0) & (pl.program_id(3) == 0))
     def _init():
         pos_ref[...] = jnp.zeros_like(pos_ref)
 
-    pos_ref[...] += _bin_search_pairs_block(
-        qk_ref[...], qi_ref[...], bk_ref[...], bi_ref[...], c)
-
-
-def _bin_search_pairs_bounded(qk, qi, bk, bi, n_valid, steps: int
-                              ) -> jnp.ndarray:
-    """Count pairs in ONE bound block lexicographically < each query.
-
-    The blocked twin of :func:`_bin_search_pairs_block`: ``bk``/``bi``
-    is a (1, bb) column slice of a bound row and ``n_valid`` (traced)
-    is how many of its slots are real.  ``steps`` is static (sized for
-    the full block); once lo == hi the extra iterations are saturated
-    no-ops, so a short tail block just wastes a few compares.  The mid
-    clamp keeps the gather in-range even for an empty block (n_valid=0,
-    where lo == hi == 0 from the start and the probe result is unused).
-    """
-    lo = jnp.zeros(qk.shape, jnp.int32)
-    hi = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), qk.shape)
-    width = bk.shape[-1]
-    for _ in range(steps):
-        mid = jnp.clip((lo + hi) // 2, 0, width - 1)
-        k_mid = jnp.take_along_axis(bk, mid, axis=-1)
-        i_mid = jnp.take_along_axis(bi, mid, axis=-1)
-        pred = (k_mid < qk) | ((k_mid == qk) & (i_mid < qi))
-        go_right = pred & (lo < hi)
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-        hi = jnp.maximum(hi, lo)
-    return lo
-
-
-def _rank_kernel_blocked(qk_ref, qi_ref, bk_ref, bi_ref, pos_ref, *, c: int,
-                         bb: int, steps: int):
-    """Rank accumulation with the bound rows blocked along columns.
-
-    Grid: (query rows, query blocks, bound rows, bound blocks).  A
-    row's contribution to a query's rank is the count of its pairs <
-    the query, and counts are additive over any column partition of the
-    (sorted) row — so each (bound row, bound block) pair adds its own
-    bounded search result.  Per-step VMEM is O(bb) instead of O(row):
-    the Pallas pipeline double-buffers the (1, bb) bound blocks, DMA-ing
-    block b+1 while block b is being searched — the overlap that lets
-    the staged exchange's chunked merges proceed while later chunks are
-    still in flight.
-    """
-    k = pl.program_id(2)
-    blk = pl.program_id(3)
-
-    @pl.when((k == 0) & (blk == 0))
-    def _init():
-        pos_ref[...] = jnp.zeros_like(pos_ref)
-
-    valid = jnp.clip(c - blk * bb, 0, bb)
-    pos_ref[...] += _bin_search_pairs_bounded(
-        qk_ref[...], qi_ref[...], bk_ref[...], bi_ref[...], valid, steps)
+    qk, qi = qk_ref[...], qi_ref[...]                 # (bn, 1) columns
+    q_min, q_max = _pair_bounds(qk, qi)
+    lo, hi = chunk_range((bk_ref, bi_ref), pair_less, q_min, q_max)
+    pos_ref[...] += count_below((qk, qi), dense_chunk((bk_ref, bi_ref)),
+                                pair_less, lo, hi)
 
 
 @functools.partial(jax.jit,
@@ -246,52 +181,46 @@ def merge_ranks(keys: jnp.ndarray, ids: jnp.ndarray, block_n: int = 1024,
     permutation of [0, t*c) because the pairs are globally unique.
 
     ``bound_block=None`` holds each full bound row in VMEM per grid
-    step.  An int blocks the bound rows into (1, bound_block) slices on
-    a fourth grid axis — the double-buffered variant: per-step VMEM
-    drops to O(bound_block) and the pipeline overlaps each block's DMA
-    with the previous block's search.  Ranks are bitwise identical
+    step.  An int blocks the bound rows into column slices of about
+    ``bound_block`` pairs (rounded up to whole (8, 128) tiles) on a
+    fourth grid axis, which the Pallas pipeline double-buffers; per-step
+    VMEM then drops to O(bound_block).  Ranks are bitwise identical
     either way (counts are additive over the column partition).
     """
     t, c = keys.shape
-    bn = min(block_n, c)
-    bb = None if bound_block is None else min(int(bound_block), c)
-    # pad so both the query blocking and (if any) the bound blocking
-    # divide the width; never hit by the ops dispatcher (c is pow2 and
-    # the block sizes are pow2), guarded for direct callers
-    width = -(-c // bn) * bn
-    if bb is not None:
-        width = -(-width // bb) * bb
+    keys = widen(keys)                  # 32-bit through the kernel
+    width = -(-c // CHUNK) * CHUNK
+    bn = min(-(-block_n // CHUNK) * CHUNK, width)
+    width = -(-width // bn) * bn
+    bb = width
+    if bound_block is not None:
+        tile = 8 * CHUNK                    # one (8, 128) tile of bounds
+        bb = min(-(-int(bound_block) // tile) * tile, width)
+        width = -(-width // math.lcm(bn, bb)) * math.lcm(bn, bb)
+        bb = min(bb, width)
+    # pad so both the query blocking and the bound blocking divide the
+    # width; never hit by the ops dispatcher (c is pow2 and the block
+    # sizes are pow2), guarded for direct callers
     pad = width - c
     if pad:
         keys = jnp.pad(keys, ((0, 0), (0, pad)),
                        constant_values=sort_sentinel(keys.dtype))
         ids = jnp.pad(ids, ((0, 0), (0, pad)),
                       constant_values=jnp.iinfo(jnp.int32).max)
-    cb = keys.shape[1] // bn
-    if bb is None:
-        pos = pl.pallas_call(
-            functools.partial(_rank_kernel, c=c),
-            grid=(t, cb, t),
-            in_specs=[pl.BlockSpec((1, bn), lambda i, j, k: (i, j)),
-                      pl.BlockSpec((1, bn), lambda i, j, k: (i, j)),
-                      pl.BlockSpec((1, keys.shape[1]), lambda i, j, k: (k, 0)),
-                      pl.BlockSpec((1, ids.shape[1]), lambda i, j, k: (k, 0))],
-            out_specs=pl.BlockSpec((1, bn), lambda i, j, k: (i, j)),
-            out_shape=jax.ShapeDtypeStruct(keys.shape, jnp.int32),
-            interpret=interpret,
-        )(keys, ids, keys, ids)
-        return pos[:, :c]
-    nb = keys.shape[1] // bb
-    steps = max(1, math.ceil(math.log2(bb + 1)))
+    ids = ids.astype(jnp.int32)
+    col = pl.BlockSpec((None, bn, 1), lambda i, j, k, b: (i, j, 0))
+    row = pl.BlockSpec((None, bb // CHUNK, CHUNK),
+                       lambda i, j, k, b: (k, b, 0))
     pos = pl.pallas_call(
-        functools.partial(_rank_kernel_blocked, c=c, bb=bb, steps=steps),
-        grid=(t, cb, t, nb),
-        in_specs=[pl.BlockSpec((1, bn), lambda i, j, k, b: (i, j)),
-                  pl.BlockSpec((1, bn), lambda i, j, k, b: (i, j)),
-                  pl.BlockSpec((1, bb), lambda i, j, k, b: (k, b)),
-                  pl.BlockSpec((1, bb), lambda i, j, k, b: (k, b))],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j, k, b: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(keys.shape, jnp.int32),
+        _rank_kernel,
+        grid=(t, width // bn, t, width // bb),
+        in_specs=[col, col, row, row],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((t, width, 1), jnp.int32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(keys, ids, keys, ids)
-    return pos[:, :c]
+        name="merge_ranks",
+    )(keys[..., None], ids[..., None],
+      keys.reshape(t, width // CHUNK, CHUNK),
+      ids.reshape(t, width // CHUNK, CHUNK))
+    return pos[:, :c, 0]

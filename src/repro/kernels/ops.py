@@ -11,23 +11,18 @@ picks one of two backends:
 * ``"pallas"``    — the purpose-built kernels in ``bitonic.py`` /
   ``radix.py`` / ``bucketize.py`` / ``fused.py``, with the dispatch
   layer handling pad-to-pow2 with sort sentinels, key/index packing for
-  stable payload sorts, dtype and shape eligibility checks, and
-  **automatic fallback** to the reference for anything a kernel cannot
-  take (exotic dtypes, >2D operands, rows too long for VMEM residency).
+  stable payload sorts, and the dtype and shape eligibility gate
+  (:func:`kernel_eligible`).  The reference is taken only for operands
+  that gate refuses (exotic dtypes, >2D operands, rows too long for VMEM
+  residency) and the refusal ticks a ``"reference"`` dispatch; an
+  eligible operand always runs a kernel, never a silent fallback.
 
-Within the pallas backend, the sort family picks between two kernel
-*families* (``sort_kernel_choice``): the bitonic network (short rows —
-n log^2 n compare-exchanges, but every substage is pure SIMD min/max)
-and the LSD radix kernel (wide rows on compiled backends — pass count
-scales with the key *width*, so bf16 crosses over earlier than
-float32/int32).  The crossover constants (``RADIX_MIN_LANES``,
-``RADIX_PASS_SUBSTAGES``) are calibrated from ``benchmarks/bench_sort``:
-on this host container the interpret-mode bench shows the counting
-passes lose outright (XLA-CPU emulates the in-kernel scatter
-scalar-wise, ~30x over the network), so in interpret mode the choice
-stays bitonic and the radix family engages on compiled accelerator
-backends — or explicitly via :func:`force_sort_kernel` (tests, budget
-benches).  Radix dispatches tick ``DISPATCH_COUNTS[(op, "radix")]`` so
+Within the pallas backend the sort family is the bitonic network
+(``sort_kernel_choice``).  The LSD radix kernel runs only under
+:func:`force_sort_kernel` (tests, budget benches): its in-kernel scatter
+and gather have no Mosaic lowering, so it cannot run on TPU, and the
+CPU interpreter emulates the scatter scalar-wise (~30x over the
+network).  Radix dispatches tick ``DISPATCH_COUNTS[(op, "radix")]`` so
 the fusion budgets stay enforceable per family.
 
 Dispatch-count economy: the fused ``sort_partition[_kv]`` collapses the
@@ -50,10 +45,11 @@ values) while jnp.sort moves NaNs last.  The radix path's contract is
 strictly wider: NaNs canonicalize to the all-ones key bits, so they
 sort last in input order — full jnp.sort parity, NaNs included.
 
-``backend=None`` resolves to the module default (``DEFAULT_BACKEND``,
-seeded from the ``REPRO_KERNEL_BACKEND`` env var, ``"reference"`` when
-unset) so a whole test run can be flipped to the kernel path without
-touching call sites.
+``backend=None`` resolves to the module default: ``DEFAULT_BACKEND``
+(seeded from the ``REPRO_KERNEL_BACKEND`` env var) when set, otherwise
+the platform's own — ``"pallas"`` on TPU, ``"reference"`` elsewhere —
+so a whole test run can be flipped to the kernel path without touching
+call sites.
 
 Dispatch accounting — TWO counters with different semantics:
 
@@ -75,10 +71,10 @@ Dispatch accounting — TWO counters with different semantics:
 With tracing active (``repro.obs.trace``), every dispatch decision also
 lands as a ``kernel_dispatch`` event on the enclosing span.
 
-On this CPU container the kernels run with interpret=True (the kernel
-body executes in Python/XLA on CPU — correctness path).  On a real TPU
-runtime set ``repro.kernels.ops.INTERPRET = False`` (or export
-``REPRO_PALLAS_INTERPRET=0``) and the same calls compile with Mosaic.
+Kernel mode follows the platform, resolved at the first dispatch (never
+at import): on CPU the kernels run through the Pallas interpreter (the
+correctness path the test suites use), on TPU they compile with Mosaic.
+:func:`platform` is the one place that asks JAX.
 """
 from __future__ import annotations
 
@@ -97,14 +93,15 @@ from .radix import key_to_bits, bits_to_key
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
-
 BACKENDS = ("reference", "pallas")
-DEFAULT_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "reference")
+# Explicit process-wide default; None = the platform's own
+# (:func:`platform_backend`).
+DEFAULT_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND")
 
-# Largest padded lane count a VMEM-resident kernel row may occupy (64k f32
-# = 256 KiB, comfortably under the ~16 MiB VMEM budget with headroom for
-# double buffering).  Longer rows fall back to the reference path.
+# Largest padded lane count a VMEM-resident kernel row may occupy: every
+# main-path kernel compiles for v5e at this bound (tests/test_tpu_compile.py)
+# under the kernels' 64 MiB scoped-VMEM limit.  Longer rows take the
+# reference path.
 MAX_KERNEL_LANES = 1 << 16
 
 # Bound-row rows longer than this make the rank-merge kernel block its
@@ -113,28 +110,7 @@ MAX_KERNEL_LANES = 1 << 16
 # O(block) instead of O(row).
 RANK_MERGE_BOUND_BLOCK = 1 << 11
 
-# ---- sort-family cost-model split (bitonic network vs LSD radix) ----
-# Digits per counting pass (16 bins): 8 passes for 32-bit keys, 4 for
-# bf16 — the key-specialization payoff (radix.key_to_bits).
-RADIX_BITS = radix.DEFAULT_RADIX_BITS
-# Rows narrower than this never pick radix: below it the whole bitonic
-# network is a handful of VREG-resident substages and the counting
-# pass's fixed costs (histogram + scatter setup) can't amortize.
-RADIX_MIN_LANES = 1 << 13
-# One counting pass costs about this many bitonic compare-exchange
-# substages of VPU work: ~8 vector ops for the 16-bin one-hot
-# rank/total cumsum plus ~4 for digit extract, position arithmetic and
-# the permutation scatter.  Radix wins once the network's
-# log2(n)(log2(n)+1)/2 substages exceed passes * this — log2(n) >= 14
-# for 32-bit keys, >= 13 for bf16 (the RADIX_MIN_LANES floor).
-# Calibrated against benchmarks/bench_sort.py: the compiled-mode rows
-# (BENCH_sort.json "compiled") recalibrate it on real hardware; the
-# interpret-mode rows show the host emulator is not in this regime at
-# all (XLA-CPU scatter ~30x over the network), which is why
-# sort_kernel_choice pins bitonic while INTERPRET is on.
-RADIX_PASS_SUBSTAGES = 12
-
-# force_sort_kernel override: None = cost model decides.
+# force_sort_kernel override: None = the bitonic network.
 _FORCE_SORT_KERNEL = None
 
 # (op, path) -> number of dispatch decisions, counted at trace time.
@@ -164,17 +140,37 @@ __all__ = [
     "resolve_backend", "reset_dispatch_counts", "kernel_eligible",
     "sort_kernel_choice", "force_sort_kernel",
     "key_to_bits", "bits_to_key",
-    "INTERPRET", "BACKENDS", "DEFAULT_BACKEND", "DISPATCH_COUNTS",
+    "platform", "interpret_mode", "platform_backend",
+    "BACKENDS", "DEFAULT_BACKEND", "DISPATCH_COUNTS",
     "MAX_KERNEL_LANES", "RANK_MERGE_BOUND_BLOCK",
-    "RADIX_BITS", "RADIX_MIN_LANES", "RADIX_PASS_SUBSTAGES",
     "EXEC_COUNTS_ENABLED", "OP_TIMING_ENABLED",
     "enable_exec_counts", "exec_dispatch_counts",
 ]
 
 
+@functools.lru_cache(maxsize=None)
+def platform() -> str:
+    """The backend JAX computes on ("cpu", "tpu", ...).  Asked at the
+    first dispatch, not at import: importing ``repro`` initialises no
+    backend."""
+    return jax.default_backend()
+
+
+def interpret_mode() -> bool:
+    """Kernels run through the Pallas interpreter exactly on CPU."""
+    return platform() == "cpu"
+
+
+def platform_backend() -> str:
+    """The default kernel backend of this platform: the kernels where
+    they compile (TPU), the jnp reference elsewhere."""
+    return "pallas" if platform() == "tpu" else "reference"
+
+
 def resolve_backend(backend) -> str:
     """None -> module default; otherwise validate the explicit choice."""
-    b = DEFAULT_BACKEND if backend is None else backend
+    b = backend if backend is not None else (DEFAULT_BACKEND
+                                             or platform_backend())
     if b not in BACKENDS:
         raise ValueError(f"unknown kernel backend {b!r}; "
                          f"expected one of {BACKENDS}")
@@ -314,60 +310,34 @@ def kernel_eligible(op: str, x, y=None) -> bool:
         # today, but the radix core needs no pow2 padding)
         return x.ndim in (1, 2) and _key_dtype_ok(x) and _lanes_ok(x.shape[-1])
     if op in ("merge_sorted_rows", "merge_sorted_rows_kv"):
-        t, c = x.shape
         if not _key_dtype_ok(x):
             return False
-        tp2, cp2 = _next_pow2(t), _next_pow2(max(2, c))
+        tp2, cp2 = bitonic.merge_padded_shape(*x.shape)
         if _lanes_ok(tp2 * cp2):
             return True               # in-VMEM hierarchical network merge
-        # rank-merge path: per-block VMEM is one row, so only the row
-        # length is lane-bound; the row count just sizes the grid
+        # rank-merge path: per-step VMEM is one query block and one
+        # bound block, so only the row length is lane-bound; the row
+        # count just sizes the grid
         return _lanes_ok(cp2) and tp2 <= 512
     raise ValueError(f"unknown op {op!r}")
 
 
-def sort_kernel_choice(x) -> str:
-    """Which sort-kernel family would the pallas path run: the cost-model
-    split between ``"bitonic"`` and ``"radix"``.
-
-    Bitonic's work is log2(n)·(log2(n)+1)/2 compare-exchange substages
-    over the padded row; an LSD radix sort is ``ceil(key_bits / 4)``
-    counting passes, each worth ~``RADIX_PASS_SUBSTAGES`` substages of
-    VPU work — so radix wins past a crossover in BOTH the row length
-    and the key width (bf16's 16-bit keys halve the pass count and
-    cross over a full octave earlier than float32/int32).  The split
-    only applies on compiled backends: the interpret-mode bench
-    calibrated that XLA-CPU's scalar scatter emulation prices radix out
-    entirely (see the module docstring), so while ``INTERPRET`` is on
-    the choice pins bitonic unless a :func:`force_sort_kernel` context
-    overrides it.  Pure function of shape/dtype/constants — safe to
-    consult without dispatching.
-    """
-    if _FORCE_SORT_KERNEL is not None:
-        return _FORCE_SORT_KERNEL
-    if INTERPRET or not _key_dtype_ok(x):
-        return "bitonic"
-    n = x.shape[-1]
-    if n < RADIX_MIN_LANES:
-        return "bitonic"
-    logn = max(1, max(2, _next_pow2(n)).bit_length() - 1)
-    bitonic_substages = logn * (logn + 1) // 2
-    passes = -(-radix.key_bits(x.dtype) // RADIX_BITS)
-    if bitonic_substages > passes * RADIX_PASS_SUBSTAGES:
-        return "radix"
-    return "bitonic"
+def sort_kernel_choice() -> str:
+    """Which sort-kernel family the pallas path runs: ``"bitonic"``,
+    unless a :func:`force_sort_kernel` context pins ``"radix"``."""
+    return _FORCE_SORT_KERNEL or "bitonic"
 
 
 @contextlib.contextmanager
 def force_sort_kernel(kind):
     """Pin :func:`sort_kernel_choice` to one family for the duration.
 
-    ``kind``: ``"radix"``, ``"bitonic"``, or ``None`` (restore the cost
-    model).  Used by the differential tests and the dispatch-budget
-    bench to exercise the radix paths on the interpret-mode container,
-    where the cost model would otherwise never pick them.  Affects
-    *trace-time* decisions only — already-compiled programs keep the
-    family they traced with (``reset_default_pool()`` to re-trace).
+    ``kind``: ``"radix"``, ``"bitonic"``, or ``None`` (restore the
+    default, bitonic).  Used by the differential tests and the
+    dispatch-budget bench to exercise the radix paths, which nothing
+    picks otherwise.  Affects *trace-time* decisions only —
+    already-compiled programs keep the family they traced with
+    (``reset_default_pool()`` to re-trace).
     """
     if kind not in (None, "bitonic", "radix"):
         raise ValueError(f"unknown sort kernel family {kind!r}")
@@ -401,16 +371,16 @@ def sort(x: jnp.ndarray, *, backend=None, block_rows: int = 8,
     b = resolve_backend(backend)
     if b == "pallas" and kernel_eligible("sort", x):
         x2 = x[None, :] if x.ndim == 1 else x
-        if sort_kernel_choice(x) == "radix":
+        if sort_kernel_choice() == "radix":
             _tick("sort", "radix")
             out, _ = radix.radix_sort(
                 x2, block_rows=min(block_rows, x2.shape[0]),
-                interpret=INTERPRET)
+                interpret=interpret_mode())
         else:
             _tick("sort", "pallas")
             out = bitonic.bitonic_sort(
                 x2, block_rows=min(block_rows, x2.shape[0]),
-                interpret=INTERPRET)
+                interpret=interpret_mode())
         return out[0] if x.ndim == 1 else out
     _tick("sort", "reference")
     return jnp.sort(x, axis=-1)
@@ -437,19 +407,20 @@ def sort_kv(keys: jnp.ndarray, values, *, backend=None, block_rows: int = 8,
                          "the same power-of-two length (use ops.pad_pow2)")
     b = resolve_backend(backend)
     if b == "pallas" and kernel_eligible("sort_kv", keys, values):
-        if sort_kernel_choice(keys) == "radix":
+        if sort_kernel_choice() == "radix":
             # the permutation channel comes out of the counting passes
             # for free — one gather carries the payload, no (key, iota)
             # lexicographic pair-sort
             _tick("sort_kv", "radix")
-            ks, order = radix.radix_sort(keys[None, :], interpret=INTERPRET)
+            ks, order = radix.radix_sort(keys[None, :],
+                                         interpret=interpret_mode())
             return ks[0], values[order[0]]
         _tick("sort_kv", "pallas")
         n = keys.shape[0]
         iota = jnp.arange(n, dtype=jnp.int32)
         ks, order = bitonic.bitonic_sort_kv(keys[None, :], iota[None, :],
                                             block_rows=1,
-                                            interpret=INTERPRET)
+                                            interpret=interpret_mode())
         return ks[0], values[order[0]]
     _tick("sort_kv", "reference")
     order = jnp.argsort(keys, axis=-1)
@@ -481,7 +452,8 @@ def searchsorted(sorted_arr: jnp.ndarray, queries: jnp.ndarray, *,
     if b == "pallas" and kernel_eligible("searchsorted", sorted_arr, queries):
         _tick("searchsorted", "pallas")
         ids = bucketize.searchsorted(sorted_arr, queries, side=side,
-                                     block_n=block_n, interpret=INTERPRET)
+                                     block_n=block_n,
+                                     interpret=interpret_mode())
     else:
         _tick("searchsorted", "reference")
         ids = jnp.searchsorted(sorted_arr, queries,
@@ -508,7 +480,7 @@ def sort_partition(x: jnp.ndarray, interior: jnp.ndarray, *, backend=None):
         xs = sort(x, backend=backend)
         cuts = jnp.zeros((0,), jnp.int32)
     elif (b == "pallas" and kernel_eligible("sort_partition", x, interior)
-          and sort_kernel_choice(x) == "radix"):
+          and sort_kernel_choice() == "radix"):
         # no fused radix+search kernel: past the crossover the sort
         # dominates, so the split costs one extra (cheap) searchsorted
         # dispatch — the budget benches carry it as smms_radix /
@@ -517,7 +489,8 @@ def sort_partition(x: jnp.ndarray, interior: jnp.ndarray, *, backend=None):
         cuts = searchsorted(xs, interior, side="left", backend=b)
     elif b == "pallas" and kernel_eligible("sort_partition", x, interior):
         _tick("sort_partition", "pallas")
-        xs, cuts = fused.sort_partition(x, interior, interpret=INTERPRET)
+        xs, cuts = fused.sort_partition(x, interior,
+                                        interpret=interpret_mode())
     else:
         _tick("sort_partition", "reference")
         xs = jnp.sort(x)
@@ -546,7 +519,7 @@ def sort_partition_kv(keys: jnp.ndarray, values, interior: jnp.ndarray, *,
     elif (b == "pallas"
           and kernel_eligible("sort_partition_kv", keys, interior)
           and values.shape[:1] == keys.shape[:1]
-          and sort_kernel_choice(keys) == "radix"):
+          and sort_kernel_choice() == "radix"):
         ks, vs = sort_kv(keys, values, backend=b)
         cuts = searchsorted(ks, interior, side="left", backend=b)
     elif (b == "pallas"
@@ -554,7 +527,7 @@ def sort_partition_kv(keys: jnp.ndarray, values, interior: jnp.ndarray, *,
           and values.shape[:1] == keys.shape[:1]):
         _tick("sort_partition_kv", "pallas")
         ks, order, cuts = fused.sort_partition_kv(keys, interior,
-                                                  interpret=INTERPRET)
+                                                  interpret=interpret_mode())
         vs = values[order]
     else:
         _tick("sort_partition_kv", "reference")
@@ -581,7 +554,7 @@ def bucketize_histogram(keys: jnp.ndarray, boundaries: jnp.ndarray, t: int,
         _tick("bucketize_histogram", "pallas")
         return bucketize.bucketize_histogram(keys, boundaries, t,
                                              block_n=block_n,
-                                             interpret=INTERPRET)
+                                             interpret=interpret_mode())
     _tick("bucketize_histogram", "reference")
     ids = jnp.searchsorted(boundaries, keys, side="right").astype(jnp.int32)
     counts = jnp.zeros((t,), jnp.int32).at[jnp.clip(ids, 0, t - 1)].add(1)
@@ -593,7 +566,8 @@ def bucketize_histogram(keys: jnp.ndarray, boundaries: jnp.ndarray, t: int,
 # ---------------------------------------------------------------------------
 
 def _merge_fits_one_tile(t: int, c: int) -> bool:
-    return _lanes_ok(_next_pow2(t) * _next_pow2(max(2, c)))
+    tp2, cp2 = bitonic.merge_padded_shape(t, c)
+    return _lanes_ok(tp2 * cp2)
 
 
 def _rank_merge(keys: jnp.ndarray):
@@ -617,7 +591,7 @@ def _rank_merge(keys: jnp.ndarray):
     bound_block = RANK_MERGE_BOUND_BLOCK if cp2 > RANK_MERGE_BOUND_BLOCK \
         else None
     pos = fused.merge_ranks(kp, ip, bound_block=bound_block,
-                            interpret=INTERPRET).reshape(-1)
+                            interpret=interpret_mode()).reshape(-1)
     merged = jnp.zeros((tp2 * cp2,), keys.dtype).at[pos].set(kp.reshape(-1))
     order = jnp.zeros((tp2 * cp2,), jnp.int32).at[pos].set(ip.reshape(-1))
     return merged[:t * c], order[:t * c]
@@ -637,7 +611,7 @@ def merge_sorted_rows(x: jnp.ndarray, *, backend=None) -> jnp.ndarray:
     if b == "pallas" and kernel_eligible("merge_sorted_rows", x):
         _tick("merge_sorted_rows", "pallas")
         if _merge_fits_one_tile(*x.shape):
-            return bitonic.merge_sorted_rows(x, interpret=INTERPRET)
+            return bitonic.merge_sorted_rows(x, interpret=interpret_mode())
         return _rank_merge(x)[0]
     _tick("merge_sorted_rows", "reference")
     return jnp.sort(x.reshape(-1))
@@ -645,26 +619,28 @@ def merge_sorted_rows(x: jnp.ndarray, *, backend=None) -> jnp.ndarray:
 
 @_op_timing
 def merge_sorted_rows_kv(keys: jnp.ndarray, values, *, backend=None):
-    """Merge sorted rows carrying payload.  keys: (t, c); values: (t, c, ...).
+    """Merge sorted rows carrying payload.  keys: (t, c); values: (t, c, ...)
+    or a tuple of such arrays.
 
     Returns (merged_keys (t*c,), merged_values (t*c, ...)).  Both backends
     realize the *stable* flat argsort (ties keep buffer order), so the
     kernel path is bitwise-identical to the reference."""
     b = resolve_backend(backend)
     t, c = keys.shape
-    vflat = values.reshape(t * c, *values.shape[2:])
+    gather = lambda order: jax.tree.map(
+        lambda v: v.reshape(t * c, *v.shape[2:])[order], values)
     if b == "pallas" and kernel_eligible("merge_sorted_rows_kv", keys):
         _tick("merge_sorted_rows_kv", "pallas")
         if _merge_fits_one_tile(t, c):
             merged, order = bitonic.merge_sorted_rows_argsort(
-                keys, interpret=INTERPRET)
+                keys, interpret=interpret_mode())
         else:
             merged, order = _rank_merge(keys)
-        return merged, vflat[order]
+        return merged, gather(order)
     _tick("merge_sorted_rows_kv", "reference")
     kflat = keys.reshape(-1)
     order = jnp.argsort(kflat)
-    return kflat[order], vflat[order]
+    return kflat[order], gather(order)
 
 
 # ---------------------------------------------------------------------------
@@ -676,4 +652,4 @@ def flash_attention(q, k, v, causal: bool = True, window=None,
     """Blocked online-softmax attention with GQA + sliding window."""
     return fa.flash_attention(q, k, v, causal=causal, window=window,
                               block_q=block_q, block_k=block_k,
-                              interpret=INTERPRET)
+                              interpret=interpret_mode())

@@ -3,9 +3,11 @@
 Bitonic's n log^2 n comparator count loses on wide rows: at n = 2^14
 the network runs 105 compare-exchange substages over the full row,
 while an LSD radix sort of 32-bit keys is 8 counting passes (4 bits
-each).  This module is the radix side of the ``ops.sort`` cost-model
-split (``ops.sort_kernel_choice``): past a crossover in row length and
-key width, the dispatcher routes here.
+each).  No platform runs it well today: Mosaic lowers neither the
+in-kernel scatter nor the per-lane gather, so on TPU the sort family
+stays bitonic, and the CPU interpreter prices the scatter out.  The
+kernel is reached through ``ops.force_sort_kernel`` only
+(``ops.sort_kernel_choice``).
 
 Key specialization: one *unsigned* radix core serves every eligible
 dtype through a monotone bijection into sortable unsigned bits —
@@ -217,9 +219,9 @@ def radix_sort(x: jnp.ndarray, radix_bits: int = DEFAULT_RADIX_BITS,
     stable ``jnp.argsort`` for every input, NaN (either sign, payload
     bits preserved), -0.0 and infinities included (the comparator
     equivalence classes — see the module docstring).
-    interpret=True validates on CPU; on TPU
-    pass interpret=False and the same call compiles with Mosaic (the
-    in-kernel scatter needs a Mosaic version with scatter support).
+    Interpret mode only: Mosaic lowers neither the in-kernel scatter nor
+    the per-lane gather, so ``ops.sort_kernel_choice`` picks this kernel
+    only under ``ops.force_sort_kernel``.
     """
     rows, n = x.shape
     if n == 0:

@@ -4,6 +4,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 # count on first init).  Everything below may import jax.
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
+A CPU-only model-scaffold tool: it forces 512 host devices at import
+and spawns child processes, so it is never sent to the chip, and
+nothing on the chip path (``chip_smoke.py``, the cluster front door,
+``benchmarks/run.py``) imports it.
+
 Proves the distribution config is coherent without hardware:
 
   * 16x16 single-pod mesh (256 chips) AND 2x16x16 multi-pod (512 chips)

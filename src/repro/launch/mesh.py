@@ -2,8 +2,7 @@
 
 Importing this module never touches jax device state — meshes are built
 inside functions only (the dry-run sets XLA_FLAGS before any jax import).
-Mesh construction goes through repro.cluster.compat so the axis-type
-handling tracks whatever this jax version supports.
+Every mesh goes through :func:`make_mesh`, so all axes are ``Auto``.
 
 ``factor_shards`` / ``staged_axes`` / ``make_staged_mesh`` support the
 two-level (AMS-style) exchange: the shard axis t is factored into
@@ -18,6 +17,18 @@ import warnings
 from typing import Optional, Tuple
 
 STAGED_AXIS_NAMES = ("i1", "i2")
+
+
+def make_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the
+    explicit-collectives behaviour the substrate's shard_map bodies
+    are written for."""
+    import jax
+    from jax.sharding import AxisType
+
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names), **kw)
 
 
 def factor_shards(t: int, *, warn: bool = False
@@ -57,8 +68,6 @@ def make_staged_mesh(t: int, names: Tuple[str, str] = STAGED_AXIS_NAMES):
     Non-factorable t degrades to a flat 1-axis mesh (with a warning)
     instead of raising — same contract as the exchange itself.
     """
-    from repro.cluster.compat import make_mesh
-
     fs = factor_shards(t, warn=True)
     if fs is None:
         return make_mesh((int(t),), (names[0],))
@@ -73,8 +82,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     'data'  — batch + FSDP,
     'model' — TP / EP / sequence-sharded KV.
     """
-    from repro.cluster.compat import make_mesh
-
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
@@ -83,8 +90,6 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_host_mesh(t: int = 8):
     """Small mesh over however many (host) devices exist — examples/tests."""
     import jax
-
-    from repro.cluster.compat import make_mesh
 
     n = len(jax.devices())
     t = min(t, n)

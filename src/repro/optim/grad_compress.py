@@ -54,8 +54,7 @@ def compressed_psum(x: jnp.ndarray, residual: jnp.ndarray, axis_name: str):
 
     The int8 operand is what crosses the links; the sum is widened
     locally.  Returns (mean-reduced value, new residual)."""
-    from repro.cluster.compat import axis_size
-    t = axis_size(axis_name)
+    t = jax.lax.axis_size(axis_name)
     val = x.astype(jnp.float32) + residual
     q, scale = _quantize(val)
     # wire: int8 payload (+ one f32 scale each) — each contribution is

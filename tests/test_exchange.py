@@ -65,14 +65,11 @@ def test_property_exchange_conserves_or_drops(t, m, seed):
 
 # ---------------------------------------------------------------------------
 # ragged backend: values routing (regression — values used to be silently
-# dropped) and version gating
+# dropped)
 # ---------------------------------------------------------------------------
 
 def test_ragged_backend_does_not_silently_drop_values(rng):
-    """backend='ragged' must either route values or fail loudly."""
-    from repro.cluster import compat
-    from repro.core.exchange import ragged_exchange
-
+    """backend='ragged' must route the values through the exchange."""
     t, m = 4, 32
     x = jnp.sort(jnp.asarray(rng.normal(size=m), jnp.float32))
     vals = jnp.arange(m, dtype=jnp.int32)
@@ -84,23 +81,13 @@ def test_ragged_backend_does_not_silently_drop_values(rng):
                                      backend="ragged")
         return r.keys, r.values
 
-    if not compat.HAS_RAGGED:
-        # this jax build has no ragged_all_to_all: loud error, not a
-        # silently values-less result
-        with pytest.raises(NotImplementedError, match="ragged_all_to_all"):
-            jax.vmap(body, axis_name="i")(jnp.tile(x, (t, 1)),
-                                          jnp.tile(vals, (t, 1)))
-        return
-
-    # op available: the lowered program must carry TWO ragged exchanges
-    # (keys + values) with the same size vectors
-    import jax as _jax
-    from jax.experimental.shard_map import shard_map
+    # the lowered program must carry TWO ragged exchanges (keys + values)
+    # with the same size vectors
     from jax.sharding import PartitionSpec as P
-    if len(_jax.devices()) < t:
+    if len(jax.devices()) < t:
         pytest.skip("needs >= t devices for shard_map lowering")
-    mesh = _jax.make_mesh((t,), ("i",))
-    fn = _jax.jit(shard_map(
+    mesh = jax.make_mesh((t,), ("i",))
+    fn = jax.jit(jax.shard_map(
         lambda xl, vl: body(xl[0], vl[0]),
         mesh=mesh, in_specs=(P("i"), P("i")), out_specs=P("i")))
     txt = fn.lower(jnp.tile(x, (t, 1)), jnp.tile(vals, (t, 1))).as_text()

@@ -267,30 +267,19 @@ def test_sort_partition_families_agree(family):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_sort_kernel_choice_cost_model():
-    """The roofline-gated selection: bitonic under interpret mode and on
-    short rows; radix past the crossover on compiled backends; bf16
-    crosses a full octave earlier than float32."""
-    short = jnp.zeros((4, 1 << 10), jnp.float32)
-    wide = jnp.zeros((4, 1 << 14), jnp.float32)
-    wide_bf16 = jnp.zeros((4, 1 << 13), jnp.bfloat16)
-    assert ops.sort_kernel_choice(short) == "bitonic"
-    # interpret mode pins bitonic regardless of width
-    assert ops.INTERPRET  # this container runs interpret mode
-    assert ops.sort_kernel_choice(wide) == "bitonic"
-    prev = ops.INTERPRET
-    ops.INTERPRET = False
-    try:
-        assert ops.sort_kernel_choice(wide) == "radix"
-        assert ops.sort_kernel_choice(wide_bf16) == "radix"
-        assert ops.sort_kernel_choice(
-            jnp.zeros((4, 1 << 13), jnp.float32)) == "bitonic"
-        assert ops.sort_kernel_choice(short) == "bitonic"
-    finally:
-        ops.INTERPRET = prev
-    with ops.force_sort_kernel("radix"):
-        assert ops.sort_kernel_choice(short) == "radix"
-    assert ops.sort_kernel_choice(short) == "bitonic"
+def test_sort_kernel_choice_cost_model(monkeypatch):
+    """The pallas sort family is the bitonic network on every platform
+    and at every width; only force_sort_kernel picks radix, and the
+    force is scoped to its context."""
+    for plat in ("cpu", "tpu"):
+        monkeypatch.setattr(ops, "platform", lambda: plat)
+        assert ops.sort_kernel_choice() == "bitonic"
+        with ops.force_sort_kernel("radix"):
+            assert ops.sort_kernel_choice() == "radix"
+            with ops.force_sort_kernel(None):
+                assert ops.sort_kernel_choice() == "bitonic"
+            assert ops.sort_kernel_choice() == "radix"
+        assert ops.sort_kernel_choice() == "bitonic"
     with pytest.raises(ValueError):
         with ops.force_sort_kernel("quantum"):
             pass

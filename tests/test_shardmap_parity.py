@@ -7,8 +7,7 @@ drives everything through the cluster substrate: SMMS / Terasort /
 RandJoin / StatJoin on a ShardMapSubstrate, checked for exact parity
 (sorted output, join pairs, AlphaKReport k's) against the VmapSubstrate
 run of the identical input.  The ragged exchange backend is checked at
-the lowering level on jax builds that ship lax.ragged_all_to_all, and
-for its loud NotImplementedError on builds that don't.
+the lowering level.
 """
 import os
 import subprocess
@@ -22,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro import cluster
-from repro.cluster import ShardMapSubstrate, VmapSubstrate, compat
+from repro.cluster import ShardMapSubstrate, VmapSubstrate
+from repro.launch.mesh import make_mesh
 from repro.data import uniform_keys, zipf_tables
 
 t, m = 8, 512
@@ -65,24 +65,18 @@ assert rep_v2.k_network == rep_s2.k_network
 assert rep_v2.alpha == rep_s2.alpha == 4
 print("SMMS staged-exchange mesh parity OK:", rep_s2.summary())
 
-# ---- ragged backend: lowers on capable builds, fails loudly elsewhere -----
-if compat.HAS_RAGGED:
-    from jax.sharding import PartitionSpec as P
-    from repro.core.smms import smms_shard
-    mesh = compat.make_mesh((t,), ("i",))
-    def body(xl):
-        res = smms_shard(xl[0], axis_name="i", t=t, r=2, backend="ragged")
-        return res.keys[None]
-    txt = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(P("i"),),
-                                   out_specs=P("i"))).lower(x).as_text()
-    assert "ragged" in txt, "expected ragged-all-to-all in lowered HLO"
-    print("ragged backend lowers OK (execution is TPU-only)")
-else:
-    try:
-        cluster.sort(x, backend="ragged", substrate=ShardMapSubstrate(t))
-        raise SystemExit("ragged backend should have raised")
-    except NotImplementedError:
-        print("ragged backend raises cleanly on this jax version")
+# ---- ragged backend: lowers (execution is TPU-only) -----------------------
+from jax.sharding import PartitionSpec as P
+from repro.core.smms import smms_shard
+mesh = make_mesh((t,), ("i",))
+def body(xl):
+    res = smms_shard(xl[0], axis_name="i", t=t, r=2, backend="ragged")
+    return res.keys[None]
+txt = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("i"),),
+                            out_specs=P("i"), check_vma=False)
+              ).lower(x).as_text()
+assert "ragged" in txt, "expected ragged-all-to-all in lowered HLO"
+print("ragged backend lowers OK (execution is TPU-only)")
 
 # ---- RandJoin on a real 2D (a, b) mesh ------------------------------------
 a, b = 2, 4

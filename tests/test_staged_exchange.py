@@ -135,31 +135,43 @@ def test_terasort_staged_output_and_report_parity(gen):
 
 
 @pytest.mark.parametrize("sorter", [smms_sort, terasort_sort])
-def test_staged_carries_values(sorter, rng):
-    """kv parity needs distinct keys — equal keys may legally reorder
-    their values between topologies."""
-    t, m = 8, 256
-    keys = rng.permutation(t * m).astype(np.float32).reshape(t, m)
+@pytest.mark.parametrize("t,chunks,tied", [(8, 2, False), (4, 2, True),
+                                           (4, 1, True), (8, 4, True)])
+def test_staged_carries_values(sorter, t, chunks, tied, rng):
+    """kv parity with the flat path and the stable global argsort.  The
+    tied cases draw 2^14 keys from 1024 values, ~16 copies of each
+    spread over all machines, so values of equal keys must leave in
+    source-machine order, not in the order of the hops."""
+    m = (1 << 14) // t
+    keys = (rng.integers(0, 1024, size=(t, m)) if tied
+            else rng.permutation(t * m).reshape(t, m)).astype(np.float32)
     vals = np.arange(t * m, dtype=np.int32).reshape(t, m)
     (kf, vf), _ = sorter(jnp.asarray(keys), values=jnp.asarray(vals),
                          exchange="flat")
     (ks, vs), _ = sorter(jnp.asarray(keys), values=jnp.asarray(vals),
-                         exchange="staged")
+                         exchange="staged", overlap_chunks=chunks)
     np.testing.assert_array_equal(np.asarray(kf), np.asarray(ks))
     np.testing.assert_array_equal(np.asarray(vf), np.asarray(vs))
     order = np.argsort(keys.reshape(-1), kind="stable")
-    np.testing.assert_array_equal(np.asarray(vs),
-                                  vals.reshape(-1)[order])
+    np.testing.assert_array_equal(np.asarray(vs), vals.reshape(-1)[order])
 
 
-def test_staged_pallas_matches_reference():
+@pytest.mark.parametrize("with_values", [False, True])
+def test_staged_pallas_matches_reference(with_values):
     t, m = 8, 512
-    x = jnp.asarray(uniform_keys(t * m, seed=7).reshape(t, m))
-    (k_ref, _), _ = smms_sort(x, r=2, exchange="staged",
-                              kernel_backend="reference")
-    (k_pal, _), _ = smms_sort(x, r=2, exchange="staged",
-                              kernel_backend="pallas")
+    if with_values:        # tied keys: the (key, origin) re-order runs
+        x = jnp.asarray(zipf_keys(t * m, seed=7).reshape(t, m))
+        v = jnp.arange(t * m, dtype=jnp.int32).reshape(t, m)
+    else:
+        x = jnp.asarray(uniform_keys(t * m, seed=7).reshape(t, m))
+        v = None
+    (k_ref, v_ref), _ = smms_sort(x, r=2, values=v, exchange="staged",
+                                  kernel_backend="reference")
+    (k_pal, v_pal), _ = smms_sort(x, r=2, values=v, exchange="staged",
+                                  kernel_backend="pallas")
     np.testing.assert_array_equal(np.asarray(k_ref), np.asarray(k_pal))
+    if with_values:
+        np.testing.assert_array_equal(np.asarray(v_ref), np.asarray(v_pal))
 
 
 @pytest.mark.parametrize("chunks", [2, 4])
