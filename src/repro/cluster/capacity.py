@@ -113,7 +113,9 @@ def run_with_capacity(attempt: Callable[[float], Tuple[object, int]],
             obs_trace.event("capacity_retry", attempt=attempts,
                             cap_factor=float(factor),
                             dropped=int(dropped))
-        result, dropped = attempt(factor)
+        with obs_trace.span("capacity.attempt", attempt=attempts,
+                            cap_factor=float(factor)):
+            result, dropped = attempt(factor)
         if int(dropped) == 0:
             return result, factor, attempts
     err = CapacityOverflowError(

@@ -180,6 +180,16 @@ class Substrate:
             sp.add_child(f"phase:{ph.name}", sent=ph.sent,
                          received=ph.received)
 
+    @staticmethod
+    def _call(fn: Callable, args, *, built: bool, body: str):
+        """Call a program; the first call of one just built traces and
+        compiles it (or loads it from the persistent cache), inside a
+        ``substrate.compile`` span."""
+        if not built:
+            return fn(*args)
+        with obs_trace.span("substrate.compile", body=body):
+            return fn(*args)
+
     def run(self, shard_fn: Callable, *args, donate_argnums=()):
         """Execute ``shard_fn(*local_args, tape=tape)`` on every machine.
 
@@ -234,6 +244,7 @@ class VmapSubstrate(Substrate):
                 substrate=type(self).__name__, t=self.t) as sp:
             self.stats["runs"] += 1
             donate = self._donation(donate_argnums)
+            built = False
             if not self._jit:
                 fn, tape = self._build(shard_fn)   # eager: donation is moot
             else:
@@ -241,7 +252,8 @@ class VmapSubstrate(Substrate):
                        tuple((jnp.shape(a), str(getattr(a, "dtype", type(a))))
                              for a in args))
                 cached = self._compiled.get(key)
-                if cached is None:
+                built = cached is None
+                if built:
                     fn, tape = self._build(shard_fn)
                     cached = self._compiled[key] = (
                         jax.jit(fn, donate_argnums=donate), tape)
@@ -256,7 +268,8 @@ class VmapSubstrate(Substrate):
                 fn, tape = cached
                 if donate:
                     self.stats["donated_runs"] += 1
-            out, frames = fn(*args)
+            out, frames = self._call(fn, args, built=built,
+                                     body=_fn_label(shard_fn))
             snap = tape.bound_snapshot(jax.tree.map(np.asarray, frames))
             self._attach_phases(sp, snap)
             return out, snap
@@ -297,7 +310,8 @@ class ShardMapSubstrate(Substrate):
             donate = self._donation(donate_argnums) if self._jit else ()
             key = self._signature(shard_fn, args) + (donate,)
             cached = self._compiled.get(key)
-            if cached is None:
+            built = cached is None
+            if built:
                 tape = CollectiveTape()
                 k = len(self.axes)
                 lead = (0,) * k
@@ -329,7 +343,8 @@ class ShardMapSubstrate(Substrate):
             fn, tape = cached
             if donate:
                 self.stats["donated_runs"] += 1
-            out, frames = fn(*args)
+            out, frames = self._call(fn, args, built=built,
+                                     body=_fn_label(shard_fn))
             snap = tape.bound_snapshot(jax.tree.map(np.asarray, frames))
             self._attach_phases(sp, snap)
             return out, snap

@@ -31,6 +31,7 @@ from repro.cluster.capacity import CapacityPolicy, run_with_capacity
 from repro.cluster.collectives import CollectiveTape
 from repro.cluster.substrate import Substrate, default_pool
 from repro.kernels import ops
+from repro.obs import trace as obs_trace
 
 from .boundaries import boundaries_jax, equidepth_samples
 from .exchange import ExchangeResult, exchange_sorted_segments
@@ -268,15 +269,16 @@ def smms_sort(x: jnp.ndarray, r: int = 2,
 
     (res, tape), factor, attempts = run_with_capacity(attempt, policy)
 
-    keys = np.asarray(res.keys).reshape(t, -1)
-    counts = np.asarray(res.count).reshape(-1)
-    flat = np.concatenate([keys[i, :counts[i]] for i in range(t)])
-    vals = None
-    if res.values is not None:
-        v = np.asarray(res.values)
-        if staged_shape is not None:      # (t1, t2, C, ...) -> (t, C, ...)
-            v = v.reshape((t,) + v.shape[2:])
-        vals = np.concatenate([v[i, :counts[i]] for i in range(t)])
+    with obs_trace.span("smms.fetch"):       # device to host
+        keys = np.asarray(res.keys).reshape(t, -1)
+        counts = np.asarray(res.count).reshape(-1)
+        v = None if res.values is None else np.asarray(res.values)
+    if v is not None and staged_shape is not None:
+        v = v.reshape((t,) + v.shape[2:])  # (t1, t2, C, ...) -> (t, C, ...)
+    with obs_trace.span("smms.compact"):     # each machine's filled prefix
+        flat = np.concatenate([keys[i, :counts[i]] for i in range(t)])
+        vals = (None if v is None
+                else np.concatenate([v[i, :counts[i]] for i in range(t)]))
 
     report = tape.report(algorithm=f"SMMS(r={r})", t=t, n_in=n, n_out=n,
                          workload=counts)

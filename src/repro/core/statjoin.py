@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.cluster.collectives import CollectiveTape
 from repro.cluster.substrate import Substrate, default_pool
+from repro.obs import trace as obs_trace
 
 from .localjoin import MASKED_KEY, local_equijoin
 from .alpha_k import statjoin_workload_bound
@@ -83,14 +84,20 @@ class Rectangle:
 def collect_statistics(s_keys: np.ndarray, t_keys: np.ndarray
                        ) -> JoinStatistics:
     """Per-key multiplicities for keys present in both tables."""
-    ks, cs = np.unique(s_keys, return_counts=True)
-    kt, ct = np.unique(t_keys, return_counts=True)
-    common, is_, it_ = np.intersect1d(ks, kt, return_indices=True)
-    return JoinStatistics(common, cs[is_], ct[it_])
+    with obs_trace.span("statjoin.statistics"):
+        ks, cs = np.unique(s_keys, return_counts=True)
+        kt, ct = np.unique(t_keys, return_counts=True)
+        common, is_, it_ = np.intersect1d(ks, kt, return_indices=True)
+        return JoinStatistics(common, cs[is_], ct[it_])
 
 
 def plan_statjoin(stats: JoinStatistics, t: int) -> List[Rectangle]:
     """§4.3.2/4.3.3 planner.  Returns the result-to-machine mapping."""
+    with obs_trace.span("statjoin.plan"):
+        return _plan(stats, t)
+
+
+def _plan(stats: JoinStatistics, t: int) -> List[Rectangle]:
     w = stats.total
     if w == 0:
         return []
@@ -238,17 +245,18 @@ def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
         substrate = default_pool()(t)
     assert substrate.t == t, (substrate, t)
 
-    s_idx, _ = _routing_tensors(s_keys, rects, t, "s")
-    t_idx, _ = _routing_tensors(t_keys, rects, t, "t")
-
     def frag(keys, rows, idx):
         k = np.where(idx >= 0, keys[np.clip(idx, 0, len(keys) - 1)],
                      MASKED_KEY).astype(np.int32)
         v = np.where(idx >= 0, rows[np.clip(idx, 0, len(rows) - 1)], 0)
         return jnp.asarray(k), jnp.asarray(v.astype(np.int32))
 
-    sk, sr = frag(s_keys, np.asarray(s_rows), s_idx)
-    tk, tr = frag(t_keys, np.asarray(t_rows), t_idx)
+    # everything that turns the plan into device operands
+    with obs_trace.span("statjoin.route"):
+        s_idx, _ = _routing_tensors(s_keys, rects, t, "s")
+        t_idx, _ = _routing_tensors(t_keys, rects, t, "t")
+        sk, sr = frag(s_keys, np.asarray(s_rows), s_idx)
+        tk, tr = frag(t_keys, np.asarray(t_rows), t_idx)
 
     capacity = (int(out_capacity) if out_capacity is not None
                 else max(1, math.ceil(

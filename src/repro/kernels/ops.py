@@ -83,7 +83,6 @@ import contextlib
 import functools
 import os
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -124,12 +123,6 @@ _COUNTS_LOCK = threading.Lock()
 # registry ticks per program EXECUTION, cached programs included.
 EXEC_COUNTS_ENABLED = os.environ.get("REPRO_EXEC_COUNTS", "0") == "1"
 
-# Optional per-op host timing: each dispatcher call (trace or eager
-# execute) lands in the kernel_op_seconds{op} registry histogram.  Off
-# by default — the block_until_ready serialization distorts pipelined
-# runs, so this is a debugging lens, not an always-on metric.
-OP_TIMING_ENABLED = os.environ.get("REPRO_OP_TIMING", "0") == "1"
-
 _KERNEL_KEY_DTYPES = frozenset(
     jnp.dtype(d) for d in (jnp.float32, jnp.bfloat16, jnp.int32))
 
@@ -143,7 +136,7 @@ __all__ = [
     "platform", "interpret_mode", "platform_backend",
     "BACKENDS", "DEFAULT_BACKEND", "DISPATCH_COUNTS",
     "MAX_KERNEL_LANES", "RANK_MERGE_BOUND_BLOCK",
-    "EXEC_COUNTS_ENABLED", "OP_TIMING_ENABLED",
+    "EXEC_COUNTS_ENABLED",
     "enable_exec_counts", "exec_dispatch_counts",
 ]
 
@@ -219,29 +212,6 @@ def _tick(op: str, path: str) -> None:
     obs_trace.event("kernel_dispatch", op=op, path=path)
     if EXEC_COUNTS_ENABLED:
         jax.debug.callback(functools.partial(_exec_tick, op, path))
-
-
-def _op_timing(fn):
-    """Record per-call host time of a dispatcher when OP_TIMING_ENABLED.
-
-    Measures the dispatcher call plus a block_until_ready on its result
-    (a no-op on tracers, so under jit this times the *trace*; eagerly it
-    times the real execution).  Disabled (the default) costs one bool
-    check per dispatch.
-    """
-    name = fn.__name__
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kw):
-        if not OP_TIMING_ENABLED:
-            return fn(*args, **kw)
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args, **kw))
-        REGISTRY.histogram("kernel_op_seconds", op=name).observe(
-            time.perf_counter() - t0)
-        return out
-
-    return wrapper
 
 
 _next_pow2 = bitonic._next_pow2
@@ -354,7 +324,6 @@ def force_sort_kernel(kind):
 # sort / sort_kv
 # ---------------------------------------------------------------------------
 
-@_op_timing
 def sort(x: jnp.ndarray, *, backend=None, block_rows: int = 8,
          prepadded: bool = False) -> jnp.ndarray:
     """Ascending sort along the last axis.  x: (n,) or (rows, n).
@@ -386,7 +355,6 @@ def sort(x: jnp.ndarray, *, backend=None, block_rows: int = 8,
     return jnp.sort(x, axis=-1)
 
 
-@_op_timing
 def sort_kv(keys: jnp.ndarray, values, *, backend=None, block_rows: int = 8,
             prepadded: bool = False):
     """Stable sort of (keys, values) by key: returns (sorted, permuted).
@@ -434,7 +402,6 @@ def sort_kv(keys: jnp.ndarray, values, *, backend=None, block_rows: int = 8,
 # searchsorted / bucketize
 # ---------------------------------------------------------------------------
 
-@_op_timing
 def searchsorted(sorted_arr: jnp.ndarray, queries: jnp.ndarray, *,
                  side: str = "left", backend=None, block_n: int = 1024,
                  valid_len=None) -> jnp.ndarray:
@@ -463,7 +430,6 @@ def searchsorted(sorted_arr: jnp.ndarray, queries: jnp.ndarray, *,
     return ids
 
 
-@_op_timing
 def sort_partition(x: jnp.ndarray, interior: jnp.ndarray, *, backend=None):
     """Fused local sort + contiguous-destination partition (one dispatch).
 
@@ -500,7 +466,6 @@ def sort_partition(x: jnp.ndarray, interior: jnp.ndarray, *, backend=None):
     return xs, starts, ends - starts
 
 
-@_op_timing
 def sort_partition_kv(keys: jnp.ndarray, values, interior: jnp.ndarray, *,
                       backend=None):
     """Payload-carrying :func:`sort_partition` (stable, one dispatch).
@@ -539,7 +504,6 @@ def sort_partition_kv(keys: jnp.ndarray, values, interior: jnp.ndarray, *,
     return ks, vs, starts, ends - starts
 
 
-@_op_timing
 def bucketize_histogram(keys: jnp.ndarray, boundaries: jnp.ndarray, t: int,
                         *, backend=None, block_n: int = 1024):
     """Fused bucket-id + histogram (SMMS Round-3 planning).
@@ -597,7 +561,6 @@ def _rank_merge(keys: jnp.ndarray):
     return merged[:t * c], order[:t * c]
 
 
-@_op_timing
 def merge_sorted_rows(x: jnp.ndarray, *, backend=None) -> jnp.ndarray:
     """Merge already-sorted rows into one sorted vector.  x: (t, c).
 
@@ -617,7 +580,6 @@ def merge_sorted_rows(x: jnp.ndarray, *, backend=None) -> jnp.ndarray:
     return jnp.sort(x.reshape(-1))
 
 
-@_op_timing
 def merge_sorted_rows_kv(keys: jnp.ndarray, values, *, backend=None):
     """Merge sorted rows carrying payload.  keys: (t, c); values: (t, c, ...)
     or a tuple of such arrays.

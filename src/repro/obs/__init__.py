@@ -1,11 +1,15 @@
 """Cross-layer observability: span tracing, metrics, exporters, timing.
 
-Three small, dependency-light modules (stdlib + numpy only; ``timeit``
-lazily touches jax to block on async results):
+Small, dependency-light modules (stdlib + numpy only; ``trace`` and
+``timeit`` import jax lazily, for the profiler's annotations and to
+block on async results):
 
 * :mod:`repro.obs.trace` — hierarchical span tracer with an explicit
   contextvar-carried trace context, threaded request → planner →
-  substrate → tape phase → kernel dispatch.
+  substrate → tape phase → kernel dispatch.  Each span is also a
+  ``jax.profiler.TraceAnnotation``, so a profiler session records it on
+  the device trace's clock; with no profiler running it costs one
+  TraceMe object.
 * :mod:`repro.obs.metrics` — thread-safe registry of counters, gauges
   and streaming histograms with Prometheus-text / JSON exporters;
   backs ``ServeStats`` and the kernel dispatch counters.

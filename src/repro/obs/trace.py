@@ -21,8 +21,8 @@ The trace context is an explicit object (:class:`Span`) carried in a
 ``contextvars.ContextVar``.  A *root* span is opened with
 :meth:`Tracer.trace`; every instrumented layer below calls the
 module-level :func:`span` / :func:`event`, which attach to the current
-span **in the same thread** and are no-ops (one ContextVar read + a
-None check) when no trace is active.  A span is only ever mutated by
+span **in the same thread** and build no tree when no trace is active
+(see the overhead contract below).  A span is only ever mutated by
 the thread that opened it; cross-thread hand-off happens by opening the
 root where the work executes (the serving engine opens it inside the
 dispatcher/worker thread, so the whole request tree lives there).
@@ -35,9 +35,23 @@ construction.  Phase wall time is not host-observable (phases execute
 inside one compiled program), so phase spans are instants at the run's
 end carrying the traffic attributes.
 
+Profiler trace
+--------------
+Every span opened with :func:`span` or :meth:`Tracer.trace` is also a
+``jax.profiler.TraceAnnotation`` of the same name, entered whether or
+not an in-memory trace is active.  While a profiler session runs
+(``jax.profiler.trace(dir)``), the spans land on the host planes of its
+``.xplane.pb``, on the same clock as the device ops, so XProf or
+Perfetto show which host layer ran in each idle gap of the device.
+Post-hoc children (:meth:`Span.add_child`, the ``phase:*`` instants)
+and :func:`event` have no host duration and stay out of it.
+
 Overhead contract: with no active trace (the default — the global
-tracer starts disabled) every instrumentation point short-circuits
-before allocating anything; ``benchmarks/trace_report.py``'s
+tracer starts disabled) an instrumentation point builds no ``Span``:
+:func:`event` is one ContextVar read, and :func:`span` is that read
+plus one TraceMe object, which records nothing unless a profiler
+session is running.  jax is imported at the first span, so
+``repro.obs`` imports without it.  ``benchmarks/trace_report.py``'s
 perf-smoke gate pins that the tracing-off front door does not regress.
 """
 from __future__ import annotations
@@ -45,6 +59,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -138,7 +153,31 @@ class Span:
 _CURRENT: "contextvars.ContextVar[Optional[Span]]" = \
     contextvars.ContextVar("repro_obs_current_span", default=None)
 
-_NULL = contextlib.nullcontext(None)
+
+@functools.cache
+def _trace_me():
+    """``jax.profiler.TraceAnnotation``, imported at the first span (a
+    null context where jax is absent)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # pragma: no cover - jax is always present here
+        return lambda name: contextlib.nullcontext()
+    return TraceAnnotation
+
+
+class _Annotation:
+    """A span with no in-memory trace: only its TraceMe, yielding None."""
+
+    __slots__ = ("_me",)
+
+    def __init__(self, name: str):
+        self._me = _trace_me()(name)
+
+    def __enter__(self) -> None:
+        self._me.__enter__()
+
+    def __exit__(self, *exc):
+        return self._me.__exit__(*exc)
 
 
 def current() -> Optional[Span]:
@@ -154,23 +193,25 @@ def _child_cm(name: str, parent: Span, attrs: Dict[str, Any]):
     parent.children.append(sp)
     token = _CURRENT.set(sp)
     try:
-        yield sp
+        with _trace_me()(name):
+            yield sp
     finally:
         sp.end_s = time.perf_counter()
         _CURRENT.reset(token)
 
 
 def span(name: str, **attrs):
-    """Open a child span under the current one; no-op without a trace.
+    """Open a child span under the current one, and a profiler-trace
+    annotation of the same name (always).
 
     The instrumentation entry every layer uses::
 
         with obs_trace.span("substrate.run", body=label) as sp:
-            ...            # sp is None when tracing is off
+            ...            # sp is None when no trace is active
     """
     parent = _CURRENT.get()
     if parent is None:
-        return _NULL
+        return _Annotation(name)
     return _child_cm(name, parent, attrs)
 
 
@@ -184,10 +225,11 @@ def event(name: str, **attrs) -> None:
 class Tracer:
     """Collects finished request traces (bounded; newest kept).
 
-    ``enabled=False`` makes :meth:`trace` a no-op context yielding None
-    — the zero-overhead off switch.  The tracer is thread-safe: roots
-    may be opened from any number of engine worker threads; each root's
-    subtree is single-threaded by the threading contract above.
+    ``enabled=False`` makes :meth:`trace` a context yielding None that
+    records no tree (only its profiler-trace annotation) — the off
+    switch.  The tracer is thread-safe: roots may be opened from any
+    number of engine worker threads; each root's subtree is
+    single-threaded by the threading contract above.
     """
 
     def __init__(self, *, enabled: bool = True, max_traces: int = 256):
@@ -196,9 +238,10 @@ class Tracer:
         self._lock = threading.Lock()
 
     def trace(self, name: str, **attrs):
-        """Open a ROOT span (a new trace) and make it current."""
+        """Open a ROOT span (a new trace) and make it current; the
+        profiler-trace annotation is entered either way."""
         if not self.enabled:
-            return _NULL
+            return _Annotation(name)
         return self._root_cm(name, attrs)
 
     @contextlib.contextmanager
@@ -208,7 +251,8 @@ class Tracer:
                     attrs=attrs)
         token = _CURRENT.set(root)
         try:
-            yield root
+            with _trace_me()(name):
+                yield root
         finally:
             root.end_s = time.perf_counter()
             _CURRENT.reset(token)

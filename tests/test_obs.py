@@ -138,6 +138,89 @@ def test_tracing_disabled_records_nothing(rng):
 
 
 # ---------------------------------------------------------------------------
+# the profiler trace
+# ---------------------------------------------------------------------------
+
+JOIN_SPANS = ("statjoin.statistics", "statjoin.plan", "statjoin.route",
+              "substrate.compile", "substrate.run")
+SORT_SPANS = ("capacity.attempt", "substrate.compile", "substrate.run",
+              "smms.fetch", "smms.compact")
+
+
+def _host_spans(logdir) -> dict:
+    """{name: [(start_ns, end_ns), ...]} of the host planes of the one
+    ``.xplane.pb`` a profiler session wrote under ``logdir``."""
+    from jax.profiler import ProfileData
+    path, = logdir.rglob("*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def _join_call(rng):
+    s_keys = rng.zipf(1.5, size=512).astype(np.int32) % 64
+    t_keys = rng.permutation(64).astype(np.int32)
+    out, _ = cluster.join(s_keys, np.arange(512, dtype=np.int32), t_keys,
+                          np.arange(64, dtype=np.int32),
+                          algorithm="statjoin", t_machines=4)
+    jax.block_until_ready(out)
+
+
+def _sort_call(rng):
+    x = jnp.asarray(rng.normal(size=(4, 256)).astype(np.float32))
+    cluster.sort(x, algorithm="smms", values=jnp.arange(1024).reshape(4, 256))
+
+
+@pytest.mark.parametrize("call,names", [(_join_call, JOIN_SPANS),
+                                        (_sort_call, SORT_SPANS)],
+                         ids=["statjoin", "smms"])
+def test_program_spans_reach_the_profiler_trace(call, names, rng, tmp_path):
+    """With no obs root open, each layer's span still lands on a host
+    plane of the profiler's trace, inside the call's interval."""
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("test.call"):
+            call(rng)
+    spans = _host_spans(tmp_path)
+    (c0, c1), = spans["test.call"]
+    for name in names:
+        assert name in spans, (name, sorted(spans))
+        for a, b in spans[name]:
+            assert c0 <= a <= b <= c1, name
+    if "statjoin.plan" in names:
+        (s0, s1), = spans["statjoin.statistics"]
+        (p0, p1), = spans["statjoin.plan"]
+        (r0, r1), = spans["statjoin.route"]
+        assert s1 <= p0 and p1 <= r0
+        (run0, _), = spans["substrate.run"]
+        assert r1 <= run0
+    else:
+        (f0, f1), = spans["smms.fetch"]
+        (k0, _), = spans["smms.compact"]
+        (_, a1), = spans["capacity.attempt"]
+        assert a1 <= f0 and f1 <= k0
+    # post-hoc children and instant events stay out of it
+    assert not any(n.startswith("phase:") for n in spans)
+
+
+def test_spans_without_root_or_profiler_build_no_tree(rng):
+    tracer = obs.get_tracer()
+    assert not tracer.enabled
+    with obs.span("statjoin.plan") as sp:
+        assert sp is None and obs.current() is None
+    with Tracer(enabled=False).trace("query") as root:
+        assert root is None
+    _join_call(rng)
+    _sort_call(rng)
+    assert not tracer.traces and tracer.last() is None
+    assert obs.current() is None
+
+
+# ---------------------------------------------------------------------------
 # metrics registry
 # ---------------------------------------------------------------------------
 
