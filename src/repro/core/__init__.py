@@ -16,8 +16,8 @@ from .randjoin import choose_ab, randjoin, randjoin_shard
 from .repartition import repartition_join
 from .sampling import algorithm_s, terasort_sample_count
 from .smms import SortResult, default_cap_factor, smms_shard, smms_sort
-from .statjoin import (JoinStatistics, Rectangle, collect_statistics,
-                       plan_statjoin, statjoin)
+from .statjoin import (JoinStatistics, Rectangle, StatJoinPlan,
+                       collect_statistics, plan_statjoin, statjoin)
 from .terasort import terasort_shard, terasort_sort
 
 __all__ = [
@@ -30,6 +30,6 @@ __all__ = [
     "randjoin_shard",
     "repartition_join", "algorithm_s", "terasort_sample_count",
     "SortResult", "default_cap_factor", "smms_shard", "smms_sort",
-    "JoinStatistics", "Rectangle", "collect_statistics", "plan_statjoin",
-    "statjoin", "terasort_shard", "terasort_sort",
+    "JoinStatistics", "Rectangle", "StatJoinPlan", "collect_statistics",
+    "plan_statjoin", "statjoin", "terasort_shard", "terasort_sort",
 ]

@@ -17,6 +17,18 @@ Planner (faithful to §4.3.2-4.3.3):
   * Small results (and residuals) go one-by-one to the currently
     least-loaded machine.
 
+The plan is a struct of arrays (:class:`StatJoinPlan`), one row per
+rectangle, from the planner through routing to ``report.plan``; it
+iterates as :class:`Rectangle` objects for callers that want them.  Only
+the big keys (fewer than t) are planned in a Python loop.  The greedy is
+a heap of (load, machine) pairs of Python ints: its least pair is the
+lowest-index machine among the least loaded, the first minimum that
+``np.argmin(loads)`` would pick, so ties go to the lowest machine and
+equal small results go round robin from machine 0.  Routing sorts each
+table once by (key, row) packed into an int64, finds each rectangle's
+key group by ``searchsorted``, and lays the rectangles' rows out per
+machine with ``np.repeat``, in plan order within each machine.
+
 Theorem 6: every machine's output <= 2 W / t — deterministically.  That
 bound is the static output-buffer capacity on TPU.
 
@@ -31,8 +43,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import heapq
 import math
-from typing import List, Optional, Tuple
+from typing import Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +59,8 @@ from .localjoin import MASKED_KEY, local_equijoin
 from .alpha_k import statjoin_workload_bound
 
 __all__ = [
-    "JoinStatistics", "Rectangle", "collect_statistics", "plan_statjoin",
-    "statjoin",
+    "JoinStatistics", "Rectangle", "StatJoinPlan", "collect_statistics",
+    "plan_statjoin", "statjoin",
 ]
 
 
@@ -91,33 +104,59 @@ def collect_statistics(s_keys: np.ndarray, t_keys: np.ndarray
         return JoinStatistics(common, cs[is_], ct[it_])
 
 
-def plan_statjoin(stats: JoinStatistics, t: int) -> List[Rectangle]:
+@dataclasses.dataclass(frozen=True)
+class StatJoinPlan:
+    """The result-to-machine mapping as a struct of int64 arrays: row i
+    is the rectangle ``key[i] x [s_lo[i], s_hi[i]) x [t_lo[i], t_hi[i])``
+    on ``machine[i]``.
+
+    Rows are in planning order: the big rectangles placed on fresh
+    machines, then the small pool in placement order (big keys'
+    residuals, then the small keys in statistics order).  Iterating
+    yields the rows as :class:`Rectangle`s."""
+    key: np.ndarray
+    s_lo: np.ndarray
+    s_hi: np.ndarray
+    t_lo: np.ndarray
+    t_hi: np.ndarray
+    machine: np.ndarray
+
+    def __len__(self) -> int:
+        return len(self.key)
+
+    def __iter__(self) -> Iterator[Rectangle]:
+        cols = (self.key, self.s_lo, self.s_hi, self.t_lo, self.t_hi,
+                self.machine)
+        return (Rectangle(*row) for row in zip(*(c.tolist() for c in cols)))
+
+
+def plan_statjoin(stats: JoinStatistics, t: int) -> StatJoinPlan:
     """§4.3.2/4.3.3 planner.  Returns the result-to-machine mapping."""
     with obs_trace.span("statjoin.plan"):
         return _plan(stats, t)
 
 
-def _plan(stats: JoinStatistics, t: int) -> List[Rectangle]:
+def _plan(stats: JoinStatistics, t: int) -> StatJoinPlan:
     w = stats.total
     if w == 0:
-        return []
+        return StatJoinPlan(*(np.zeros(0, np.int64) for _ in range(6)))
     # Exact integer comparisons against the threshold W/t: MN > W/t iff
     # MN * t > W.  (float W/t misclassifies exact multiples, e.g.
     # MN = 21, W/t = 21/5: 5 * (21/5.) != 21.0 in binary floats.)
     big_mask = stats.sizes * t > w
 
-    rects: List[Rectangle] = []
-    small_pool: List[Rectangle] = []  # machine=-1 until placed
-    next_machine = 0
-    loads = np.zeros(t, dtype=np.int64)
+    placed = []  # (key, s_lo, s_hi, t_lo, t_hi, machine) on fresh machines
+    pool = []    # big keys' residuals (and overflow), placed by the greedy
+    loads = [0] * t
 
-    # ---- big results: split along the longer side into j rectangles -------
-    for key, m_k, n_k in zip(stats.keys[big_mask], stats.m[big_mask],
-                             stats.n[big_mask]):
-        mn = int(m_k) * int(n_k)
+    # ---- big results (fewer than t): split along the longer side -----------
+    for key, m_k, n_k in zip(stats.keys[big_mask].tolist(),
+                             stats.m[big_mask].tolist(),
+                             stats.n[big_mask].tolist()):
+        mn = m_k * n_k
         j = -(-mn * t // w)  # ceil(MN / (W/t)) in exact integers
         split_s = m_k >= n_k
-        longer = int(m_k if split_s else n_k)
+        longer = m_k if split_s else n_k
         j = min(j, longer)  # cannot split finer than one tuple per interval
         base, extra = divmod(longer, j)
         # interval sizes (desc): 'extra' intervals of base+1, rest of base
@@ -129,65 +168,88 @@ def _plan(stats: JoinStatistics, t: int) -> List[Rectangle]:
             lo += size
         pieces.sort(key=lambda ab: ab[1] - ab[0], reverse=True)
         exact = mn * t == j * w  # MN == j * W/t, exactly
-        assigned = pieces if exact else pieces[:-1]
-        residual = None if exact else pieces[-1]
-        for (plo, phi) in assigned:
-            r = (Rectangle(int(key), plo, phi, 0, int(n_k), next_machine)
-                 if split_s else
-                 Rectangle(int(key), 0, int(m_k), plo, phi, next_machine))
-            if next_machine < t:
-                rects.append(r)
-                loads[next_machine] += r.size
-                next_machine += 1
+        residual = None if exact else pieces.pop()
+        for plo, phi in pieces:
+            rect = ((key, plo, phi, 0, n_k) if split_s
+                    else (key, 0, m_k, plo, phi))
+            if len(placed) < t:
+                loads[len(placed)] = (phi - plo) * (n_k if split_s else m_k)
+                placed.append(rect + (len(placed),))
             else:  # cannot happen when sum(j_B - 1) <= t; guard anyway
-                small_pool.append(dataclasses.replace(r, machine=-1))
+                pool.append(rect)
         if residual is not None:
             plo, phi = residual
-            r = (Rectangle(int(key), plo, phi, 0, int(n_k), -1) if split_s
-                 else Rectangle(int(key), 0, int(m_k), plo, phi, -1))
-            small_pool.append(r)
+            pool.append((key, plo, phi, 0, n_k) if split_s
+                        else (key, 0, m_k, plo, phi))
 
-    # ---- small results -----------------------------------------------------
-    for key, m_k, n_k in zip(stats.keys[~big_mask], stats.m[~big_mask],
-                             stats.n[~big_mask]):
-        small_pool.append(Rectangle(int(key), 0, int(m_k), 0, int(n_k), -1))
+    # ---- small results, as columns after the residuals ---------------------
+    small = ~big_mask
+    m_s = stats.m[small].astype(np.int64)
+    n_s = stats.n[small].astype(np.int64)
+    zeros = np.zeros(len(m_s), np.int64)
+    cols = [np.concatenate((c, x)) for c, x in zip(
+        np.asarray(pool, np.int64).reshape(-1, 5).T,
+        (stats.keys[small].astype(np.int64), zeros, m_s, zeros, n_s))]
 
-    # greedy: next small result to the least-loaded machine (§4.3.3)
-    for r in small_pool:
-        machine = int(np.argmin(loads))
-        rects.append(dataclasses.replace(r, machine=machine))
-        loads[machine] += r.size
-    return rects
+    # greedy: next small result to the least-loaded machine (§4.3.3).  The
+    # heap's least (load, machine) is np.argmin(loads)'s first minimum.
+    heap = [(load, i) for i, load in enumerate(loads)]
+    heapq.heapify(heap)
+    machines = []
+    for size in ((cols[2] - cols[1]) * (cols[4] - cols[3])).tolist():
+        load, i = heap[0]
+        heapq.heapreplace(heap, (load + size, i))
+        machines.append(i)
+
+    big = np.asarray(placed, np.int64).reshape(-1, 6).T
+    return StatJoinPlan(*(np.concatenate((b, c)) for b, c in zip(
+        big, cols + [np.asarray(machines, np.int64)])))
 
 
-def _routing_tensors(keys: np.ndarray, rects: List[Rectangle], t: int,
+def _routing_tensors(keys: np.ndarray, plan: StatJoinPlan, t: int,
                      side: str) -> Tuple[np.ndarray, int]:
     """Per-machine padded index lists of table rows, per the plan.
 
-    keys: the table's key column.  side: 's' or 't' picks the rect range.
+    keys: the table's int32 key column.  side: 's' or 't' picks the
+    rectangle range.  Row ``i`` of the result lists, rectangle by
+    rectangle in plan order, the rows each of machine ``i``'s rectangles
+    takes from its key's group (rows of a group in table order); ``-1``
+    pads.
     """
-    order = np.argsort(keys, kind="stable")  # ranks within key group
-    sorted_keys = keys[order]
-    group_start = {}
-    uk, first = np.unique(sorted_keys, return_index=True)
-    for k, f in zip(uk, first):
-        group_start[int(k)] = int(f)
+    n = len(keys)
+    # one sort of (key, row) packed in an int64: the stable key order
+    packed = np.sort((keys.astype(np.int64) << 32) | np.arange(n))
+    order = packed & 0xFFFFFFFF
+    sorted_keys = packed >> 32
+    # a key group starts where the sorted key changes
+    first = np.flatnonzero(np.concatenate(
+        ([True], sorted_keys[1:] != sorted_keys[:-1]))[:n])
+    group_keys = sorted_keys[first]
+    at = np.searchsorted(group_keys, plan.key)
+    found = at < len(first)
+    found[found] = group_keys[at[found]] == plan.key[found]
+    lo, hi = (plan.s_lo, plan.s_hi) if side == "s" else (plan.t_lo, plan.t_hi)
+    keep = found & (hi > lo)  # keys the table lacks and empty ranges drop
+    # order[base + lo : base + hi], clipped at the table's end as a slice
+    base = first[at[keep]]
+    start = np.minimum(base + lo[keep], n)
+    length = np.minimum(base + hi[keep], n) - start
+    machine = plan.machine[keep]
 
-    per_machine: List[List[np.ndarray]] = [[] for _ in range(t)]
-    for r in rects:
-        lo, hi = (r.s_lo, r.s_hi) if side == "s" else (r.t_lo, r.t_hi)
-        base = group_start.get(r.key)
-        if base is None or hi <= lo:
-            continue
-        per_machine[r.machine].append(order[base + lo: base + hi])
-
-    cap = max(1, max((sum(len(a) for a in lst) for lst in per_machine),
-                     default=1))
+    # rectangles grouped by machine, plan order kept within each machine
+    by = np.argsort(machine, kind="stable")
+    start, length, machine = start[by], length[by], machine[by]
+    # the rows of every rectangle, back to back: rectangle r's are
+    # rows[edges[r]:edges[r + 1]], machine i's rows[cuts[i]:cuts[i + 1]]
+    edges = np.concatenate(([0], np.cumsum(length)))
+    src = np.repeat(start - edges[:-1], length)
+    src += np.arange(len(src))
+    rows = order[src]
+    cuts = edges[np.searchsorted(machine, np.arange(t + 1))].tolist()
+    cap = max(1, max(b - a for a, b in zip(cuts, cuts[1:])))
     out = np.full((t, cap), -1, dtype=np.int64)
-    for i, lst in enumerate(per_machine):
-        if lst:
-            idx = np.concatenate(lst)
-            out[i, :len(idx)] = idx
+    for i, (a, b) in enumerate(zip(cuts, cuts[1:])):
+        out[i, :b - a] = rows[a:b]
     return out, cap
 
 
@@ -239,7 +301,7 @@ def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
     t_keys = np.asarray(t_keys, np.int32)
     if stats is None:
         stats = collect_statistics(s_keys, t_keys)
-    rects = plan_statjoin(stats, t)
+    plan = plan_statjoin(stats, t)
     w = stats.total
     if substrate is None:
         substrate = default_pool()(t)
@@ -253,8 +315,8 @@ def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
 
     # everything that turns the plan into device operands
     with obs_trace.span("statjoin.route"):
-        s_idx, _ = _routing_tensors(s_keys, rects, t, "s")
-        t_idx, _ = _routing_tensors(t_keys, rects, t, "t")
+        s_idx, _ = _routing_tensors(s_keys, plan, t, "s")
+        t_idx, _ = _routing_tensors(t_keys, plan, t, "t")
         sk, sr = frag(s_keys, np.asarray(s_rows), s_idx)
         tk, tr = frag(t_keys, np.asarray(t_rows), t_idx)
 
@@ -275,5 +337,5 @@ def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
     report = tape.report(algorithm="StatJoin", t=t, n_in=n_in, n_out=w,
                          workload=counts)
     report.theoretical_workload_bound = statjoin_workload_bound(w, t)
-    report.plan = rects
+    report.plan = plan
     return out, report
