@@ -16,10 +16,11 @@ measured ones, and the sketch round's tape entries
 (``report.sketch_phases``).  Plans are cached under a shard
 fingerprint, so repeating a query over unchanged data skips the sketch.
 
-Every algorithm runs on a Substrate (vmap virtual machines by default,
-shard_map real mesh when requested) and returns the AlphaKReport
-assembled from the instrumented collectives.  Core imports are lazy to
-keep repro.core -> repro.cluster -> repro.core import order acyclic.
+Every algorithm runs on a Substrate (by default its machines laid over
+every chip JAX sees, vmapped on each; a shard_map mesh when requested)
+and returns the AlphaKReport assembled from the instrumented
+collectives.  Core imports are lazy to keep repro.core -> repro.cluster
+-> repro.core import order acyclic.
 """
 from __future__ import annotations
 

@@ -48,6 +48,30 @@ from jax import lax
 __all__ = ["CollectiveTape"]
 
 
+def _flat(axis_name):
+    """A reduction's axis names with nested pairs flattened: a
+    MeshVmapSubstrate names its leading axis ``(chip, machine)``, so a
+    reduction over ``(leading, second)`` arrives as a nested tuple."""
+    if not isinstance(axis_name, tuple):
+        return axis_name
+    return tuple(n for name in axis_name
+                 for n in (_flat(name) if isinstance(name, tuple)
+                           else (name,)))
+
+
+def _all_gather(x, axis_name, *, tiled: bool):
+    """``lax.all_gather``, also over a ``(chip, machine)`` pair, which
+    vmap cannot gather at once: the innermost axis first, so the rows
+    come in linear machine order."""
+    if not isinstance(axis_name, tuple):
+        return lax.all_gather(x, axis_name, tiled=tiled)
+    out = x
+    for name in reversed(axis_name):
+        out = lax.all_gather(out, name)
+    out = out.reshape((-1,) + jnp.shape(x))
+    return out.reshape((-1,) + jnp.shape(x)[1:]) if tiled else out
+
+
 def _leading_count(x) -> int:
     """Default object count of an operand: its leading-axis length."""
     shape = jnp.shape(x)
@@ -101,7 +125,7 @@ class CollectiveTape:
     # ---- instrumented collectives ------------------------------------
     def all_gather(self, x, axis_name: str, *, count=None, tiled: bool = False,
                    track: bool = True):
-        out = lax.all_gather(x, axis_name, tiled=tiled)
+        out = _all_gather(x, axis_name, tiled=tiled)
         if track:
             c = jnp.asarray(count if count is not None else _leading_count(x))
             self.record(sent=c, received=lax.psum(c, axis_name))
@@ -236,7 +260,7 @@ class CollectiveTape:
 
     def psum(self, x, axis_name: str, *, count=None):
         """Reduction; O(1) control scalars are untracked by default."""
-        out = lax.psum(x, axis_name)
+        out = lax.psum(x, _flat(axis_name))
         if count is not None:
             c = jnp.asarray(count)
             self.record(sent=c, received=c)

@@ -1,14 +1,17 @@
-"""Execution substrates: one ``run(shard_fn, *args)`` API, two executors.
+"""Execution substrates: one ``run(shard_fn, *args)`` API, three executors.
 
 A per-device body written against named-axis collectives runs unchanged
-under two interchangeable executors:
+under interchangeable executors:
 
 * :class:`VmapSubstrate`     — t *virtual* machines on one device via
   ``jax.vmap`` with axis names (the unit-test / laptop path).
-* :class:`ShardMapSubstrate` — a real device mesh via ``shard_map``
-  (the production path; also exercised on forced host devices in CI).
+* :class:`ShardMapSubstrate` — a real device mesh via ``shard_map``, one
+  device per machine (also exercised on forced host devices in CI).
+* :class:`MeshVmapSubstrate` — t = chips x v machines: ``shard_map`` over
+  the chips, ``vmap`` over each chip's v machines.  The default pool
+  (:func:`machines_over_chips`) picks it whenever JAX sees several chips.
 
-Both thread a :class:`~repro.cluster.collectives.CollectiveTape` through
+Each threads a :class:`~repro.cluster.collectives.CollectiveTape` through
 the body (keyword argument ``tape``) and return ``(outputs, tape)`` with
 the tape bound to concrete per-device traffic counters, so the caller
 can assemble an AlphaKReport without knowing which executor ran.
@@ -40,18 +43,21 @@ from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .collectives import CollectiveTape
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY
 
 __all__ = ["Substrate", "VmapSubstrate", "ShardMapSubstrate",
-           "SubstratePool", "default_substrate", "default_pool",
-           "reset_default_pool", "recommend_pool_size",
-           "DONATION_PLATFORMS"]
+           "MeshVmapSubstrate", "SubstratePool", "machines_over_chips",
+           "default_pool", "reset_default_pool",
+           "recommend_pool_size", "DONATION_PLATFORMS"]
 
 AxisSpec = Union[int, Tuple[str, int]]
+# What a body passes to a collective: one axis name, or the (chip,
+# machine) pair of a MeshVmapSubstrate's leading axis.
+AxisName = Union[str, Tuple[str, str]]
 
 _DEFAULT_NAMES = ("i", "j", "k")
 
@@ -125,16 +131,17 @@ class Substrate:
             return dict(self.stats)
 
     @property
-    def axis_names(self) -> Tuple[str, ...]:
+    def axis_names(self) -> Tuple[AxisName, ...]:
+        """What a body names each axis by in its collectives."""
         return tuple(n for n, _ in self.axes)
 
     @property
-    def axis_name(self) -> str:
+    def axis_name(self) -> AxisName:
         """The sole axis name (1D substrates)."""
         if len(self.axes) != 1:
             raise ValueError(f"substrate has {len(self.axes)} axes; "
                              "use .axis_names")
-        return self.axes[0][0]
+        return self.axis_names[0]
 
     @property
     def shape(self) -> Tuple[int, ...]:
@@ -180,15 +187,53 @@ class Substrate:
             sp.add_child(f"phase:{ph.name}", sent=ph.sent,
                          received=ph.received)
 
-    @staticmethod
-    def _call(fn: Callable, args, *, built: bool, body: str):
-        """Call a program; the first call of one just built traces and
-        compiles it (or loads it from the persistent cache), inside a
+    def _program(self, sp: Optional["obs_trace.Span"], shard_fn: Callable,
+                 args, donate: Tuple[int, ...], build: Callable):
+        """The compiled program of ``shard_fn`` at ``args``' shapes, as
+        ``(fn, tape, built)``: from the cache, or made by ``build()`` and
+        counted as a compile (call under the run lock).
+
+        ``jax.jit``'s own cache keys on function identity, so a fresh
+        wrapper closure per run would recompile every call; reusing the
+        wrapper (and its tape, whose static phase metadata the trace
+        populated) keeps one program per (body, donation, signature).
+        """
+        key = (_stable_fn_key(shard_fn), donate,
+               tuple((jnp.shape(a), str(getattr(a, "dtype", type(a))))
+                     for a in args))
+        label = _fn_label(shard_fn)
+        cached = self._compiled.get(key)
+        built = cached is None
+        if built:
+            cached = self._compiled[key] = build()
+            self.stats["compiles"] += 1
+            self.stats[f"compiles[{label}]"] += 1
+            if sp is not None:
+                sp.add_event("compile", body=label)
+        else:
+            self.stats["program_cache_hits"] += 1
+            if sp is not None:
+                sp.add_event("program_cache_hit")
+        if donate:
+            self.stats["donated_runs"] += 1
+        fn, tape = cached
+        return fn, tape, built
+
+    def _execute(self, sp: Optional["obs_trace.Span"], fn: Callable,
+                 tape: CollectiveTape, args, *, built: bool, body: str):
+        """Call a program and bind a private snapshot of its tape.
+
+        The first call of a program just built traces and compiles it
+        (or loads it from the persistent cache), inside a
         ``substrate.compile`` span."""
-        if not built:
-            return fn(*args)
-        with obs_trace.span("substrate.compile", body=body):
-            return fn(*args)
+        if built:
+            with obs_trace.span("substrate.compile", body=body):
+                out, frames = fn(*args)
+        else:
+            out, frames = fn(*args)
+        snap = tape.bound_snapshot(jax.tree.map(np.asarray, frames))
+        self._attach_phases(sp, snap)
+        return out, snap
 
     def run(self, shard_fn: Callable, *args, donate_argnums=()):
         """Execute ``shard_fn(*local_args, tape=tape)`` on every machine.
@@ -205,6 +250,23 @@ class Substrate:
     def __repr__(self) -> str:
         axes = ",".join(f"{n}={s}" for n, s in self.axes)
         return f"{type(self).__name__}({axes})"
+
+
+def _vmapped(shard_fn: Callable, axes) -> Tuple[Callable, CollectiveTape]:
+    """``shard_fn`` with a fresh tape, vmapped over ``axes`` (outermost
+    first) under their names: returns ``(fn, tape)`` with ``fn`` giving
+    ``(outputs, traced counters)``."""
+    tape = CollectiveTape()
+
+    def wrapper(*local):
+        tape.reset()
+        out = shard_fn(*local, tape=tape)
+        return out, tape.traced()
+
+    fn = wrapper
+    for name, _ in reversed(axes):
+        fn = jax.vmap(fn, axis_name=name)
+    return fn, tape
 
 
 class VmapSubstrate(Substrate):
@@ -225,54 +287,23 @@ class VmapSubstrate(Substrate):
         self._jit = jit
         self._compiled = {}
 
-    def _build(self, shard_fn: Callable):
-        tape = CollectiveTape()
-
-        def wrapper(*local):
-            tape.reset()
-            out = shard_fn(*local, tape=tape)
-            return out, tape.traced()
-
-        fn = wrapper
-        for name, _ in reversed(self.axes):
-            fn = jax.vmap(fn, axis_name=name)
-        return fn, tape
-
     def run(self, shard_fn: Callable, *args, donate_argnums=()):
         with self._lock, obs_trace.span(
                 "substrate.run", body=_fn_label(shard_fn),
                 substrate=type(self).__name__, t=self.t) as sp:
             self.stats["runs"] += 1
             donate = self._donation(donate_argnums)
-            built = False
-            if not self._jit:
-                fn, tape = self._build(shard_fn)   # eager: donation is moot
+            if not self._jit:                  # eager: donation is moot
+                fn, tape = _vmapped(shard_fn, self.axes)
+                built = False
             else:
-                key = (_stable_fn_key(shard_fn), donate,
-                       tuple((jnp.shape(a), str(getattr(a, "dtype", type(a))))
-                             for a in args))
-                cached = self._compiled.get(key)
-                built = cached is None
-                if built:
-                    fn, tape = self._build(shard_fn)
-                    cached = self._compiled[key] = (
-                        jax.jit(fn, donate_argnums=donate), tape)
-                    self.stats["compiles"] += 1
-                    self.stats[f"compiles[{_fn_label(shard_fn)}]"] += 1
-                    if sp is not None:
-                        sp.add_event("compile", body=_fn_label(shard_fn))
-                else:
-                    self.stats["program_cache_hits"] += 1
-                    if sp is not None:
-                        sp.add_event("program_cache_hit")
-                fn, tape = cached
-                if donate:
-                    self.stats["donated_runs"] += 1
-            out, frames = self._call(fn, args, built=built,
-                                     body=_fn_label(shard_fn))
-            snap = tape.bound_snapshot(jax.tree.map(np.asarray, frames))
-            self._attach_phases(sp, snap)
-            return out, snap
+                def build():
+                    fn, tape = _vmapped(shard_fn, self.axes)
+                    return jax.jit(fn, donate_argnums=donate), tape
+                fn, tape, built = self._program(sp, shard_fn, args, donate,
+                                                build)
+            return self._execute(sp, fn, tape, args, built=built,
+                                 body=_fn_label(shard_fn))
 
 
 class ShardMapSubstrate(Substrate):
@@ -290,17 +321,7 @@ class ShardMapSubstrate(Substrate):
             mesh = make_mesh(self.shape, self.axis_names)
         self.mesh = mesh
         self._jit = jit
-        # (shard_fn, arg signature) -> (jitted fn, tape).  jax.jit's own
-        # cache keys on function identity, so a fresh wrapper closure per
-        # run() would recompile every call; reusing the wrapper (and its
-        # tape, whose static phase metadata the trace populated) restores
-        # compile caching for repeated runs of the same body.
         self._compiled = {}
-
-    def _signature(self, shard_fn: Callable, args) -> tuple:
-        return (_stable_fn_key(shard_fn),
-                tuple((jnp.shape(a), str(getattr(a, "dtype", type(a))))
-                      for a in args))
 
     def run(self, shard_fn: Callable, *args, donate_argnums=()):
         with self._lock, obs_trace.span(
@@ -308,10 +329,8 @@ class ShardMapSubstrate(Substrate):
                 substrate=type(self).__name__, t=self.t) as sp:
             self.stats["runs"] += 1
             donate = self._donation(donate_argnums) if self._jit else ()
-            key = self._signature(shard_fn, args) + (donate,)
-            cached = self._compiled.get(key)
-            built = cached is None
-            if built:
+
+            def build():
                 tape = CollectiveTape()
                 k = len(self.axes)
                 lead = (0,) * k
@@ -330,24 +349,108 @@ class ShardMapSubstrate(Substrate):
                                    out_specs=spec, check_vma=False)
                 if self._jit:
                     fn = jax.jit(fn, donate_argnums=donate)
-                cached = (fn, tape)
-                self._compiled[key] = cached
-                self.stats["compiles"] += 1
-                self.stats[f"compiles[{_fn_label(shard_fn)}]"] += 1
-                if sp is not None:
-                    sp.add_event("compile", body=_fn_label(shard_fn))
-            else:
-                self.stats["program_cache_hits"] += 1
-                if sp is not None:
-                    sp.add_event("program_cache_hit")
-            fn, tape = cached
-            if donate:
-                self.stats["donated_runs"] += 1
-            out, frames = self._call(fn, args, built=built,
-                                     body=_fn_label(shard_fn))
-            snap = tape.bound_snapshot(jax.tree.map(np.asarray, frames))
-            self._attach_phases(sp, snap)
-            return out, snap
+                return fn, tape
+
+            fn, tape, built = self._program(sp, shard_fn, args, donate,
+                                            build)
+            return self._execute(sp, fn, tape, args, built=built,
+                                 body=_fn_label(shard_fn))
+
+
+class MeshVmapSubstrate(Substrate):
+    """t machines over c chips: shard_map over a chip axis, vmap inside.
+
+    The leading axis ``(name, t1)`` is split over ``chips`` devices:
+    machine i (of that axis) lives on chip ``i // v``, ``v = t1 / chips``,
+    and each chip vmaps its v machines (and every later axis) as
+    :class:`VmapSubstrate` does.  A body names the leading axis by the
+    pair ``(CHIP_AXIS, name)`` (``axis_name`` / ``axis_names`` return
+    it), so ``lax.axis_index`` gives ``chip * v + vm`` and a collective
+    over the pair covers every machine of the axis: the flat
+    all_to_all delivers tile row k to machine k whichever chip holds
+    it.  Machine order, and with it every output and tape counter, is
+    that of ``VmapSubstrate(*axes)``.
+
+    Inputs laid out otherwise (the caller made them on one device) are
+    placed with one ``jax.device_put`` inside a ``substrate.place``
+    span; outputs come back sharded over the chips, machine-major.
+    """
+
+    CHIP_AXIS = "chip"
+
+    def __init__(self, *axes: AxisSpec, chips: int, devices=None):
+        super().__init__(*axes)
+        lead = self.axes[0][1]
+        if chips < 1 or lead % chips:
+            raise ValueError(f"{chips} chips do not divide the leading "
+                             f"axis {self.axes[0]}")
+        if self.CHIP_AXIS in (n for n, _ in self.axes):
+            raise ValueError(f"axis name {self.CHIP_AXIS!r} is the "
+                             "substrate's own")
+        from repro.launch.mesh import make_mesh
+        devices = list(jax.devices() if devices is None else devices)
+        self.chips = int(chips)
+        self.per_chip = lead // self.chips
+        self.mesh = make_mesh((self.chips,), (self.CHIP_AXIS,),
+                              devices=devices[:self.chips])
+        self.sharding = NamedSharding(self.mesh, P(self.CHIP_AXIS))
+        self._compiled = {}
+
+    @property
+    def axis_names(self) -> Tuple[AxisName, ...]:
+        lead, *rest = super().axis_names
+        return ((self.CHIP_AXIS, lead), *rest)
+
+    def _place(self, args) -> tuple:
+        """``args`` laid out machine-major over the chips."""
+        if all(isinstance(a, jax.Array)
+               and a.sharding.is_equivalent_to(self.sharding, a.ndim)
+               for a in args):
+            return args
+        with obs_trace.span("substrate.place", chips=self.chips):
+            return jax.block_until_ready(
+                jax.device_put(tuple(args), self.sharding))
+
+    def _build(self, shard_fn: Callable, n_args: int,
+               donate: Tuple[int, ...]):
+        """The jitted program: each chip's block of the leading axis,
+        vmapped over its machines, under shard_map over the chips."""
+        fn, tape = _vmapped(shard_fn, self.axes)
+        spec = P(self.CHIP_AXIS)
+        fn = jax.shard_map(fn, mesh=self.mesh, in_specs=(spec,) * n_args,
+                           out_specs=spec, check_vma=False)
+        return jax.jit(fn, donate_argnums=donate), tape
+
+    def run(self, shard_fn: Callable, *args, donate_argnums=()):
+        with self._lock, obs_trace.span(
+                "substrate.run", body=_fn_label(shard_fn),
+                substrate=type(self).__name__, t=self.t, chips=self.chips,
+                machines_per_chip=self.per_chip) as sp:
+            self.stats["runs"] += 1
+            donate = self._donation(donate_argnums)
+            args = self._place(args)
+            fn, tape, built = self._program(
+                sp, shard_fn, args, donate,
+                functools.partial(self._build, shard_fn, len(args), donate))
+            return self._execute(sp, fn, tape, args, built=built,
+                                 body=_fn_label(shard_fn))
+
+    def __repr__(self) -> str:
+        return f"{super().__repr__()[:-1]};chips={self.chips})"
+
+
+def machines_over_chips(*axes: AxisSpec) -> Substrate:
+    """The default executor: the machines of the leading axis laid over
+    the chips JAX sees.  It takes the most chips that divide both the
+    leading axis (each chip holds an equal share of the machines) and
+    the device count (a host's chips split into equal groups, never
+    three of four): their gcd.  Where that is one chip this is exactly
+    the jit-compiling :class:`VmapSubstrate`."""
+    axes = _normalize_axes(axes)
+    chips = math.gcd(axes[0][1], len(jax.devices()))
+    if chips == 1:
+        return VmapSubstrate(*axes, jit=True)
+    return MeshVmapSubstrate(*axes, chips=chips)
 
 
 class SubstratePool:
@@ -363,14 +466,14 @@ class SubstratePool:
     its compile counters.
 
     ``make`` overrides substrate construction (e.g. 1-device
-    ``ShardMapSubstrate`` in the stress tests); the default is a
-    jit-compiling :class:`VmapSubstrate`, the fast repeated-traffic
-    executor on a single host.
+    ``ShardMapSubstrate`` in the stress tests); the default,
+    :func:`machines_over_chips`, lays the machines over every chip JAX
+    sees: a jit-compiling :class:`VmapSubstrate` on one chip, a
+    :class:`MeshVmapSubstrate` on several.
     """
 
     def __init__(self, make: Optional[Callable[..., Substrate]] = None):
-        self._make = make if make is not None \
-            else (lambda *axes: VmapSubstrate(*axes, jit=True))
+        self._make = make if make is not None else machines_over_chips
         self._lock = threading.Lock()
         self._subs: dict = {}
 
@@ -455,17 +558,3 @@ def recommend_pool_size(qps: float, service_time_s: float, *,
     # in binary and must size as 10 replicas, not 11
     need = math.ceil(round(qps * service_time_s / target_utilization, 9))
     return max(1, min(int(max_replicas), int(need)))
-
-
-def default_substrate(*axes: AxisSpec,
-                      prefer_mesh: bool = False) -> Substrate:
-    """Pick an executor for the requested machine count.
-
-    shard_map needs one device per machine; when the process doesn't
-    have them (the common single-CPU test environment) fall back to
-    virtual machines under vmap.
-    """
-    sub = VmapSubstrate(*axes)
-    if prefer_mesh and len(jax.devices()) >= sub.t:
-        return ShardMapSubstrate(*axes)
-    return sub

@@ -144,3 +144,30 @@ def test_sort_kernel_choice_never_radix_on_tpu(monkeypatch):
               jnp.zeros((4, 1 << 13), jnp.bfloat16)):
         jax.eval_shape(lambda y: ops.sort(y), x)
     assert ops.DISPATCH_COUNTS == {("sort", "pallas"): 3}
+
+
+def test_machines_over_four_chips_compile(topo, monkeypatch):
+    """The front door's four-chip program (t = 64 as 16 machines on each
+    chip of a 2x2 host, SMMS with payload, kernels on) compiles for the
+    chip, with the Round-3 exchange as cross-chip all-to-all ops."""
+    import functools
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.cluster.substrate import MeshVmapSubstrate
+    from repro.core.smms import _smms_shard_kv, default_cap_factor
+
+    monkeypatch.setattr(ops, "platform", lambda: "tpu")
+    t, n = 64, 1 << 18
+    sub = MeshVmapSubstrate(("i", t), chips=4, devices=topo.devices)
+    body = functools.partial(
+        _smms_shard_kv, axis_name=sub.axis_name, t=t, r=2,
+        cap_factor=float(default_cap_factor(n, t, 2)), backend="static",
+        kernel_backend=None)
+    sharded = NamedSharding(sub.mesh, P("chip"))
+    args = [jax.ShapeDtypeStruct((t, n // t), d, sharding=sharded)
+            for d in (jnp.float32, jnp.int32)]
+    fn, _ = sub._build(body, 2, ())
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert text.count(" all-to-all(") == 2         # keys and payload
