@@ -5,9 +5,10 @@ This module is the single entry the cluster hot loops call (`ops.sort`,
 `ops.sort_partition[_kv]`, `ops.merge_sorted_rows[_kv]`).  Each call
 picks one of two backends:
 
-* ``"reference"`` — the plain jnp implementation (``jnp.sort``,
-  ``jnp.argsort``, ``jnp.searchsorted``).  Always available, always the
-  semantic contract.
+* ``"reference"`` — the plain jnp / lax implementation (``jnp.sort``,
+  ``jnp.searchsorted``, and payload sorts as one stable multi-operand
+  ``lax.sort``, bitwise ``jnp.argsort`` + gather).  Always available,
+  always the semantic contract.
 * ``"pallas"``    — the purpose-built kernels in ``bitonic.py`` /
   ``radix.py`` / ``bucketize.py`` / ``fused.py``, with the dispatch
   layer handling pad-to-pow2 with sort sentinels, key/index packing for
@@ -58,7 +59,10 @@ Dispatch accounting — TWO counters with different semantics:
   the compiled-program cache ticks nothing.  This is exactly what the
   fusion budget gates want ("how many kernel launches does one cold
   query trace?") and stays their contract.  Mirrored into the obs
-  registry as ``kernel_dispatch_traces_total{op,path}``.
+  registry as ``kernel_dispatch_traces_total{op,path}``.  A payload
+  sort on the reference path ticks ``"reference"`` when every payload
+  leaf rides through the sort, ``"reference_gather"`` when a leaf with
+  trailing dims is gathered after it.
 * ``kernel_dispatch_execs_total{op,path}`` (obs registry) ticks once
   per *execution* — a ``jax.debug.callback`` inserted at trace time
   fires every time the compiled program actually runs, so cached
@@ -86,6 +90,7 @@ import threading
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from . import bitonic, bucketize, fused, radix, flash_attention as fa
 from .radix import key_to_bits, bits_to_key
@@ -362,7 +367,9 @@ def sort_kv(keys: jnp.ndarray, values, *, backend=None, block_rows: int = 8,
     keys: (n,); values: any array with leading dim n (extra trailing dims
     ride along).  Both backends realize ``order = jnp.argsort(keys)``
     (stable) exactly: the kernel path pair-sorts (key, arange) with a
-    lexicographic network, so key ties keep input order bitwise.
+    lexicographic network, so key ties keep input order bitwise; the
+    reference path carries the payload through one stable XLA sort
+    (:func:`_sort_carrying`).
 
     ``prepadded=True``: both operands were padded to the same power of
     two (keys with their sort sentinel via ``pad_pow2``); the kernel
@@ -390,12 +397,43 @@ def sort_kv(keys: jnp.ndarray, values, *, backend=None, block_rows: int = 8,
                                             block_rows=1,
                                             interpret=interpret_mode())
         return ks[0], values[order[0]]
-    _tick("sort_kv", "reference")
-    order = jnp.argsort(keys, axis=-1)
-    if keys.ndim == 1:
-        return keys[order], values[order]
-    return (jnp.take_along_axis(keys, order, axis=-1),
-            jnp.take_along_axis(values, order, axis=-1))
+    return _sort_carrying("sort_kv", keys, values)
+
+
+def _sort_carrying(op: str, keys: jnp.ndarray, values):
+    """The XLA fallback of a payload sort: one stable multi-operand sort.
+
+    Sorts along the keys' last axis; bitwise ``order = jnp.argsort(keys)``
+    followed by gathering keys and every payload leaf by ``order`` (the
+    same comparator, ties in input order).  Leaves shaped like the keys
+    ride through the sort as operands, so no element gather is made.  A
+    leaf with trailing dims cannot be a sort operand: an iota rides in its
+    place and that leaf alone is gathered by it, ticking
+    ``"reference_gather"`` instead of ``"reference"``.
+    """
+    leaves, treedef = jax.tree.flatten(values)
+    axis = keys.ndim - 1
+    rides = [v.shape == keys.shape for v in leaves]
+    operands = [keys] + [v for v, r in zip(leaves, rides) if r]
+    gathered = not all(rides)
+    if gathered:
+        operands.append(lax.broadcasted_iota(jnp.int32, keys.shape, axis))
+    _tick(op, "reference_gather" if gathered else "reference")
+    out = lax.sort(tuple(operands), dimension=axis, num_keys=1,
+                   is_stable=True)
+    carried = iter(out[1:])
+    order = out[-1] if gathered else None
+
+    def take(v, rides_along):
+        if rides_along:
+            return next(carried)
+        if keys.ndim == 1:
+            return v[order]
+        idx = order.reshape(order.shape + (1,) * (v.ndim - keys.ndim))
+        return jnp.take_along_axis(v, idx, axis=axis)
+
+    return out[0], jax.tree.unflatten(
+        treedef, [take(v, r) for v, r in zip(leaves, rides)])
 
 
 # ---------------------------------------------------------------------------
@@ -495,9 +533,7 @@ def sort_partition_kv(keys: jnp.ndarray, values, interior: jnp.ndarray, *,
                                                   interpret=interpret_mode())
         vs = values[order]
     else:
-        _tick("sort_partition_kv", "reference")
-        order = jnp.argsort(keys)
-        ks, vs = keys[order], values[order]
+        ks, vs = _sort_carrying("sort_partition_kv", keys, values)
         cuts = jnp.searchsorted(ks, interior, side="left").astype(jnp.int32)
     starts = jnp.concatenate([jnp.zeros((1,), cuts.dtype), cuts])
     ends = jnp.concatenate([cuts, jnp.full((1,), m, cuts.dtype)])
@@ -589,8 +625,7 @@ def merge_sorted_rows_kv(keys: jnp.ndarray, values, *, backend=None):
     kernel path is bitwise-identical to the reference."""
     b = resolve_backend(backend)
     t, c = keys.shape
-    gather = lambda order: jax.tree.map(
-        lambda v: v.reshape(t * c, *v.shape[2:])[order], values)
+    flat = jax.tree.map(lambda v: v.reshape(t * c, *v.shape[2:]), values)
     if b == "pallas" and kernel_eligible("merge_sorted_rows_kv", keys):
         _tick("merge_sorted_rows_kv", "pallas")
         if _merge_fits_one_tile(t, c):
@@ -598,11 +633,8 @@ def merge_sorted_rows_kv(keys: jnp.ndarray, values, *, backend=None):
                 keys, interpret=interpret_mode())
         else:
             merged, order = _rank_merge(keys)
-        return merged, gather(order)
-    _tick("merge_sorted_rows_kv", "reference")
-    kflat = keys.reshape(-1)
-    order = jnp.argsort(kflat)
-    return kflat[order], gather(order)
+        return merged, jax.tree.map(lambda v: v[order], flat)
+    return _sort_carrying("merge_sorted_rows_kv", keys.reshape(-1), flat)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@ reverse-sorted, +-inf sentinels, non-power-of-two lengths, int32 and
 float32 keys — via the _prop shim so they run with or without hypothesis.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from _prop import given, settings, st
@@ -195,6 +196,129 @@ def test_merge_sorted_rows_kv_stable(t, c, seed):
     order = np.argsort(keys.reshape(-1), kind="stable")
     np.testing.assert_array_equal(np.asarray(gk), keys.reshape(-1)[order])
     np.testing.assert_array_equal(np.asarray(gv), vals.reshape(-1)[order])
+
+
+# ---------------------------------------------------------------------------
+# reference path: the payload rides through one stable XLA sort
+# ---------------------------------------------------------------------------
+
+def _tie_keys(dtype, shape, seed):
+    """Heavy ties, -0.0 beside +0.0, +-inf, and a PAD (sentinel) tail on
+    every row of the last axis."""
+    rng = np.random.default_rng(seed)
+    if dtype == np.float32:
+        pool = np.float32([-np.inf, -1.5, -0.0, 0.0, 2.25, np.inf])
+        pad = np.float32(np.inf)
+    else:
+        pool = np.int32([np.iinfo(np.int32).min, -7, 0, 3,
+                         np.iinfo(np.int32).max])
+        pad = np.iinfo(np.int32).max
+    k = rng.choice(pool, size=shape).astype(dtype)
+    k[..., -(shape[-1] // 5):] = pad
+    return k
+
+
+def _payload(kind, shape, seed):
+    rng = np.random.default_rng(seed)
+    n = int(np.prod(shape))
+    ids = rng.permutation(n).astype(np.int32).reshape(shape)
+    f = rng.normal(size=shape).astype(np.float32)
+    f.reshape(-1)[::7] = -0.0
+    if kind == "i32":
+        return ids
+    if kind == "f32":
+        return f
+    if kind == "tuple":
+        return (f, ids)
+    return rng.normal(size=shape + (3,)).astype(np.float32)   # "rows3"
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint32) if a.dtype == np.float32 else a
+
+
+def _assert_bitwise(got, want):
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def _oracle_ref_kv(keys, leaf):
+    """``kernels.ref.sort_kv_ref`` applied to one payload leaf (a trailing
+    payload dim goes column by column)."""
+    from repro.kernels import ref
+    if leaf.ndim == keys.ndim:
+        return ref.sort_kv_ref(jnp.asarray(keys), jnp.asarray(leaf))[1]
+    cols = [ref.sort_kv_ref(jnp.asarray(keys), jnp.asarray(leaf[..., j]))[1]
+            for j in range(leaf.shape[-1])]
+    return np.stack([np.asarray(c) for c in cols], axis=-1)
+
+
+@pytest.mark.parametrize("op", ["sort_kv", "sort_kv_rows",
+                                "sort_partition_kv", "merge_sorted_rows_kv"])
+@pytest.mark.parametrize("payload", ["i32", "f32", "tuple", "rows3"])
+@pytest.mark.parametrize("key_dtype", [np.float32, np.int32])
+def test_reference_payload_sort_bitwise(op, payload, key_dtype):
+    """Each reference payload sort equals numpy's stable argsort + gather
+    and ``ref.sort_kv_ref`` bit for bit, whichever form the payload
+    takes, and ticks the path its payload's shape picks."""
+    shape = {"sort_kv": (257,), "sort_kv_rows": (3, 129),
+             "sort_partition_kv": (257,),
+             "merge_sorted_rows_kv": (5, 41)}[op]
+    keys = _tie_keys(key_dtype, shape, seed=11)
+    if op == "merge_sorted_rows_kv":
+        keys = np.sort(keys, axis=-1, kind="stable")
+    vals = _payload(payload, shape, seed=12)
+    leaves = list(vals) if isinstance(vals, tuple) else [vals]
+    jvals = jax.tree.map(jnp.asarray, vals)
+    ops.reset_dispatch_counts()
+    if op == "merge_sorted_rows_kv":
+        gk, gv = ops.merge_sorted_rows_kv(jnp.asarray(keys), jvals,
+                                          backend="reference")
+        keys = keys.reshape(-1)
+        leaves = [v.reshape(keys.shape[0], *v.shape[2:]) for v in leaves]
+    elif op == "sort_partition_kv":
+        interior = np.sort(_tie_keys(key_dtype, (4,), seed=13))
+        gk, gv, starts, lens = ops.sort_partition_kv(
+            jnp.asarray(keys), jvals, jnp.asarray(interior),
+            backend="reference")
+        cuts = np.searchsorted(np.sort(keys, kind="stable"), interior)
+        np.testing.assert_array_equal(np.asarray(starts),
+                                      np.concatenate([[0], cuts]))
+        np.testing.assert_array_equal(
+            np.asarray(lens), np.diff(np.concatenate([[0], cuts,
+                                                      [keys.size]])))
+    else:
+        gk, gv = ops.sort_kv(jnp.asarray(keys), jvals, backend="reference")
+    got = list(gv) if isinstance(gv, tuple) else [gv]
+    order = np.argsort(keys, axis=-1, kind="stable")
+    _assert_bitwise(gk, np.take_along_axis(keys, order, axis=-1))
+    for g, v in zip(got, leaves):
+        idx = order.reshape(order.shape + (1,) * (v.ndim - keys.ndim))
+        want = np.take_along_axis(v, idx, axis=keys.ndim - 1)
+        _assert_bitwise(g, want)
+        _assert_bitwise(g, _oracle_ref_kv(keys, v))
+    path = "reference_gather" if payload == "rows3" else "reference"
+    tick_op = "sort_kv" if op == "sort_kv_rows" else op
+    assert dict(ops.DISPATCH_COUNTS) == {(tick_op, path): 1}
+
+
+@pytest.mark.parametrize("op", ["sort_kv", "merge_sorted_rows_kv"])
+@pytest.mark.parametrize("payload,gathers", [("i32", 0), ("tuple", 0),
+                                             ("rows3", 1)])
+def test_reference_payload_sort_lowering(op, payload, gathers):
+    """The reference payload sort lowers to exactly one ``sort``; only a
+    payload with trailing dims adds a gather, one, for that leaf."""
+    shape = (4, 16) if op == "merge_sorted_rows_kv" else (64,)
+    keys = jnp.zeros(shape, jnp.float32)
+    vals = jax.tree.map(jnp.asarray, _payload(payload, shape, seed=3))
+    fn = getattr(ops, op)
+    ops.reset_dispatch_counts()
+    text = jax.jit(lambda k, v: fn(k, v, backend="reference")).lower(
+        keys, vals).as_text()
+    assert text.count('"stablehlo.sort"(') == 1
+    assert text.count('"stablehlo.gather"(') == gathers
+    path = "reference_gather" if gathers else "reference"
+    assert dict(ops.DISPATCH_COUNTS) == {(op, path): 1}
 
 
 # ---------------------------------------------------------------------------

@@ -171,3 +171,21 @@ def test_machines_over_four_chips_compile(topo, monkeypatch):
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
     assert text.count(" all-to-all(") == 2         # keys and payload
+
+
+@pytest.mark.parametrize("op,shape", [
+    ("sort_kv", (16, 1 << 22)),                 # Round 1, 16 machines
+    ("merge_sorted_rows_kv", (16, 16, 550_504)),  # the receive merge
+])
+def test_reference_payload_sorts_compile_without_gather(one_chip, op, shape):
+    """At the one-chip sort cell's shapes (rows past every kernel's lane
+    bound, under ``vmap`` over the machines) the reference payload sort
+    compiles to the chip's sort with the payload as an operand: no
+    gather of keys or payload is left."""
+    fn = getattr(ops, op)
+    args = [jax.ShapeDtypeStruct(shape, d, sharding=one_chip)
+            for d in (jnp.float32, jnp.int32)]
+    body = jax.vmap(lambda k, v: fn(k, v, backend="reference"))
+    text = jax.jit(body).lower(*args).compile().as_text()
+    assert " sort(" in text
+    assert " gather(" not in text
