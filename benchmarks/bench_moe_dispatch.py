@@ -23,6 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from benchmarks.bench_sort import kernel_path
 from repro import cluster
 from repro.cluster.substrate import reset_default_pool
 from repro.configs.base import MoEConfig
@@ -143,8 +144,9 @@ def run_dispatch_budget(report_rows: List[str]) -> None:
     reset_default_pool()
     clear_plan_cache()
     ops.reset_dispatch_counts()
-    _, rep = cluster.moe_dispatch(params, x, cfg, mode="cluster",
-                                  t_machines=4, kernel_backend="pallas")
+    with kernel_path("pallas"):
+        _, rep = cluster.moe_dispatch(params, x, cfg, mode="cluster",
+                                      t_machines=4)
     ticks = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
                 if path == "pallas")
     budget = MOE_DISPATCH_BUDGET["cluster"]

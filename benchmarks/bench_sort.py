@@ -7,6 +7,7 @@ in most cases; SMMS total runtime beats Terasort by ~25%.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import sys
@@ -26,6 +27,18 @@ from repro.obs import timeit
 
 BENCH_JSON = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           os.pardir, "BENCH_sort.json")
+
+
+@contextlib.contextmanager
+def kernel_path(path: str):
+    """Pin the kernel path ``ops`` takes (its ``DEFAULT_BACKEND`` seam)
+    for the block."""
+    pinned = ops.DEFAULT_BACKEND
+    ops.DEFAULT_BACKEND = path
+    try:
+        yield
+    finally:
+        ops.DEFAULT_BACKEND = pinned
 
 # Per-trace Pallas dispatch budget for one query through the front door
 # — the fusion contract, enforced by run_dispatch_budget (CI perf-smoke)
@@ -130,14 +143,13 @@ def _radix_end_to_end(entries, report_rows, best_of) -> None:
     xw = jnp.asarray(uniform_keys(t_w * m_w, seed=7).reshape(t_w, m_w))
     e2e = {}
     for family in ("bitonic", "radix"):
-        with ops.force_sort_kernel(family):
+        with ops.force_sort_kernel(family), kernel_path("pallas"):
             reset_default_pool()
             ops.reset_dispatch_counts()
-            (keys_f, _), rep_f = cluster.sort(xw, algorithm="smms",
-                                              kernel_backend="pallas")
+            (keys_f, _), rep_f = cluster.sort(xw, algorithm="smms")
             calls = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
                         if path in KERNEL_PATHS)
-            us = best_of(xw, algorithm="smms", kernel_backend="pallas")
+            us = best_of(xw, algorithm="smms")
         e2e[family] = {"us": us, "keys": np.asarray(keys_f),
                        "dispatches": int(calls),
                        "k_workload": rep_f.k_workload}
@@ -167,8 +179,8 @@ def _radix_end_to_end(entries, report_rows, best_of) -> None:
 def run_kernel_compare(report_rows: List[str]) -> None:
     """Kernel-dispatch layer on vs off through the REAL front door.
 
-    Each row times ``cluster.sort`` (and the raw ops) with
-    kernel_backend="pallas" vs "reference" and asserts the outputs are
+    Each row times ``cluster.sort`` (and the raw ops) with the kernel
+    path pinned to "pallas" vs "reference" and asserts the outputs are
     bitwise identical — the differential contract, measured at benchmark
     scale.  Results land under ``kernel_compare[<mode>]`` of
     BENCH_sort.json, keyed "interpret" or "compiled" by the platform's
@@ -295,15 +307,16 @@ def run_kernel_compare(report_rows: List[str]) -> None:
                       reps=reps, warmup=0).best_us
 
     for algorithm in ("smms", "terasort"):
-        (ref_keys, _), rep_ref = cluster.sort(x, algorithm=algorithm,
-                                              kernel_backend="reference")
-        ref_us = best_of(x, algorithm=algorithm, kernel_backend="reference")
+        with kernel_path("reference"):
+            (ref_keys, _), rep_ref = cluster.sort(x, algorithm=algorithm)
+            ref_us = best_of(x, algorithm=algorithm)
         ops.reset_dispatch_counts()
-        (ker_keys, _), rep_ker = cluster.sort(x, algorithm=algorithm,
-                                              kernel_backend="pallas")
-        kernel_calls = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
-                           if path in KERNEL_PATHS)
-        ker_us = best_of(x, algorithm=algorithm, kernel_backend="pallas")
+        with kernel_path("pallas"):
+            (ker_keys, _), rep_ker = cluster.sort(x, algorithm=algorithm)
+            kernel_calls = sum(c for (op, path), c
+                               in ops.DISPATCH_COUNTS.items()
+                               if path in KERNEL_PATHS)
+            ker_us = best_of(x, algorithm=algorithm)
         equal = bool(np.array_equal(np.asarray(ref_keys),
                                     np.asarray(ker_keys)))
         assert equal, f"{algorithm}: kernel path diverged from reference"
@@ -388,43 +401,45 @@ def run_exchange_compare(report_rows: List[str]) -> None:
         return timeit(lambda: cluster.sort(xt, **kw),
                       reps=reps, warmup=0).best_us
 
-    for t in (16, 64, 256):
-        m = n // t
-        xt = jnp.asarray(x.reshape(t, m))
-        kw = dict(algorithm="smms", kernel_backend="reference")
-        (flat_keys, _), rep_flat = cluster.sort(xt, exchange="flat", **kw)
-        (stag_keys, _), rep_stag = cluster.sort(xt, exchange="staged", **kw)
-        assert bool(np.array_equal(np.asarray(flat_keys),
-                                   np.asarray(stag_keys))), (
-            f"t={t}: staged exchange diverged from flat")
-        assert rep_stag.exchange_topology == "staged", rep_stag.summary()
-        flat_us = best_of(xt, exchange="flat", **kw)
-        stag_us = best_of(xt, exchange="staged", **kw)
-        t1, t2 = factor_shards(t)
-        flat_bytes = bytes_per_obj * flat_receive_capacity(
-            m, t, rep_flat.cap_factor)
-        stag_bytes = bytes_per_obj * max(staged_receive_capacities(
-            m, t1, t2, rep_stag.cap_factor))
-        entries.append({
-            "t": t, "m": m, "staged_shape": [t1, t2],
-            "flat_us": round(flat_us), "staged_us": round(stag_us),
-            "flat_cap_factor": rep_flat.cap_factor,
-            "staged_cap_factor": rep_stag.cap_factor,
-            "flat_capacity_attempts": rep_flat.capacity_attempts,
-            "staged_capacity_attempts": rep_stag.capacity_attempts,
-            "flat_peak_receive_bytes": flat_bytes,
-            "staged_peak_receive_bytes": stag_bytes,
-            "flat_alpha": rep_flat.alpha, "staged_alpha": rep_stag.alpha,
-            "bitwise_equal": True,
-        })
-        report_rows.append(
-            f"exchange_compare,t={t},flat_us={flat_us:.0f},"
-            f"staged_us={stag_us:.0f},flat_bytes={flat_bytes},"
-            f"staged_bytes={stag_bytes}")
-        if t == 256:
-            assert stag_bytes < flat_bytes, (
-                f"staged exchange must shrink the peak receive buffer at "
-                f"t=256: staged {stag_bytes} vs flat {flat_bytes} bytes")
+    with kernel_path("reference"):
+        for t in (16, 64, 256):
+            m = n // t
+            xt = jnp.asarray(x.reshape(t, m))
+            kw = dict(algorithm="smms")
+            (flat_keys, _), rep_flat = cluster.sort(xt, exchange="flat", **kw)
+            (stag_keys, _), rep_stag = cluster.sort(xt, exchange="staged",
+                                                    **kw)
+            assert bool(np.array_equal(np.asarray(flat_keys),
+                                       np.asarray(stag_keys))), (
+                f"t={t}: staged exchange diverged from flat")
+            assert rep_stag.exchange_topology == "staged", rep_stag.summary()
+            flat_us = best_of(xt, exchange="flat", **kw)
+            stag_us = best_of(xt, exchange="staged", **kw)
+            t1, t2 = factor_shards(t)
+            flat_bytes = bytes_per_obj * flat_receive_capacity(
+                m, t, rep_flat.cap_factor)
+            stag_bytes = bytes_per_obj * max(staged_receive_capacities(
+                m, t1, t2, rep_stag.cap_factor))
+            entries.append({
+                "t": t, "m": m, "staged_shape": [t1, t2],
+                "flat_us": round(flat_us), "staged_us": round(stag_us),
+                "flat_cap_factor": rep_flat.cap_factor,
+                "staged_cap_factor": rep_stag.cap_factor,
+                "flat_capacity_attempts": rep_flat.capacity_attempts,
+                "staged_capacity_attempts": rep_stag.capacity_attempts,
+                "flat_peak_receive_bytes": flat_bytes,
+                "staged_peak_receive_bytes": stag_bytes,
+                "flat_alpha": rep_flat.alpha, "staged_alpha": rep_stag.alpha,
+                "bitwise_equal": True,
+            })
+            report_rows.append(
+                f"exchange_compare,t={t},flat_us={flat_us:.0f},"
+                f"staged_us={stag_us:.0f},flat_bytes={flat_bytes},"
+                f"staged_bytes={stag_bytes}")
+            if t == 256:
+                assert stag_bytes < flat_bytes, (
+                    f"staged exchange must shrink the peak receive buffer at "
+                    f"t=256: staged {stag_bytes} vs flat {flat_bytes} bytes")
 
     _merge_bench_json({"exchange_compare": {
         "suite": "bench_sort.run_exchange_compare",
@@ -455,8 +470,7 @@ def run_dispatch_budget(report_rows: List[str]) -> None:
 
     def sort_query(algorithm, exchange="flat"):
         return lambda: cluster.sort(x, algorithm=algorithm,
-                                    exchange=exchange,
-                                    kernel_backend="pallas")
+                                    exchange=exchange)
 
     def radix_query(algorithm):
         # forced-radix variant: the pool is already fresh when the
@@ -464,14 +478,12 @@ def run_dispatch_budget(report_rows: List[str]) -> None:
         # the force context and the program keeps the radix family
         def q():
             with ops.force_sort_kernel("radix"):
-                return cluster.sort(x, algorithm=algorithm,
-                                    kernel_backend="pallas")
+                return cluster.sort(x, algorithm=algorithm)
         return q
 
     def join_query(algorithm):
         return lambda: cluster.join(s_keys, rows, t_keys, rows,
-                                    algorithm=algorithm, t_machines=t,
-                                    kernel_backend="pallas")
+                                    algorithm=algorithm, t_machines=t)
 
     queries = {"smms": sort_query("smms"),
                "terasort": sort_query("terasort"),
@@ -486,7 +498,8 @@ def run_dispatch_budget(report_rows: List[str]) -> None:
     for algorithm, query in queries.items():
         reset_default_pool()
         ops.reset_dispatch_counts()
-        query()
+        with kernel_path("pallas"):
+            query()
         ticks = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
                     if path in KERNEL_PATHS)
         budget = DISPATCH_BUDGET[algorithm]

@@ -17,8 +17,9 @@ phases drive the entry points a user calls and check every answer:
 * serve — a ``QueryEngine`` on the Pallas kernel backend answers mixed
   sort and join queries at kernel-eligible shapes (t = 8, 2^13 to 2^16
   keys per machine); each answer must equal, bit for bit, the same
-  query run with ``kernel_backend="reference"``, and no eligible
-  operand may take the reference path.
+  query run with the path pinned to the reference
+  (``ops.DEFAULT_BACKEND``), and no eligible operand may take the
+  reference path.
 * mesh (``--chips 4``) — ``cluster.sort(algorithm="smms")`` with the
   flat and the staged 2x2 exchange (uniform keys, with ties) and
   ``cluster.join(algorithm="statjoin")`` on a ``ShardMapSubstrate``
@@ -318,30 +319,36 @@ def serve_phase(sizes, seed: int) -> None:
     from repro.kernels import ops
     from repro.serve.query import QueryEngine, run_spec
     specs = serve_specs(sizes, seed)
-    with Phase("serve"):
-        with QueryEngine(kernel_backend="pallas", result_cache_size=0,
-                         max_pending=len(specs)) as engine:
-            t0 = time.perf_counter()
-            results = engine.run(specs, timeout=1800)
-            log(f"  engine served {len(results)} queries in "
-                f"{time.perf_counter() - t0:.3f}s")
-        pallas_counts = dict(ops.DISPATCH_COUNTS)
-        for r in results:
-            check(r.ok, f"{r.spec.tag}: {r.error}")
-            report_line(f"{r.spec.tag} {r.spec.kind} {r.algorithm}",
-                        r.report, r.exec_s)
-        fallbacks = {k: c for k, c in pallas_counts.items()
-                     if k[1] == "reference"}
-        check(not fallbacks, f"eligible operands took the reference path: "
-              f"{fallbacks}")
-        check(any(p in ("pallas", "radix") for _, p in pallas_counts),
-              "no kernel dispatched")
-    with Phase("serve-reference"):
-        for r in results:
-            want, _ = run_spec(r.spec, kernel_backend="reference")
-            check(same_answer(r.spec.kind, r.value, want),
-                  f"{r.spec.tag}: pallas answer != reference answer")
-        log(f"  {len(results)} answers bitwise equal to the reference")
+    pinned = ops.DEFAULT_BACKEND
+    ops.DEFAULT_BACKEND = "pallas"
+    try:
+        with Phase("serve"):
+            with QueryEngine(result_cache_size=0,
+                             max_pending=len(specs)) as engine:
+                t0 = time.perf_counter()
+                results = engine.run(specs, timeout=1800)
+                log(f"  engine served {len(results)} queries in "
+                    f"{time.perf_counter() - t0:.3f}s")
+            pallas_counts = dict(ops.DISPATCH_COUNTS)
+            for r in results:
+                check(r.ok, f"{r.spec.tag}: {r.error}")
+                report_line(f"{r.spec.tag} {r.spec.kind} {r.algorithm}",
+                            r.report, r.exec_s)
+            fallbacks = {k: c for k, c in pallas_counts.items()
+                         if k[1] == "reference"}
+            check(not fallbacks, f"eligible operands took the reference "
+                  f"path: {fallbacks}")
+            check(any(p in ("pallas", "radix") for _, p in pallas_counts),
+                  "no kernel dispatched")
+        ops.DEFAULT_BACKEND = "reference"
+        with Phase("serve-reference"):
+            for r in results:
+                want, _ = run_spec(r.spec)
+                check(same_answer(r.spec.kind, r.value, want),
+                      f"{r.spec.tag}: pallas answer != reference answer")
+            log(f"  {len(results)} answers bitwise equal to the reference")
+    finally:
+        ops.DEFAULT_BACKEND = pinned
 
 
 def mesh_phase(sizes, seed: int) -> None:

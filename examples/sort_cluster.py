@@ -18,6 +18,7 @@ from repro import cluster
 from repro.cluster import ShardMapSubstrate
 from repro.core.alpha_k import smms_workload_bound
 from repro.data import lidar_like
+from repro.kernels import ops
 
 
 def main():
@@ -41,24 +42,30 @@ def main():
         print(f"  phase {p.name:22s} max sent {int(np.max(p.sent)):6d}  "
               f"max received {int(np.max(p.received)):6d}")
 
-    # --- the same sort through the Pallas kernel layer -------------------
-    # kernel_backend="pallas" routes the Round-1 bitonic sort, the
-    # branch-free searchsorted partition, and the Round-3 merge kernel; the
-    # output is bitwise identical to the jnp reference path.  (The kernel
-    # mode follows the platform: the Pallas interpreter on CPU, Mosaic-
-    # compiled kernels on a TPU, for the identical calls.)
+    # --- the same sort on each kernel path -----------------------------
+    # repro.kernels.ops chooses the path by itself (the Pallas kernels on
+    # a TPU, the jnp reference elsewhere); its test seam
+    # ops.DEFAULT_BACKEND pins one.  On the Pallas path the Round-1
+    # bitonic sort, the branch-free searchsorted partition and the
+    # Round-3 merge kernel run; the output is bitwise identical to the
+    # reference path.  (The Pallas interpreter runs them on CPU, Mosaic
+    # compiles them on a TPU, for the identical calls.)
     mk = 1 << 10
     xk = jnp.asarray(lidar_like(t * mk, seed=3).reshape(t, mk))
-    (keys_ref, _), _ = cluster.sort(xk, algorithm="smms", r=r,
-                                    substrate=ShardMapSubstrate(("machines", t)),
-                                    kernel_backend="reference")
-    (keys_ker, _), rep_k = cluster.sort(xk, algorithm="smms", r=r,
-                                        substrate=ShardMapSubstrate(("machines", t)),
-                                        kernel_backend="pallas")
-    assert np.array_equal(np.asarray(keys_ref), np.asarray(keys_ker))
-    print(f"kernel_backend='pallas' (n={t*mk}): bitwise-identical output, "
+    keys_by_path = {}
+    pinned = ops.DEFAULT_BACKEND
+    try:
+        for path in ("reference", "pallas"):
+            ops.DEFAULT_BACKEND = path
+            (keys_by_path[path], _), rep_k = cluster.sort(
+                xk, algorithm="smms", r=r,
+                substrate=ShardMapSubstrate(("machines", t)))
+    finally:
+        ops.DEFAULT_BACKEND = pinned
+    assert np.array_equal(np.asarray(keys_by_path["reference"]),
+                          np.asarray(keys_by_path["pallas"]))
+    print(f"pallas kernel path (n={t*mk}): bitwise-identical output, "
           f"imbalance {rep_k.imbalance:.3f}")
-
 
 if __name__ == "__main__":
     from repro.compile_cache import enable_compile_cache

@@ -88,20 +88,14 @@ def sort(x, *, algorithm: str = "smms",
          substrate: Optional[Substrate] = None,
          values=None, r: int = 2, seed: int = 0,
          cap_factor: Optional[float] = None,
-         backend: str = "static", kernel_backend: Optional[str] = None,
-         policy=None, exchange: str = "flat", overlap_chunks: int = 2,
+         backend: str = "static", policy=None, exchange: str = "flat",
+         overlap_chunks: int = 2,
          donate: Optional[bool] = None):
     """Distributed sort of x: (t, m).  Returns ((keys, values), report).
 
     algorithm: one of SORT_ALGORITHMS, or "auto" to let the planner
     sketch the shards and pick (the dispatched call is bitwise-identical
     to naming the winner explicitly).
-
-    kernel_backend: "pallas" routes every local sort/partition/merge hot
-    loop through the Pallas kernels (repro.kernels.ops), "reference"
-    pins the jnp path, None uses ops.DEFAULT_BACKEND (the
-    REPRO_KERNEL_BACKEND env var).  Outputs and (alpha, k) reports are
-    bitwise-identical across kernel backends.
 
     exchange: shuffle topology — "flat" (one t-way all_to_all, the
     default), "staged" (two sqrt(t)-way hops over a t1 x t2 factored
@@ -134,12 +128,11 @@ def sort(x, *, algorithm: str = "smms",
     if algorithm == AUTO:
         from repro.planner import plan_sort_query
         plan, sketch_phases = plan_sort_query(
-            x, t=t, r=r, kernel_backend=kernel_backend,
-            substrate=_resolve_substrate(substrate, t))
+            x, t=t, r=r, substrate=_resolve_substrate(substrate, t))
         out, report = sort(x, algorithm=plan.algorithm, substrate=substrate,
                            values=values, r=r, seed=seed,
                            cap_factor=cap_factor, backend=backend,
-                           kernel_backend=kernel_backend, policy=policy,
+                           policy=policy,
                            exchange=(plan.exchange if exchange == AUTO
                                      else exchange),
                            overlap_chunks=overlap_chunks, donate=donate)
@@ -167,8 +160,7 @@ def sort(x, *, algorithm: str = "smms",
     if algorithm == "smms":
         from repro.core.smms import smms_sort
         return smms_sort(x, r=r, cap_factor=cap_factor, values=values,
-                         backend=backend, kernel_backend=kernel_backend,
-                         substrate=substrate, policy=policy,
+                         backend=backend, substrate=substrate, policy=policy,
                          exchange=exchange, overlap_chunks=overlap_chunks,
                          donate=donate)
     if algorithm == "terasort":
@@ -176,14 +168,12 @@ def sort(x, *, algorithm: str = "smms",
         if values is not None:
             return terasort_sort(x, seed=seed, cap_factor=cap_factor,
                                  backend=backend, values=values,
-                                 kernel_backend=kernel_backend,
                                  substrate=substrate, policy=policy,
                                  exchange=exchange,
                                  overlap_chunks=overlap_chunks,
                                  donate=donate)
         flat, report = terasort_sort(x, seed=seed, cap_factor=cap_factor,
                                      backend=backend,
-                                     kernel_backend=kernel_backend,
                                      substrate=substrate, policy=policy,
                                      exchange=exchange,
                                      overlap_chunks=overlap_chunks,
@@ -197,7 +187,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
          t_machines: int, substrate: Optional[Substrate] = None,
          out_capacity: Optional[int] = None, seed: int = 0,
          in_cap_factor: float = 4.0, out_cap_factor: float = 1.05,
-         kernel_backend: Optional[str] = None,
          ab: Optional[Tuple[int, int]] = None, stats=None,
          mem_budget: Optional[int] = None, small_side: Optional[str] = None,
          donate: Optional[bool] = None):
@@ -206,9 +195,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
     algorithm: one of JOIN_ALGORITHMS, or "auto" — sketch both tables in
     one on-device pass, score StatJoin/RandJoin/Broadcast/Repartition
     through the theorem cost model, dispatch to the winner.
-
-    kernel_backend: as in :func:`sort` — routes the per-device sort and
-    binary-search hot loops through the Pallas kernels when "pallas".
 
     out_capacity defaults to the Theorem-6 bound ceil(2W/t) + slack for
     the algorithms that need an explicit buffer (randjoin/repartition/
@@ -228,14 +214,12 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
         from repro.planner import plan_join_query
         plan, sketch_phases = plan_join_query(
             s_keys, t_keys, t_machines=t_machines, mem_budget=mem_budget,
-            kernel_backend=kernel_backend,
             substrate=_resolve_substrate(substrate, t_machines))
         out, report = join(s_keys, s_rows, t_keys, t_rows,
                            algorithm=plan.algorithm, t_machines=t_machines,
                            substrate=substrate, out_capacity=out_capacity,
                            seed=seed, in_cap_factor=in_cap_factor,
-                           out_cap_factor=out_cap_factor,
-                           kernel_backend=kernel_backend, ab=ab, stats=stats,
+                           out_cap_factor=out_cap_factor, ab=ab, stats=stats,
                            mem_budget=mem_budget, small_side=small_side,
                            donate=donate)
         _attach_plan(report, plan, sketch_phases)
@@ -247,7 +231,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
         from repro.core.statjoin import statjoin
         return statjoin(s_keys, s_rows, t_keys, t_rows, t_machines=t_machines,
                         out_cap_factor=out_cap_factor, stats=stats,
-                        kernel_backend=kernel_backend,
                         substrate=_resolve_substrate(substrate, t_machines),
                         out_capacity=out_capacity, donate=donate)
 
@@ -277,7 +260,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
                                 out_capacity=int(cap), seed=seed,
                                 in_cap_factor=in_cap_factor
                                 * (cap / out_capacity),
-                                kernel_backend=kernel_backend,
                                 ab=(a, b), substrate=rj_sub)
             return (out, rep), int(np.asarray(out.dropped).max())
 
@@ -303,7 +285,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
             out, rep = broadcast_join(s_keys, s_rows, t_keys, t_rows,
                                       t_machines=t_machines,
                                       out_capacity=int(cap),
-                                      kernel_backend=kernel_backend,
                                       substrate=bc_sub,
                                       small_side=small_side)
             return (out, rep), int(np.asarray(out.dropped).max())
@@ -321,7 +302,6 @@ def join(s_keys, s_rows, t_keys, t_rows, *, algorithm: str = "statjoin",
     from repro.core.repartition import repartition_join
     return repartition_join(s_keys, s_rows, t_keys, t_rows,
                             t_machines=t_machines, out_capacity=out_capacity,
-                            kernel_backend=kernel_backend,
                             substrate=_resolve_substrate(substrate,
                                                          t_machines),
                             donate=donate)
@@ -340,8 +320,7 @@ def _dense_moe_jit():
 def moe_dispatch(params, x, cfg, *, mode: Optional[str] = None,
                  t_machines: int = 8,
                  substrate: Optional[Substrate] = None, policy=None,
-                 act: str = "swiglu", kernel_backend: Optional[str] = None,
-                 rng=None):
+                 act: str = "swiglu", rng=None):
     """One MoE layer with dispatch as a first-class cluster workload.
 
     Token->expert routing is the skew-join problem (tokens keyed by
@@ -397,7 +376,6 @@ def moe_dispatch(params, x, cfg, *, mode: Optional[str] = None,
             t_machines=t_machines, num_experts=e, top_k=k,
             extra_slots=cfg.extra_slots,
             capacity_factor=cfg.capacity_factor,
-            kernel_backend=kernel_backend,
             substrate=_resolve_substrate(substrate, t_machines))
         if mode == AUTO:
             mode = plan.algorithm
@@ -407,8 +385,7 @@ def moe_dispatch(params, x, cfg, *, mode: Optional[str] = None,
         counts = expert_counts_estimate(plan.profile, e)
         y, report = cluster_moe_dispatch(
             params, x, cfg, t_machines=t_machines, counts=counts,
-            substrate=substrate, policy=policy, act=act,
-            kernel_backend=kernel_backend)
+            substrate=substrate, policy=policy, act=act)
         _attach_plan(report, plan, sketch_phases)
         return y, report
 

@@ -46,6 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .collectives import CollectiveTape
+from ..kernels import ops
 from ..obs import trace as obs_trace
 from ..obs.metrics import REGISTRY
 
@@ -196,9 +197,10 @@ class Substrate:
         ``jax.jit``'s own cache keys on function identity, so a fresh
         wrapper closure per run would recompile every call; reusing the
         wrapper (and its tape, whose static phase metadata the trace
-        populated) keeps one program per (body, donation, signature).
+        populated) keeps one program per (body, kernel path, donation,
+        signature).
         """
-        key = (_stable_fn_key(shard_fn), donate,
+        key = (_stable_fn_key(shard_fn), ops.program_key(), donate,
                tuple((jnp.shape(a), str(getattr(a, "dtype", type(a))))
                      for a in args))
         label = _fn_label(shard_fn)

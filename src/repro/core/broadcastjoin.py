@@ -36,17 +36,15 @@ __all__ = ["broadcast_join"]
 
 
 def _broadcast_body(bk, br, sk, sr, *, tape: CollectiveTape, axis,
-                    small_side, out_capacity, kernel_backend):
+                    small_side, out_capacity):
     """Per-device body (module-level for stable compiled-program keys)."""
     with tape.phase("broadcast+join"):
         cnt = jnp.sum(sk != MASKED_KEY)
         gk = tape.all_gather(sk, axis, count=cnt).reshape(-1)
         gr = tape.all_gather(sr, axis, track=False).reshape(-1)
         if small_side == "s":
-            return local_equijoin(gk, gr, bk, br, out_capacity,
-                                  kernel_backend=kernel_backend)
-        return local_equijoin(bk, br, gk, gr, out_capacity,
-                              kernel_backend=kernel_backend)
+            return local_equijoin(gk, gr, bk, br, out_capacity)
+        return local_equijoin(bk, br, gk, gr, out_capacity)
 
 
 def _deal_round_robin(keys: np.ndarray, rows: np.ndarray, t: int):
@@ -64,7 +62,6 @@ def _deal_round_robin(keys: np.ndarray, rows: np.ndarray, t: int):
 def broadcast_join(s_keys: np.ndarray, s_rows: np.ndarray,
                    t_keys: np.ndarray, t_rows: np.ndarray,
                    t_machines: int, out_capacity: int,
-                   kernel_backend: Optional[str] = None,
                    substrate: Optional[Substrate] = None,
                    small_side: Optional[str] = None):
     """All-gather the small table, join locally.  Returns (JoinOutput, report).
@@ -94,8 +91,7 @@ def broadcast_join(s_keys: np.ndarray, s_rows: np.ndarray,
 
     body = functools.partial(_broadcast_body, axis=axis,
                              small_side=small_side,
-                             out_capacity=out_capacity,
-                             kernel_backend=kernel_backend)
+                             out_capacity=out_capacity)
     out, tape = substrate.run(body, big_k, big_r, small_k, small_r)
 
     counts = np.asarray(out.count).reshape(-1)

@@ -59,7 +59,6 @@ def _null_tape():
 
 
 def partition_sorted(x_sorted: jnp.ndarray, interior: jnp.ndarray,
-                     kernel_backend: Optional[str] = None,
                      valid_len: Optional[int] = None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Split a locally sorted vector into t contiguous destination segments.
@@ -80,7 +79,6 @@ def partition_sorted(x_sorted: jnp.ndarray, interior: jnp.ndarray,
     """
     m = valid_len if valid_len is not None else x_sorted.shape[0]
     cuts = ops.searchsorted(x_sorted, interior, side="left",
-                            backend=kernel_backend,
                             valid_len=(None if valid_len is None
                                        else m))                # (t-1,)
     starts = jnp.concatenate([jnp.zeros((1,), cuts.dtype), cuts])
@@ -189,7 +187,6 @@ class RoutedRows(NamedTuple):
 
 def exchange_routed_rows(owner: jnp.ndarray, payload: jnp.ndarray, *,
                          axis_name, t: int, cap_pair: int,
-                         kernel_backend: Optional[str] = None,
                          tape=None) -> RoutedRows:
     """Deliver payload row i to machine ``owner[i]`` through the flat
     static exchange — the slot-major transpose as a first-class routed
@@ -210,11 +207,10 @@ def exchange_routed_rows(owner: jnp.ndarray, payload: jnp.ndarray, *,
     n = owner.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     owner_f = owner.astype(jnp.float32)
-    owner_sorted, perm = ops.sort_kv(owner_f, iota, backend=kernel_backend)
+    owner_sorted, perm = ops.sort_kv(owner_f, iota)
     pay_sorted = payload[perm]
     interior = jnp.arange(1, t, dtype=jnp.float32)
-    starts, lens = partition_sorted(owner_sorted, interior,
-                                    kernel_backend=kernel_backend)
+    starts, lens = partition_sorted(owner_sorted, interior)
     keys_buf, vals_buf, local_drop = build_send_buffer(
         owner_sorted, starts, lens, cap_pair, pay_sorted)
     me = lax.axis_index(axis_name)
@@ -285,7 +281,7 @@ def staged_receive_capacities(m: int, t1: int, t2: int, cap_factor: float,
 
 def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
                      t1: int, t2: int, m: int, cap_factor: float,
-                     values, kernel_backend, valid_len, overlap_chunks: int,
+                     values, valid_len, overlap_chunks: int,
                      tape, phase_prefix: str) -> "ExchangeResult":
     """Two-level compacted exchange (AMS-style): group-hop, merge,
     re-partition, final hop with overlapped chunk merges.
@@ -335,16 +331,14 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
         # interior[g*t2 .. g*t2+t2-2]) — the same side='left' rule the
         # flat partition applies, so routing is identical.
         if rv is not None:
-            merged, merged_v = ops.merge_sorted_rows_kv(
-                rk, rv, backend=kernel_backend)
+            merged, merged_v = ops.merge_sorted_rows_kv(rk, rv)
         else:
-            merged = ops.merge_sorted_rows(rk, backend=kernel_backend)
+            merged = ops.merge_sorted_rows(rk)
             merged_v = None
         count1 = jnp.sum(merged < jnp.asarray(PAD, merged.dtype)
                          ).astype(jnp.int32)
         local_interior = lax.dynamic_slice(interior, (me1 * t2,), (t2 - 1,))
         s2_starts, s2_lens = partition_sorted(merged, local_interior,
-                                              kernel_backend=kernel_backend,
                                               valid_len=count1)
         kbuf2, vbuf2, drop2 = build_send_buffer(merged, s2_starts, s2_lens,
                                                 c2, merged_v,
@@ -354,8 +348,8 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
 
     def chunk_fn(rk, rv):
         if rv is not None:
-            return ops.merge_sorted_rows_kv(rk, rv, backend=kernel_backend)
-        return ops.merge_sorted_rows(rk, backend=kernel_backend), None
+            return ops.merge_sorted_rows_kv(rk, rv)
+        return ops.merge_sorted_rows(rk), None
 
     outs, sent2 = tape.staged_all_to_all(
         kbuf1, (a1, a2), values_buf=vbuf1, sent=sent1, pad=PAD,
@@ -369,18 +363,16 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
         if values is not None:
             final_k, final_v = ops.merge_sorted_rows_kv(
                 stacked, jax.tree.map(lambda *cv: jnp.stack(cv),
-                                      *[cv for _, cv in outs]),
-                backend=kernel_backend)
+                                      *[cv for _, cv in outs]))
         else:
-            final_k = ops.merge_sorted_rows(stacked, backend=kernel_backend)
+            final_k = ops.merge_sorted_rows(stacked)
             final_v = None
     if values is not None:
         # (key, origin) order: stable sort by origin, then by key
         final_v, origin = final_v
         iota = jnp.arange(origin.shape[0], dtype=jnp.int32)
-        _, by_origin = ops.sort_kv(origin, iota, backend=kernel_backend)
-        final_k, perm = ops.sort_kv(final_k[by_origin], by_origin,
-                                    backend=kernel_backend)
+        _, by_origin = ops.sort_kv(origin, iota)
+        final_k, perm = ops.sort_kv(final_k[by_origin], by_origin)
         final_v = final_v[perm]
     count = jnp.sum(final_k < jnp.asarray(PAD, final_k.dtype)
                     ).astype(jnp.int32)
@@ -403,7 +395,6 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
                              values: Optional[jnp.ndarray] = None,
                              backend: str = "static",
                              merge: bool = True,
-                             kernel_backend: Optional[str] = None,
                              sort_input: bool = False,
                              valid_len: Optional[int] = None,
                              tape=None,
@@ -416,13 +407,10 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
     x_sorted: (m,) locally sorted keys.  interior: (t-1,) boundaries.
     Output capacity = ceil(cap_factor * m) rounded up to a multiple of t.
 
-    kernel_backend routes the partition and the receive-side merge
-    through repro.kernels.ops ("pallas" = Pallas kernels, "reference" =
-    jnp, None = ops.DEFAULT_BACKEND).  On the static backend every
-    sender's tile row lands already sorted, so the merge is the fused
-    log-t bitonic merge kernel rather than a full re-sort; the ragged
-    backend's receive buffer has device-dependent run offsets, so it
-    re-sorts (still through ops, which may use the bitonic sort kernel).
+    On the static backend every sender's tile row lands already sorted,
+    so the receive side merges the t rows (``ops.merge_sorted_rows``)
+    rather than re-sorting; the ragged backend's receive buffer has
+    device-dependent run offsets, so it re-sorts.
 
     ``sort_input=True`` takes *unsorted* keys and runs the fused
     ``ops.sort_partition[_kv]`` kernel — sort and boundary search in a
@@ -463,20 +451,17 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
     if sort_input:
         if values is not None:
             x_sorted, values, starts, lens = ops.sort_partition_kv(
-                x_sorted, values, interior, backend=kernel_backend)
+                x_sorted, values, interior)
         else:
-            x_sorted, starts, lens = ops.sort_partition(
-                x_sorted, interior, backend=kernel_backend)
+            x_sorted, starts, lens = ops.sort_partition(x_sorted, interior)
     else:
         starts, lens = partition_sorted(x_sorted, interior,
-                                        kernel_backend=kernel_backend,
                                         valid_len=valid_len)
     if staged_shape is not None:
         return _staged_exchange(
             x_sorted, interior, starts, lens, axis_names=tuple(axis_name),
             t1=t1, t2=t2, m=m, cap_factor=cap_factor, values=values,
-            kernel_backend=kernel_backend, valid_len=valid_len,
-            overlap_chunks=overlap_chunks,
+            valid_len=valid_len, overlap_chunks=overlap_chunks,
             tape=tape if tape is not None else _null_tape(),
             phase_prefix=phase_prefix)
     me = lax.axis_index(axis_name)
@@ -505,13 +490,12 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
     if merge:
         if recv2d is not None:          # static: per-sender rows are sorted
             if recv_v2d is not None:
-                recv, recv_v = ops.merge_sorted_rows_kv(
-                    recv2d, recv_v2d, backend=kernel_backend)
+                recv, recv_v = ops.merge_sorted_rows_kv(recv2d, recv_v2d)
             else:
-                recv = ops.merge_sorted_rows(recv2d, backend=kernel_backend)
+                recv = ops.merge_sorted_rows(recv2d)
         elif recv_v is not None:
-            recv, recv_v = ops.sort_kv(recv, recv_v, backend=kernel_backend)
+            recv, recv_v = ops.sort_kv(recv, recv_v)
         else:
-            recv = ops.sort(recv, backend=kernel_backend)
+            recv = ops.sort(recv)
         # pads (= inf) land at the end in every path
     return ExchangeResult(recv, recv_v, count, sent, dropped)

@@ -8,15 +8,13 @@ searchsorted over the cumulative match counts — three sorts/searches and
 two gathers, no data-dependent shapes anywhere.
 
 All three hot loops route through the kernel-dispatch layer
-(repro.kernels.ops): the T-side sort is the bitonic pair-sort kernel and
-the binary searches are the fused searchsorted kernel when
-``kernel_backend="pallas"``, with bitwise-identical jnp fallbacks.
+(repro.kernels.ops): the T-side sort and the binary searches.
 
 Masked tuples use key == MASKED_KEY (int sentinel) and never match.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax.numpy as jnp
 
@@ -35,21 +33,18 @@ class JoinOutput(NamedTuple):
     dropped: jnp.ndarray  # scalar: results beyond capacity (0 == success)
 
 
-def join_size(s_keys: jnp.ndarray, t_keys: jnp.ndarray,
-              kernel_backend: Optional[str] = None) -> jnp.ndarray:
+def join_size(s_keys: jnp.ndarray, t_keys: jnp.ndarray) -> jnp.ndarray:
     """Exact |S >< T| for the local fragments (for capacity planning)."""
-    tk = ops.sort(jnp.where(t_keys == MASKED_KEY, MASKED_KEY, t_keys),
-                  backend=kernel_backend)
-    lo = ops.searchsorted(tk, s_keys, side="left", backend=kernel_backend)
-    hi = ops.searchsorted(tk, s_keys, side="right", backend=kernel_backend)
+    tk = ops.sort(jnp.where(t_keys == MASKED_KEY, MASKED_KEY, t_keys))
+    lo = ops.searchsorted(tk, s_keys, side="left")
+    hi = ops.searchsorted(tk, s_keys, side="right")
     cnt = jnp.where(s_keys == MASKED_KEY, 0, hi - lo)
     return jnp.sum(cnt)
 
 
 def local_equijoin(s_keys: jnp.ndarray, s_rows: jnp.ndarray,
                    t_keys: jnp.ndarray, t_rows: jnp.ndarray,
-                   capacity: int,
-                   kernel_backend: Optional[str] = None) -> JoinOutput:
+                   capacity: int) -> JoinOutput:
     """Cross-product of equal keys, statically shaped.
 
     s_keys/t_keys: (ns,)/(nt,) int32 join keys (MASKED_KEY = absent).
@@ -59,11 +54,10 @@ def local_equijoin(s_keys: jnp.ndarray, s_rows: jnp.ndarray,
 
     # Sort T by key; masked tuples (sentinel = int max) sort to the end and
     # are excluded because searchsorted for any real key stops before them.
-    tk, tv = ops.sort_kv(t_keys, t_rows, backend=kernel_backend)
+    tk, tv = ops.sort_kv(t_keys, t_rows)
 
-    lo = ops.searchsorted(tk, s_keys, side="left",
-                          backend=kernel_backend)     # (ns,)
-    hi = ops.searchsorted(tk, s_keys, side="right", backend=kernel_backend)
+    lo = ops.searchsorted(tk, s_keys, side="left")     # (ns,)
+    hi = ops.searchsorted(tk, s_keys, side="right")
     cnt = jnp.where(s_keys == MASKED_KEY, 0, hi - lo)  # matches per S tuple
 
     cum = jnp.cumsum(cnt)                              # inclusive
@@ -72,8 +66,7 @@ def local_equijoin(s_keys: jnp.ndarray, s_rows: jnp.ndarray,
 
     out_j = jnp.arange(capacity)
     # slot j belongs to the S tuple whose [excl, cum) window contains j
-    src_s = ops.searchsorted(cum, out_j, side="right",
-                             backend=kernel_backend)
+    src_s = ops.searchsorted(cum, out_j, side="right")
     src_s = jnp.clip(src_s, 0, ns - 1)
     within = out_j - excl[src_s]
     t_idx = jnp.clip(lo[src_s] + within, 0, tk.shape[0] - 1)

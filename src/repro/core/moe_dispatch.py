@@ -66,7 +66,6 @@ def moe_dispatch_shard(x_local: jnp.ndarray, router: jnp.ndarray,
                        axis_name, t: int, num_experts: int, top_k: int,
                        extra_slots: int, capacity_slot: int, cap_pair: int,
                        act: str = "swiglu",
-                       kernel_backend: Optional[str] = None,
                        tape: Optional[CollectiveTape] = None
                        ) -> MoeDispatchResult:
     """Per-machine cluster MoE dispatch body.  x_local: (m, d) tokens.
@@ -125,9 +124,7 @@ def moe_dispatch_shard(x_local: jnp.ndarray, router: jnp.ndarray,
              pos.astype(jnp.float32)[:, None],
              jnp.repeat(x_local.astype(jnp.float32), k, axis=0)], axis=1)
         routed = exchange_routed_rows(owner, payload, axis_name=axis_name,
-                                      t=t, cap_pair=cap_pair,
-                                      kernel_backend=kernel_backend,
-                                      tape=tape)
+                                      t=t, cap_pair=cap_pair, tape=tape)
         valid = routed.recv_keys < jnp.asarray(PAD, routed.recv_keys.dtype)
         slot_r = routed.recv_payload[..., 0].astype(jnp.int32)
         pos_r = routed.recv_payload[..., 1].astype(jnp.int32)
@@ -181,8 +178,7 @@ def moe_dispatch_shard(x_local: jnp.ndarray, router: jnp.ndarray,
 def cluster_moe_dispatch(params, x: jnp.ndarray, cfg, *, t_machines: int,
                          counts=None, substrate: Optional[Substrate] = None,
                          policy: Optional[CapacityPolicy] = None,
-                         act: str = "swiglu",
-                         kernel_backend: Optional[str] = None):
+                         act: str = "swiglu"):
     """Run one MoE layer with cluster-routed dispatch.
 
     x: (..., d) tokens; the flattened token count must divide evenly
@@ -240,7 +236,7 @@ def cluster_moe_dispatch(params, x: jnp.ndarray, cfg, *, t_machines: int,
         static = dict(axis_name=substrate.axis_name, t=t, num_experts=e,
                       top_k=k, extra_slots=int(cfg.extra_slots),
                       capacity_slot=capacity_slot, cap_pair=cap_pair,
-                      act=act, kernel_backend=kernel_backend)
+                      act=act)
         res, tape = substrate.run(
             functools.partial(moe_dispatch_shard, **static), *args)
         return ((res, tape, capacity_slot),

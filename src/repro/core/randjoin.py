@@ -54,8 +54,7 @@ def choose_ab(t: int, size_s: int, size_t: int) -> Tuple[int, int]:
 
 def route_to_interval(keys: jnp.ndarray, rows: jnp.ndarray,
                       assign: jnp.ndarray, n_dst: int, axis_name: str,
-                      cap_pair: int, tape: Optional[CollectiveTape] = None,
-                      kernel_backend: Optional[str] = None):
+                      cap_pair: int, tape: Optional[CollectiveTape] = None):
     """all_to_all tuples to their assigned interval along ``axis_name``.
 
     Returns (join_keys, payload_rows, dropped, valid_count); masked slots
@@ -70,7 +69,7 @@ def route_to_interval(keys: jnp.ndarray, rows: jnp.ndarray,
     pairs = jnp.stack([keys, rows], axis=-1)                   # (m, 2) int32
     interior = jnp.arange(1, n_dst, dtype=assign.dtype)
     assign_sorted, payload, starts, lens = ops.sort_partition_kv(
-        assign, pairs, interior, backend=kernel_backend)
+        assign, pairs, interior)
     a_sorted = assign_sorted.astype(jnp.float32)
     kbuf, vbuf, dropped = build_send_buffer(a_sorted, starts, lens, cap_pair,
                                             values=payload)
@@ -88,7 +87,6 @@ def route_to_interval(keys: jnp.ndarray, rows: jnp.ndarray,
 def randjoin_shard(s_keys, s_rows, t_keys, t_rows, rng, *,
                    axis_a: str, axis_b: str, a: int, b: int,
                    out_capacity: int, in_cap_factor: float = 2.0,
-                   kernel_backend: Optional[str] = None,
                    tape: Optional[CollectiveTape] = None) -> JoinOutput:
     """Per-device RandJoin body.  Local fragments: (ms,), (mt,) int32."""
     ms, mt = s_keys.shape[0], t_keys.shape[0]
@@ -104,22 +102,19 @@ def randjoin_shard(s_keys, s_rows, t_keys, t_rows, rng, *,
         # ---- route S to its row (all_to_all over 'a'), replicate over 'b' --
         cap_s = max(1, math.ceil(in_cap_factor * ms / a))
         sk, sr, sdrop, s_count = route_to_interval(
-            s_keys, s_rows, i_assign, a, axis_a, cap_s, tape=tape,
-            kernel_backend=kernel_backend)
+            s_keys, s_rows, i_assign, a, axis_a, cap_s, tape=tape)
         sk = tape.all_gather(sk, axis_b, count=s_count).reshape(-1)
         sr = tape.all_gather(sr, axis_b, track=False).reshape(-1)
 
         # ---- route T to its column (all_to_all over 'b'), replicate over 'a'
         cap_t = max(1, math.ceil(in_cap_factor * mt / b))
         tk, tr, tdrop, t_count = route_to_interval(
-            t_keys, t_rows, j_assign, b, axis_b, cap_t, tape=tape,
-            kernel_backend=kernel_backend)
+            t_keys, t_rows, j_assign, b, axis_b, cap_t, tape=tape)
         tk = tape.all_gather(tk, axis_a, count=t_count).reshape(-1)
         tr = tape.all_gather(tr, axis_a, track=False).reshape(-1)
 
         # ---- reduce phase: local cross product (same round — no barrier) ---
-        out = local_equijoin(sk, sr, tk, tr, out_capacity,
-                             kernel_backend=kernel_backend)
+        out = local_equijoin(sk, sr, tk, tr, out_capacity)
         dropped = out.dropped + tape.psum(sdrop + tdrop,
                                           axis_a if a > 1 else axis_b)
     return out._replace(dropped=dropped.astype(jnp.int32))
@@ -130,7 +125,6 @@ def randjoin(s_keys: np.ndarray, s_rows: np.ndarray,
              t_machines: int, out_capacity: int,
              seed: int = 0, in_cap_factor: float = 2.0,
              ab: Optional[Tuple[int, int]] = None,
-             kernel_backend: Optional[str] = None,
              substrate: Optional[Substrate] = None):
     """Host wrapper: the a x b machine matrix on a 2-axis substrate.
 
@@ -162,8 +156,7 @@ def randjoin(s_keys: np.ndarray, s_rows: np.ndarray,
     # same parameters reuse one compiled program.
     body = functools.partial(randjoin_shard, axis_a=axis_a, axis_b=axis_b,
                              a=a, b=b, out_capacity=int(out_capacity),
-                             in_cap_factor=float(in_cap_factor),
-                             kernel_backend=kernel_backend)
+                             in_cap_factor=float(in_cap_factor))
     out, tape = substrate.run(body, sk, sr, tk, tr, rngs)
 
     counts = np.asarray(out.count).reshape(-1)

@@ -23,19 +23,17 @@ from .localjoin import MASKED_KEY, local_equijoin
 __all__ = ["repartition_join"]
 
 
-def _repartition_body(a, b, c, d, *, tape, out_capacity, kernel_backend):
+def _repartition_body(a, b, c, d, *, tape, out_capacity):
     """Per-device body (module-level for stable compiled-program keys)."""
     with tape.phase("shuffle"):
         received = jnp.sum(a != MASKED_KEY) + jnp.sum(c != MASKED_KEY)
         tape.record(sent=received, received=received)
-        return local_equijoin(a, b, c, d, out_capacity,
-                              kernel_backend=kernel_backend)
+        return local_equijoin(a, b, c, d, out_capacity)
 
 
 def repartition_join(s_keys: np.ndarray, s_rows: np.ndarray,
                      t_keys: np.ndarray, t_rows: np.ndarray,
                      t_machines: int, out_capacity: int,
-                     kernel_backend: Optional[str] = None,
                      substrate: Optional[Substrate] = None,
                      donate: Optional[bool] = None):
     """Hash-partition both tables by key; join per machine.
@@ -67,8 +65,7 @@ def repartition_join(s_keys: np.ndarray, s_rows: np.ndarray,
     sk, sr, ns = shard(s_keys, np.asarray(s_rows))
     tk, tr, nt = shard(t_keys, np.asarray(t_rows))
 
-    body = functools.partial(_repartition_body, out_capacity=out_capacity,
-                             kernel_backend=kernel_backend)
+    body = functools.partial(_repartition_body, out_capacity=out_capacity)
     donate_argnums = (0, 1, 2, 3) if donate is not False else ()
     out, tape = substrate.run(body, sk, sr, tk, tr,
                               donate_argnums=donate_argnums)

@@ -114,17 +114,13 @@ def smms_shard(x_local: jnp.ndarray, *, axis_name, t: int, r: int = 2,
                values: Optional[jnp.ndarray] = None,
                backend: str = "static",
                local_sort=None,
-               kernel_backend: Optional[str] = None,
                staged_shape: Optional[tuple] = None,
                overlap_chunks: int = 2,
                tape: Optional[CollectiveTape] = None) -> SortResult:
     """Per-device SMMS body.  x_local: (m,) this machine's objects.
 
-    kernel_backend picks the implementation of every sort/partition/merge
-    hot loop ("pallas" = the Pallas kernels via repro.kernels.ops,
-    "reference" = jnp, None = ops.DEFAULT_BACKEND); results are bitwise
-    identical either way.  An explicit ``local_sort`` callable overrides
-    the Round-1 keys-only sort (test hook).
+    An explicit ``local_sort`` callable overrides the Round-1 keys-only
+    sort (test hook).
 
     ``staged_shape=(t1, t2)`` runs Round 3 as the two-level staged
     exchange: ``axis_name`` must then be the (sub-axis-1, sub-axis-2)
@@ -149,13 +145,12 @@ def smms_shard(x_local: jnp.ndarray, *, axis_name, t: int, r: int = 2,
         if values is not None:
             xs, values = ops.sort_kv(ops.pad_pow2(x_local),
                                      ops.pad_pow2(values, fill=0),
-                                     backend=kernel_backend, prepadded=True)
+                                     prepadded=True)
         elif local_sort is not None:
             xs = local_sort(x_local)
             valid_len = None           # test hook: unpadded contract
         else:
-            xs = ops.sort(ops.pad_pow2(x_local), backend=kernel_backend,
-                          prepadded=True)
+            xs = ops.sort(ops.pad_pow2(x_local), prepadded=True)
         lam = equidepth_samples(xs[:m], s)                # (s+1,)
         if staged_shape is not None:
             lam_all = tape.all_gather_multi(lam, axis_name)   # (t1, t2, s+1)
@@ -175,7 +170,7 @@ def smms_shard(x_local: jnp.ndarray, *, axis_name, t: int, r: int = 2,
         ex: ExchangeResult = exchange_sorted_segments(
             xs, b[1:-1], axis_name=axis_name, t=t, cap_factor=cap_factor,
             values=values, backend=backend, merge=True,
-            kernel_backend=kernel_backend, valid_len=valid_len, tape=tape,
+            valid_len=valid_len, tape=tape,
             staged_shape=staged_shape, overlap_chunks=overlap_chunks,
             phase_prefix="round3 shuffle")
     else:
@@ -183,8 +178,7 @@ def smms_shard(x_local: jnp.ndarray, *, axis_name, t: int, r: int = 2,
             ex = exchange_sorted_segments(
                 xs, b[1:-1], axis_name=axis_name, t=t,
                 cap_factor=cap_factor, values=values, backend=backend,
-                merge=True, kernel_backend=kernel_backend,
-                valid_len=valid_len, tape=tape)
+                merge=True, valid_len=valid_len, tape=tape)
     return SortResult(ex.keys, ex.values, ex.count, ex.sent, ex.dropped, b)
 
 
@@ -203,7 +197,6 @@ def smms_sort(x: jnp.ndarray, r: int = 2,
               cap_factor: Optional[float] = None,
               values: Optional[jnp.ndarray] = None,
               backend: str = "static",
-              kernel_backend: Optional[str] = None,
               substrate: Optional[Substrate] = None,
               policy: Optional[CapacityPolicy] = None,
               exchange: str = "flat",
@@ -252,8 +245,7 @@ def smms_sort(x: jnp.ndarray, r: int = 2,
 
     def attempt(factor):
         static = dict(axis_name=axis_arg, t=t, r=r,
-                      cap_factor=float(factor), backend=backend,
-                      kernel_backend=kernel_backend)
+                      cap_factor=float(factor), backend=backend)
         if staged_shape is not None:
             static.update(staged_shape=staged_shape,
                           overlap_chunks=int(overlap_chunks))

@@ -253,8 +253,7 @@ def _routing_tensors(keys: np.ndarray, plan: StatJoinPlan, t: int,
     return out, cap
 
 
-def _statjoin_body(a, b, c, d, *, tape, n_in, n_stat, t, capacity,
-                   kernel_backend):
+def _statjoin_body(a, b, c, d, *, tape, n_in, n_stat, t, capacity):
     """Per-device StatJoin body (module-level: a functools.partial of this
     keys the substrate's compiled-program cache on content)."""
     # Rounds 1-2: the SMMS sort that produced the statistics — each
@@ -271,15 +270,13 @@ def _statjoin_body(a, b, c, d, *, tape, n_in, n_stat, t, capacity,
     with tape.phase("round3 route"):
         received = (jnp.sum(a != MASKED_KEY) + jnp.sum(c != MASKED_KEY))
         tape.record(sent=n_in / t, received=received)
-        return local_equijoin(a, b, c, d, capacity,
-                              kernel_backend=kernel_backend)
+        return local_equijoin(a, b, c, d, capacity)
 
 
 def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
              t_keys: np.ndarray, t_rows: np.ndarray,
              t_machines: int, out_cap_factor: float = 1.05,
              stats: Optional[JoinStatistics] = None,
-             kernel_backend: Optional[str] = None,
              substrate: Optional[Substrate] = None,
              out_capacity: Optional[int] = None,
              donate: Optional[bool] = None):
@@ -327,8 +324,7 @@ def statjoin(s_keys: np.ndarray, s_rows: np.ndarray,
     n_stat = len(stats.keys)
 
     body = functools.partial(_statjoin_body, n_in=n_in, n_stat=n_stat, t=t,
-                             capacity=capacity,
-                             kernel_backend=kernel_backend)
+                             capacity=capacity)
     donate_argnums = (0, 1, 2, 3) if donate is not False else ()
     out, tape = substrate.run(body, sk, sr, tk, tr,
                               donate_argnums=donate_argnums)

@@ -45,7 +45,6 @@ def terasort_shard(x_local: jnp.ndarray, rng: jax.Array, *, axis_name,
                    t: int, q: int, cap_factor: float = 5.5,
                    values: Optional[jnp.ndarray] = None,
                    backend: str = "static",
-                   kernel_backend: Optional[str] = None,
                    staged_shape: Optional[tuple] = None,
                    overlap_chunks: int = 2,
                    tape: Optional[CollectiveTape] = None) -> SortResult:
@@ -91,7 +90,6 @@ def terasort_shard(x_local: jnp.ndarray, rng: jax.Array, *, axis_name,
                                       t=t, cap_factor=cap_factor,
                                       values=values, backend=backend,
                                       merge=True, sort_input=True,
-                                      kernel_backend=kernel_backend,
                                       tape=tape, staged_shape=staged_shape,
                                       overlap_chunks=overlap_chunks,
                                       phase_prefix="round3 shuffle")
@@ -100,8 +98,7 @@ def terasort_shard(x_local: jnp.ndarray, rng: jax.Array, *, axis_name,
             ex = exchange_sorted_segments(
                 x_local, interior, axis_name=axis_name, t=t,
                 cap_factor=cap_factor, values=values, backend=backend,
-                merge=True, sort_input=True,
-                kernel_backend=kernel_backend, tape=tape)
+                merge=True, sort_input=True, tape=tape)
     b = jnp.concatenate([all_samples[:1], interior, all_samples[-1:]])
     return SortResult(ex.keys, ex.values, ex.count, ex.sent, ex.dropped, b)
 
@@ -116,7 +113,6 @@ def _terasort_shard_kv(x_local, rng, values, **kw):
 def terasort_sort(x: jnp.ndarray, seed: int = 0,
                   cap_factor: Optional[float] = None,
                   backend: str = "static",
-                  kernel_backend: Optional[str] = None,
                   substrate: Optional[Substrate] = None,
                   policy: Optional[CapacityPolicy] = None,
                   values: Optional[jnp.ndarray] = None,
@@ -162,8 +158,7 @@ def terasort_sort(x: jnp.ndarray, seed: int = 0,
 
     def attempt(factor):
         static = dict(axis_name=axis_arg, t=t, q=q,
-                      cap_factor=float(factor), backend=backend,
-                      kernel_backend=kernel_backend)
+                      cap_factor=float(factor), backend=backend)
         if staged_shape is not None:
             static.update(staged_shape=staged_shape,
                           overlap_chunks=int(overlap_chunks))

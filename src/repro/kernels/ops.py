@@ -2,8 +2,9 @@
 
 This module is the single entry the cluster hot loops call (`ops.sort`,
 `ops.sort_kv`, `ops.searchsorted`, `ops.bucketize_histogram`,
-`ops.sort_partition[_kv]`, `ops.merge_sorted_rows[_kv]`).  Each call
-picks one of two backends:
+`ops.sort_partition[_kv]`, `ops.merge_sorted_rows[_kv]`), and the only
+module that knows there is a path to choose: callers pass no
+``backend=``, and each call picks one of two backends itself:
 
 * ``"reference"`` — the plain jnp / lax implementation (``jnp.sort``,
   ``jnp.searchsorted``, and payload sorts as one stable multi-operand
@@ -46,11 +47,14 @@ values) while jnp.sort moves NaNs last.  The radix path's contract is
 strictly wider: NaNs canonicalize to the all-ones key bits, so they
 sort last in input order — full jnp.sort parity, NaNs included.
 
-``backend=None`` resolves to the module default: ``DEFAULT_BACKEND``
-(seeded from the ``REPRO_KERNEL_BACKEND`` env var) when set, otherwise
-the platform's own — ``"pallas"`` on TPU, ``"reference"`` elsewhere —
-so a whole test run can be flipped to the kernel path without touching
-call sites.
+``backend=None`` resolves to the platform's own path — ``"pallas"`` on
+TPU, ``"reference"`` elsewhere — unless ``DEFAULT_BACKEND`` (seeded
+from the ``REPRO_KERNEL_BACKEND`` env var) pins one.  That pin and the
+per-call ``backend=`` are test and CI seams, not user options: the CI
+matrix flips a whole run with the env var, the pipeline tests pin a
+test through ``DEFAULT_BACKEND``, and the kernel-layer differential
+suites pin a call.  A compiled program depends on the path it traced,
+so caches of compiled programs key on :func:`program_key`.
 
 Dispatch accounting — TWO counters with different semantics:
 
@@ -135,7 +139,8 @@ __all__ = [
     "sort", "sort_kv", "searchsorted", "bucketize_histogram",
     "sort_partition", "sort_partition_kv", "pad_pow2",
     "merge_sorted_rows", "merge_sorted_rows_kv", "flash_attention",
-    "resolve_backend", "reset_dispatch_counts", "kernel_eligible",
+    "resolve_backend", "program_key", "reset_dispatch_counts",
+    "kernel_eligible",
     "sort_kernel_choice", "force_sort_kernel",
     "key_to_bits", "bits_to_key",
     "platform", "interpret_mode", "platform_backend",
@@ -173,6 +178,14 @@ def resolve_backend(backend) -> str:
         raise ValueError(f"unknown kernel backend {b!r}; "
                          f"expected one of {BACKENDS}")
     return b
+
+
+def program_key() -> str:
+    """What a program traced through these ops depends on besides its
+    operands: the resolved kernel path.  A cache of compiled programs
+    carries it in its key, so a program traced on one path is never
+    served while the other is pinned."""
+    return resolve_backend(None)
 
 
 def reset_dispatch_counts() -> None:

@@ -136,7 +136,6 @@ def _sketch_substrate(t: int) -> VmapSubstrate:
 
 
 def plan_sort_query(x, *, t: int, r: int = 2,
-                    kernel_backend: Optional[str] = None,
                     substrate: Optional[Substrate] = None):
     """Sketch -> score -> choose for ``cluster.sort(algorithm="auto")``.
 
@@ -153,8 +152,7 @@ def plan_sort_query(x, *, t: int, r: int = 2,
             else _sketch_substrate(t)
         _tick("sketch_runs")
         with obs_trace.span("planner.sketch"):
-            profile, tape = profile_sorted_shards(
-                x, sub, kernel_backend=kernel_backend)
+            profile, tape = profile_sorted_shards(x, sub)
         with obs_trace.span("planner.score"):
             costs = sort_costs(profile, t, r=r)
             chosen = select(costs)
@@ -171,7 +169,6 @@ def plan_sort_query(x, *, t: int, r: int = 2,
 
 def plan_join_query(s_keys, t_keys, *, t_machines: int,
                     mem_budget: Optional[int] = None,
-                    kernel_backend: Optional[str] = None,
                     substrate: Optional[Substrate] = None):
     """Sketch -> score -> choose for ``cluster.join(algorithm="auto")``.
 
@@ -194,8 +191,7 @@ def plan_join_query(s_keys, t_keys, *, t_machines: int,
         t32 = np.asarray(t_keys, np.int32)
         with obs_trace.span("planner.sketch"):
             profile, tape = profile_join_tables(
-                s32, t32, t, sub, masked=int(MASKED_KEY),
-                kernel_backend=kernel_backend)
+                s32, t32, t, sub, masked=int(MASKED_KEY))
         with obs_trace.span("planner.score"):
             costs = join_costs(profile, t, mem_budget=mem_budget)
             chosen = select(costs)
@@ -209,7 +205,6 @@ def plan_join_query(s_keys, t_keys, *, t_machines: int,
 def plan_moe_query(x, router, *, t_machines: int, num_experts: int,
                    top_k: int, extra_slots: int,
                    capacity_factor: float = 1.25,
-                   kernel_backend: Optional[str] = None,
                    substrate: Optional[Substrate] = None):
     """Sketch -> score -> choose for ``cluster.moe_dispatch(mode="auto")``.
 
@@ -247,9 +242,7 @@ def plan_moe_query(x, router, *, t_machines: int, num_experts: int,
                         jnp.einsum("md,de->me", xl.astype(jnp.float32),
                                    jnp.asarray(router)), top_k)[1])(xr)
                 ids = ids.reshape(t, -1).astype(jnp.int32)
-                profile, tape = sketch_table(ids, sub,
-                                             kernel_backend=kernel_backend,
-                                             sample=None)
+                profile, tape = sketch_table(ids, sub, sample=None)
             with obs_trace.span("planner.score"):
                 tokens = ids.shape[0] * ids.shape[1] // top_k
                 counts = expert_counts_estimate(profile, num_experts)

@@ -7,9 +7,8 @@ shard_map mesh):
 * **Heavy hitters** — per-shard top-``HH_K`` keys with counts.  On
   kernel-eligible shards (1-2D float32/bfloat16/int32 rows that fit
   VMEM, per ``ops.kernel_eligible``) this is the *sorted-runs* pass:
-  one ``ops.sort`` plus two ``ops.searchsorted`` sweeps (the Pallas
-  bitonic/branch-free-search kernels when ``kernel_backend="pallas"``)
-  yield exact run lengths, and ``top_k`` keeps the heaviest.  Ineligible
+  one ``ops.sort`` plus two ``ops.searchsorted`` sweeps yield exact
+  run lengths, and ``top_k`` keeps the heaviest.  Ineligible
   shards fall back to a streaming :func:`misra_gries` ``lax.scan`` with
   O(HH_K) state.  Either way the per-shard summaries merge by summing
   counts per key — the standard Misra-Gries merge, a lower bound on the
@@ -146,7 +145,6 @@ def _pad_to(x: jnp.ndarray, k: int, value=0) -> jnp.ndarray:
 def shard_sketch(keys: jnp.ndarray, *, hh_k: int = HH_K,
                  cm_depth: int = CM_DEPTH, cm_width: int = CM_WIDTH,
                  kmv_k: int = KMV_K, masked=None,
-                 kernel_backend: Optional[str] = None,
                  sample: Optional[int] = None) -> ShardSketch:
     """One pass over a shard: heavy hitters + CountMin + KMV minima.
 
@@ -176,9 +174,9 @@ def shard_sketch(keys: jnp.ndarray, *, hh_k: int = HH_K,
     # (one ops.sort + two ops.searchsorted sweeps, exact counts); the
     # sorted order is reused to dedupe the KMV hashes for free.
     if ops.kernel_eligible("sort", keys):
-        xs = ops.sort(keys, backend=kernel_backend)
-        lo = ops.searchsorted(xs, xs, side="left", backend=kernel_backend)
-        hi = ops.searchsorted(xs, xs, side="right", backend=kernel_backend)
+        xs = ops.sort(keys)
+        lo = ops.searchsorted(xs, xs, side="left")
+        hi = ops.searchsorted(xs, xs, side="right")
         first = lo == jnp.arange(n, dtype=lo.dtype)
         if masked is not None:
             first = first & (xs != jnp.asarray(masked, xs.dtype))
@@ -194,7 +192,7 @@ def shard_sketch(keys: jnp.ndarray, *, hh_k: int = HH_K,
         hc, idx = lax.top_k(sc, hh_k)
         hk = sk[idx]
         hv = jnp.where(valid, _kmv_hash(ku), _I32_MAX)
-        hs = ops.sort(hv, backend=kernel_backend)
+        hs = ops.sort(hv)
         prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), hs[:-1]])
         dedup = jnp.where(hs == prev, _I32_MAX, hs)
         mins = -lax.top_k(-dedup, kk)[0]
@@ -363,15 +361,14 @@ SKETCH_PHASE = "round0 sketch"
 
 
 @functools.lru_cache(maxsize=None)
-def _single_body(t_total: int, masked, kernel_backend, sample):
+def _single_body(t_total: int, masked, sample):
     """Stable per-parameter body function — jitting substrates cache by
     function identity, so the closure must be created once, not per call."""
     size = sketch_size()
 
     def body(xl, tape):
         with tape.phase(SKETCH_PHASE):
-            sk = shard_sketch(xl, masked=masked,
-                              kernel_backend=kernel_backend, sample=sample)
+            sk = shard_sketch(xl, masked=masked, sample=sample)
             tape.record(sent=size, received=size * t_total)
         return sk
 
@@ -379,15 +376,13 @@ def _single_body(t_total: int, masked, kernel_backend, sample):
 
 
 @functools.lru_cache(maxsize=None)
-def _pair_body(t_total: int, masked, kernel_backend, sample):
+def _pair_body(t_total: int, masked, sample):
     size = 2 * sketch_size()
 
     def body(sl, tl, tape):
         with tape.phase(SKETCH_PHASE):
-            a = shard_sketch(sl, masked=masked, kernel_backend=kernel_backend,
-                             sample=sample)
-            b = shard_sketch(tl, masked=masked, kernel_backend=kernel_backend,
-                             sample=sample)
+            a = shard_sketch(sl, masked=masked, sample=sample)
+            b = shard_sketch(tl, masked=masked, sample=sample)
             tape.record(sent=size, received=size * t_total)
         return a, b
 
@@ -395,24 +390,21 @@ def _pair_body(t_total: int, masked, kernel_backend, sample):
 
 
 def sketch_table(x_shards: jnp.ndarray, substrate, *, masked=None,
-                 kernel_backend: Optional[str] = None,
                  sample: Optional[int] = SKETCH_SAMPLE):
     """Sketch a (t, m) sharded table on the substrate.
 
     Returns ``(TableProfile, tape)`` — the tape carries the sketch
     phase (each machine ships its fixed-size sketch, receives all t).
     ``sample=None`` disables the per-shard subsampling cap."""
-    body = _single_body(substrate.t, masked, kernel_backend, sample)
+    body = _single_body(substrate.t, masked, sample)
     sk, tape = substrate.run(body, x_shards)
     return merge_shard_sketches(sk), tape
 
 
 def profile_sorted_shards(x: jnp.ndarray, substrate, *,
-                          kernel_backend: Optional[str] = None,
                           sample: Optional[int] = SKETCH_SAMPLE):
     """Profile a dense (t, m) sort input.  Returns (TableProfile, tape)."""
-    return sketch_table(jnp.asarray(x), substrate,
-                        kernel_backend=kernel_backend, sample=sample)
+    return sketch_table(jnp.asarray(x), substrate, sample=sample)
 
 
 def expert_counts_estimate(profile: TableProfile,
@@ -450,14 +442,13 @@ def _deal(keys: np.ndarray, t: int, masked) -> jnp.ndarray:
 
 def profile_join_tables(s_keys: np.ndarray, t_keys: np.ndarray,
                         t_machines: int, substrate, *, masked,
-                        kernel_backend: Optional[str] = None,
                         sample: Optional[int] = SKETCH_SAMPLE):
     """Profile both join tables in ONE substrate program (one sketch round).
 
     Returns ``(DataProfile, tape)``."""
     ss = _deal(s_keys, t_machines, masked)
     ts = _deal(t_keys, t_machines, masked)
-    body = _pair_body(substrate.t, masked, kernel_backend, sample)
+    body = _pair_body(substrate.t, masked, sample)
     (sk_s, sk_t), tape = substrate.run(body, ss, ts)
     profile = build_data_profile(merge_shard_sketches(sk_s),
                                  merge_shard_sketches(sk_t))

@@ -252,8 +252,7 @@ def join_query(s_keys, s_rows, t_keys, t_rows, *, t_machines: int,
                  priority, deadline_s)
 
 
-def run_spec(spec: QuerySpec, *, substrate=None,
-             kernel_backend: Optional[str] = None):
+def run_spec(spec: QuerySpec, *, substrate=None):
     """Execute one spec through the cluster front door.
 
     The single spec-unpacking path: the engine calls it with its shared
@@ -262,8 +261,6 @@ def run_spec(spec: QuerySpec, *, substrate=None,
     """
     from repro import cluster
     kw = spec.kwargs
-    if kw.get("kernel_backend") is None and kernel_backend is not None:
-        kw["kernel_backend"] = kernel_backend
     if spec.kind == "sort":
         kw.pop("has_values", None)
         values = spec.arrays[1] if len(spec.arrays) > 1 else None
@@ -650,8 +647,6 @@ class QueryEngine:
         provider); defaults to a fresh pool of jit-compiling vmap
         substrates.  Passing one engine's pool to another shares the
         compiled programs too.
-    kernel_backend : default kernel dispatch for specs that don't pin
-        one ("pallas" / "reference" / None = ops.DEFAULT_BACKEND).
     tracer      : a :class:`repro.obs.Tracer` for per-request span
         trees; defaults to the process-global tracer (disabled unless
         ``repro.obs.enable()`` was called), so tracing costs nothing
@@ -669,7 +664,6 @@ class QueryEngine:
     def __init__(self, *, max_pending: int = 256, max_batch: int = 8,
                  batch_window_s: float = 0.002, workers: int = 1,
                  pool: Optional[SubstratePool] = None,
-                 kernel_backend: Optional[str] = None,
                  result_cache_size: int = 64,
                  result_cache: Optional[ResultCache] = None,
                  tracer: Optional[obs_trace.Tracer] = None,
@@ -678,7 +672,6 @@ class QueryEngine:
             raise ValueError("max_pending, max_batch and workers must be >= 1")
         self.max_batch = int(max_batch)
         self.batch_window_s = float(batch_window_s)
-        self.kernel_backend = kernel_backend
         self.pool = pool if pool is not None else SubstratePool()
         self._admit = _PriorityAdmission(int(max_pending))
         self._batcher = ContinuousBatcher(
@@ -1031,9 +1024,7 @@ class QueryEngine:
             with self.tracer.trace("query", kind=spec.kind,
                                    query_id=it.query_id, batch=batch_id,
                                    tag=spec.tag) as root:
-                value, report = run_spec(
-                    spec, substrate=self.pool,
-                    kernel_backend=self.kernel_backend)
+                value, report = run_spec(spec, substrate=self.pool)
             ok, error = True, None
         except Exception as exc:       # isolate failures per query
             value, report, ok, error = None, None, False, repr(exc)

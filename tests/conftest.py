@@ -81,6 +81,17 @@ def _pin_global_rngs():
 
 
 @pytest.fixture
+def kernel_path(monkeypatch):
+    """Pin the kernel path for this test: ``kernel_path("pallas")`` or
+    ``kernel_path("reference")`` sets ``ops.DEFAULT_BACKEND``, the one
+    process-wide pin ``ops`` honours.  Call it again to flip the path
+    mid-test; the default comes back when the test ends."""
+    def pin(path):
+        monkeypatch.setattr(ops, "DEFAULT_BACKEND", path)
+    return pin
+
+
+@pytest.fixture
 def rng(request) -> np.random.Generator:
     """Per-test deterministic generator — the one seeded randomness door.
 

@@ -233,13 +233,14 @@ def test_donation_plumbing_and_gating():
 # dispatch-count budget (the CI perf-smoke assertion, unit-sized)
 # ---------------------------------------------------------------------------
 
-def test_dispatch_budget_sorts():
+def test_dispatch_budget_sorts(kernel_path):
     from benchmarks.bench_sort import DISPATCH_BUDGET
+    kernel_path("pallas")
     x = jnp.asarray(uniform_keys(T * M, seed=34).reshape(T, M))
     for algorithm in ("smms", "terasort"):
         reset_default_pool()
         ops.reset_dispatch_counts()
-        cluster.sort(x, algorithm=algorithm, kernel_backend="pallas")
+        cluster.sort(x, algorithm=algorithm)
         ticks = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
                     if path == "pallas")
         assert 0 < ticks <= DISPATCH_BUDGET[algorithm], (

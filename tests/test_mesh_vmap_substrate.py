@@ -20,6 +20,7 @@ import pytest
 from repro import cluster
 from repro.cluster import SubstratePool, VmapSubstrate
 from repro.cluster.substrate import _stable_fn_key
+from repro.kernels import ops
 
 SCRIPT = r"""
 import os
@@ -248,5 +249,6 @@ def test_one_device_pool_is_the_vmap_substrate():
     body = functools.partial(
         smms_shard, axis_name="i", t=8, r=2,
         cap_factor=float(default_cap_factor(8 * 64, 8, 2)),
-        backend="static", kernel_backend=None)
-    assert key == (_stable_fn_key(body), (), (((8, 64), "float32"),))
+        backend="static")
+    assert key == (_stable_fn_key(body), ops.program_key(), (),
+                   (((8, 64), "float32"),))

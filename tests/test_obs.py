@@ -56,27 +56,28 @@ def assert_span_report_bitwise(root, report):
         root.tree_str(), [p.name for p in report.phases])
 
 
-@pytest.mark.parametrize("kernel_backend", BACKENDS)
+@pytest.mark.parametrize("path", BACKENDS)
 @pytest.mark.parametrize("algorithm", ["smms", "terasort"])
-def test_sort_span_report_bitwise(algorithm, kernel_backend, rng):
+def test_sort_span_report_bitwise(algorithm, path, rng, kernel_path):
+    kernel_path(path)
     t, m = 4, 64
     x = jnp.asarray(rng.normal(size=(t, m)).astype(np.float32))
     tracer = Tracer(enabled=True)
     kw = {"seed": 3} if algorithm == "terasort" else {}
     with tracer.trace("q") as root:
         _, report = cluster.sort(x, algorithm=algorithm,
-                                 substrate=SubstratePool(),
-                                 kernel_backend=kernel_backend, **kw)
+                                 substrate=SubstratePool(), **kw)
     assert_span_report_bitwise(root, report)
     # the dispatch decisions the cold trace made are on the span tree
     dispatch = [e for s in root.walk() for e in s.events
                 if e.name == "kernel_dispatch"]
     assert dispatch and all(
-        e.attrs["path"] == kernel_backend for e in dispatch)
+        e.attrs["path"] == path for e in dispatch)
 
 
-@pytest.mark.parametrize("kernel_backend", BACKENDS)
-def test_moe_span_report_bitwise(kernel_backend):
+@pytest.mark.parametrize("path", BACKENDS)
+def test_moe_span_report_bitwise(path, kernel_path):
+    kernel_path(path)
     d, e, tokens = 16, 4, 128
     cfg = MoEConfig(num_experts=e, top_k=2, d_ff_expert=8, extra_slots=4)
     params = init_moe(jax.random.key(0), d, cfg, jnp.float32)
@@ -86,8 +87,7 @@ def test_moe_span_report_bitwise(kernel_backend):
     with tracer.trace("q") as root:
         _, report = cluster.moe_dispatch(params, x, cfg, mode="cluster",
                                          t_machines=4,
-                                         substrate=SubstratePool(),
-                                         kernel_backend=kernel_backend)
+                                         substrate=SubstratePool())
     assert_span_report_bitwise(root, report)
 
 
@@ -315,21 +315,20 @@ def test_serve_stats_percentiles(rng):
 # execution-time dispatch counts (satellite: DISPATCH_COUNTS semantics)
 # ---------------------------------------------------------------------------
 
-def test_exec_counts_tick_per_execution(rng):
+def test_exec_counts_tick_per_execution(rng, kernel_path):
     """DISPATCH_COUNTS ticks per trace; kernel_dispatch_execs_total per
     execution — a warm re-run moves only the latter."""
     t, m = 4, 64
     x = jnp.asarray(rng.normal(size=(t, m)).astype(np.float32))
     pool = SubstratePool()
+    kernel_path("reference")
     ops.enable_exec_counts(True)
     try:
-        cluster.sort(x, algorithm="smms", substrate=pool,
-                     kernel_backend="reference")
+        cluster.sort(x, algorithm="smms", substrate=pool)
         traces_cold = dict(ops.DISPATCH_COUNTS)
         execs_cold = ops.exec_dispatch_counts()
         assert traces_cold and execs_cold == traces_cold
-        cluster.sort(x, algorithm="smms", substrate=pool,
-                     kernel_backend="reference")     # warm: no re-trace
+        cluster.sort(x, algorithm="smms", substrate=pool)  # warm: no re-trace
         assert dict(ops.DISPATCH_COUNTS) == traces_cold
         execs_warm = ops.exec_dispatch_counts()
         assert execs_warm == {k: 2 * v for k, v in traces_cold.items()}

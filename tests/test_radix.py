@@ -292,7 +292,8 @@ def test_sort_kernel_choice_cost_model(monkeypatch):
 @pytest.mark.parametrize("algorithm,budget_key", [("smms", "smms_radix"),
                                                   ("terasort",
                                                    "terasort_radix")])
-def test_cluster_sort_forced_radix_parity(algorithm, budget_key):
+def test_cluster_sort_forced_radix_parity(algorithm, budget_key,
+                                          kernel_path):
     from benchmarks.bench_sort import DISPATCH_BUDGET, KERNEL_PATHS
     from repro import cluster
     from repro.cluster.substrate import reset_default_pool
@@ -301,13 +302,13 @@ def test_cluster_sort_forced_radix_parity(algorithm, budget_key):
     t, m = 4, 256
     x = jnp.asarray(uniform_keys(t * m, seed=21).reshape(t, m))
     reset_default_pool()
-    (ref_keys, _), _ = cluster.sort(x, algorithm=algorithm,
-                                    kernel_backend="reference")
+    kernel_path("reference")
+    (ref_keys, _), _ = cluster.sort(x, algorithm=algorithm)
     reset_default_pool()
     ops.reset_dispatch_counts()
+    kernel_path("pallas")
     with ops.force_sort_kernel("radix"):
-        (keys, _), rep = cluster.sort(x, algorithm=algorithm,
-                                      kernel_backend="pallas")
+        (keys, _), rep = cluster.sort(x, algorithm=algorithm)
     np.testing.assert_array_equal(np.asarray(keys), np.asarray(ref_keys))
     radix_ticks = sum(c for (op, path), c in ops.DISPATCH_COUNTS.items()
                       if path == "radix")

@@ -157,7 +157,7 @@ def test_staged_carries_values(sorter, t, chunks, tied, rng):
 
 
 @pytest.mark.parametrize("with_values", [False, True])
-def test_staged_pallas_matches_reference(with_values):
+def test_staged_pallas_matches_reference(with_values, kernel_path):
     t, m = 8, 512
     if with_values:        # tied keys: the (key, origin) re-order runs
         x = jnp.asarray(zipf_keys(t * m, seed=7).reshape(t, m))
@@ -165,10 +165,10 @@ def test_staged_pallas_matches_reference(with_values):
     else:
         x = jnp.asarray(uniform_keys(t * m, seed=7).reshape(t, m))
         v = None
-    (k_ref, v_ref), _ = smms_sort(x, r=2, values=v, exchange="staged",
-                                  kernel_backend="reference")
-    (k_pal, v_pal), _ = smms_sort(x, r=2, values=v, exchange="staged",
-                                  kernel_backend="pallas")
+    kernel_path("reference")
+    (k_ref, v_ref), _ = smms_sort(x, r=2, values=v, exchange="staged")
+    kernel_path("pallas")
+    (k_pal, v_pal), _ = smms_sort(x, r=2, values=v, exchange="staged")
     np.testing.assert_array_equal(np.asarray(k_ref), np.asarray(k_pal))
     if with_values:
         np.testing.assert_array_equal(np.asarray(v_ref), np.asarray(v_pal))

@@ -162,8 +162,7 @@ def test_machines_over_four_chips_compile(topo, monkeypatch):
     sub = MeshVmapSubstrate(("i", t), chips=4, devices=topo.devices)
     body = functools.partial(
         _smms_shard_kv, axis_name=sub.axis_name, t=t, r=2,
-        cap_factor=float(default_cap_factor(n, t, 2)), backend="static",
-        kernel_backend=None)
+        cap_factor=float(default_cap_factor(n, t, 2)), backend="static")
     sharded = NamedSharding(sub.mesh, P("chip"))
     args = [jax.ShapeDtypeStruct((t, n // t), d, sharding=sharded)
             for d in (jnp.float32, jnp.int32)]
