@@ -89,25 +89,38 @@ def partition_sorted(x_sorted: jnp.ndarray, interior: jnp.ndarray,
 def build_send_buffer(x_sorted: jnp.ndarray, starts: jnp.ndarray,
                       lens: jnp.ndarray, cap_per_pair: int,
                       values: Optional[jnp.ndarray] = None,
-                      pad_key=PAD, valid_len: Optional[int] = None):
+                      pad_key=PAD):
     """Pack t contiguous segments into a (t, C) tile, sentinel-padded.
 
     Returns (keys_buf, values_buf_or_None, dropped) where dropped counts
     objects beyond per-pair capacity (0 when capacity is adequate).
     ``values`` may be one array or a tuple of arrays, each with leading
-    dim m.  ``valid_len`` bounds the gather when ``x_sorted`` carries a
-    padded tail (segment indices never reach it — lens sum to valid_len).
+    dim m.  ``x_sorted`` may carry a padded tail past the segments (lens
+    sum to the valid length): the tail never enters a tile.
+
+    Row k is one contiguous copy, ``x[starts[k] : starts[k] + C]``, of
+    each array extended along its leading dim by C fill slots, so no
+    start plus C passes the end (``dynamic_slice`` would clamp the start
+    and shift the row).  The t copies are one gather of whole rows, which
+    the TPU compiler runs as a loop of row copies, not one load per slot.
+    Slots at or past ``lens[k]`` become the sentinel (keys) or zeros
+    (values).
     """
     t = starts.shape[0]
-    m = valid_len if valid_len is not None else x_sorted.shape[0]
     cols = jnp.arange(cap_per_pair)
-    idx = starts[:, None] + cols[None, :]                      # (t, C)
-    valid = cols[None, :] < lens[:, None]
-    safe = jnp.clip(idx, 0, m - 1)
-    keys = jnp.where(valid, x_sorted[safe], jnp.asarray(pad_key, x_sorted.dtype))
+    valid = cols[None, :] < lens[:, None]                      # (t, C)
+    fill = jnp.asarray(pad_key, x_sorted.dtype)
+
+    def rows(v, fill_value):
+        ext = jnp.concatenate(
+            [v, jnp.full((cap_per_pair,) + v.shape[1:], fill_value, v.dtype)])
+        return jax.vmap(lambda s: lax.dynamic_slice_in_dim(
+            ext, s, cap_per_pair))(starts)                     # (t, C, ...)
+
+    keys = jnp.where(valid, rows(x_sorted, fill), fill)
 
     def pack(v):
-        vals_g = v[safe]                                       # (t, C, ...)
+        vals_g = rows(v, 0)
         mask = valid.reshape(t, cap_per_pair, *([1] * (v.ndim - 1)))
         return jnp.where(mask, vals_g, jnp.zeros_like(vals_g))
 
@@ -281,7 +294,7 @@ def staged_receive_capacities(m: int, t1: int, t2: int, cap_factor: float,
 
 def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
                      t1: int, t2: int, m: int, cap_factor: float,
-                     values, valid_len, overlap_chunks: int,
+                     values, overlap_chunks: int,
                      tape, phase_prefix: str) -> "ExchangeResult":
     """Two-level compacted exchange (AMS-style): group-hop, merge,
     re-partition, final hop with overlapped chunk merges.
@@ -320,7 +333,7 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
                               jnp.full((1,), m, starts.dtype)])
     g_lens = g_ends - g_starts
     kbuf1, vbuf1, drop1 = build_send_buffer(x_sorted, g_starts, g_lens, c1,
-                                            values, valid_len=valid_len)
+                                            values)
     me1 = lax.axis_index(a1)
     sent1 = m - g_lens[me1]
     aux = {}
@@ -341,8 +354,7 @@ def _staged_exchange(x_sorted, interior, starts, lens, *, axis_names,
         s2_starts, s2_lens = partition_sorted(merged, local_interior,
                                               valid_len=count1)
         kbuf2, vbuf2, drop2 = build_send_buffer(merged, s2_starts, s2_lens,
-                                                c2, merged_v,
-                                                valid_len=count1)
+                                                c2, merged_v)
         aux["drop2"] = drop2
         return kbuf2, vbuf2, count1 - s2_lens[lax.axis_index(a2)]
 
@@ -461,7 +473,7 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
         return _staged_exchange(
             x_sorted, interior, starts, lens, axis_names=tuple(axis_name),
             t1=t1, t2=t2, m=m, cap_factor=cap_factor, values=values,
-            valid_len=valid_len, overlap_chunks=overlap_chunks,
+            overlap_chunks=overlap_chunks,
             tape=tape if tape is not None else _null_tape(),
             phase_prefix=phase_prefix)
     me = lax.axis_index(axis_name)
@@ -479,7 +491,7 @@ def exchange_sorted_segments(x_sorted: jnp.ndarray,
         dropped = jnp.zeros((), jnp.int32)
     else:
         keys_buf, vals_buf, local_drop = build_send_buffer(
-            x_sorted, starts, lens, cap_pair, values, valid_len=valid_len)
+            x_sorted, starts, lens, cap_pair, values)
         recv2d, recv_v2d = static_exchange(keys_buf, axis_name, vals_buf,
                                            tape=tape, sent=sent)
         recv = recv2d.reshape(-1)

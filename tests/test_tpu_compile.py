@@ -188,3 +188,20 @@ def test_reference_payload_sorts_compile_without_gather(one_chip, op, shape):
     text = jax.jit(body).lower(*args).compile().as_text()
     assert " sort(" in text
     assert " gather(" not in text
+
+
+def test_send_buffer_compiles_without_gather(one_chip):
+    """At the one-chip sort cell's shapes (16 machines of 2^22 keys and
+    ids under ``vmap``, 550,504 slots a pair) the exchange packs its send
+    tiles with row copies: no gather of keys or ids is left."""
+    from repro.core.exchange import build_send_buffer
+
+    t, cap = 16, 550_504
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (((t, 1 << 22), jnp.float32),
+                         ((t, 1 << 22), jnp.int32),
+                         ((t, t), jnp.int32), ((t, t), jnp.int32))]
+    body = jax.vmap(lambda k, v, starts, lens: build_send_buffer(
+        k, starts, lens, cap, v))
+    text = jax.jit(body).lower(*args).compile().as_text()
+    assert " gather(" not in text
